@@ -17,9 +17,10 @@ and writes ``BENCH_colocation.json`` at the repo root:
   `SIZES` on ``engine="auto"`` (``imp_batched`` below 4096 nodes,
   ``imp_sharded`` above), recording events/sec and wall clock, with the
   pre-O(delta) ``legacy_loop`` run at `PARITY_SIZES` for the bit-exact
-  day-metric parity flags and the events/sec ratio baseline.  Each day
-  runs in a subprocess with an 8-device host platform so the sharded
-  engine gets a real mesh.
+  day-metric parity flags and the events/sec ratio baseline.  On a TPU
+  each day runs in-process on the real devices; on the CPU each runs in a
+  subprocess with an 8-device host platform so the sharded engine gets a
+  real mesh.
 
 ``benchmarks.check_colocation_regression`` gates CI on this file.
 
@@ -38,7 +39,7 @@ import sys
 import time
 from pathlib import Path
 
-from .common import FULL, emit
+from .common import FULL, emit, on_tpu
 
 BENCH_JSON = Path(__file__).parent.parent / "BENCH_colocation.json"
 
@@ -63,6 +64,7 @@ LEGACY_REF_NODES = 128
 #: the committed run took ~97 min on a single-core host (the 1M-event
 #: stream is host-loop-cheap; the wall is ~450k sharded plan dispatches)
 SCALE_BUDGET_S = 7200.0
+#: forced host-device count of the CPU child
 DEVICES = 8
 _CHILD_FLAG = "--scale-child"
 _MARK = "COLOCATION_SCALE_JSON:"
@@ -102,7 +104,7 @@ def report_payload(rep) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# scale-sweep child: ONE day cycle under the forced 8-device host platform
+# scale sweep: ONE day cycle per size (a forced 8-device child on the CPU)
 # ---------------------------------------------------------------------------
 
 def _scale_day(nodes: int, hours: float, seed: int, legacy: bool):
@@ -117,28 +119,43 @@ def _scale_day(nodes: int, hours: float, seed: int, legacy: bool):
     return sim, rep, wall
 
 
-def _child_main(args: argparse.Namespace) -> None:
-    sim, rep, wall = _scale_day(args.nodes, args.hours, args.seed,
-                                args.legacy)
-    print(_MARK + json.dumps({
-        "nodes": args.nodes,
-        "loop": "legacy" if args.legacy else "odelta",
+def _day_row(nodes: int, hours: float, seed: int, legacy: bool) -> dict:
+    """One sweep day in this process, as its JSON row."""
+    import jax
+
+    sim, rep, wall = _scale_day(nodes, hours, seed, legacy)
+    dev = jax.devices()[0]
+    return json.loads(json.dumps({
+        "nodes": nodes,
+        "loop": "legacy" if legacy else "odelta",
         "engine": sim.sched.engine,
-        "horizon_hours": args.hours,
-        "seed": args.seed,
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "devices": len(jax.devices()),
+        "horizon_hours": hours,
+        "seed": seed,
         "events": sim.events_processed,
         "wall_s": wall,
         "events_per_sec": sim.events_processed / wall if wall else 0.0,
-        # full day metrics only at the (small) parity sizes — the parent
+        # full day metrics only at the (small) parity sizes — the caller
         # compares legacy vs O(delta) dicts whole; both sides go through
         # one json round-trip, so float equality is preserved exactly
         "key_metrics": (rep.key_metrics()
-                        if args.nodes in PARITY_SIZES else None),
+                        if nodes in PARITY_SIZES else None),
     }))
 
 
-def _spawn_scale_day(nodes: int, hours: float, seed: int,
-                     legacy: bool) -> dict:
+def _child_main(args: argparse.Namespace) -> None:
+    from repro.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
+    print(_MARK + json.dumps(_day_row(args.nodes, args.hours, args.seed,
+                                      args.legacy)))
+
+
+def _sweep_day(nodes: int, hours: float, seed: int, legacy: bool) -> dict:
+    if on_tpu():
+        return _day_row(nodes, hours, seed, legacy)
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={DEVICES}"
@@ -175,7 +192,7 @@ def scale_sweep(full: bool = FULL, hours: float | None = None,
     km: dict[tuple[int, str], dict | None] = {}
     for n in sizes:
         for legacy in ((False, True) if n in PARITY_SIZES else (False,)):
-            row = _spawn_scale_day(n, hours, seed, legacy)
+            row = _sweep_day(n, hours, seed, legacy)
             km[(n, row["loop"])] = row.pop("key_metrics")
             rows.append(row)
             emit(f"colocation_scale_{n}_{row['loop']}", 0.0,
@@ -204,7 +221,7 @@ def scale_sweep(full: bool = FULL, hours: float | None = None,
     return {
         "protocol": "full" if full else "small",
         "engine": "auto",
-        "devices": DEVICES,
+        "devices": rows[0]["devices"],
         "horizon_hours": hours,
         "seed": seed,
         "sizes": list(sizes),

@@ -8,11 +8,12 @@ the device mesh), and the ``imp_sharded`` engine must stay bit-identical to
 
 Protocol
 --------
-The parent process re-invokes this module as a subprocess with
+On a TPU the sweep runs in-process on the real devices (one process per
+chip).  On the CPU the parent re-invokes this module as a subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` so the sharded
 engine gets a real 8-device mesh even on a single-CPU host (the flag must
-be set before jax initializes, hence the subprocess).  The child, per size
-in ``SIZES``:
+be set before jax initializes, hence the subprocess).  Per size in
+``SIZES`` the sweep:
 
 * builds one saturated cluster per engine (sizes above the 128-node base
   are TILED — the base's instance pattern replayed per 128-node block —
@@ -48,7 +49,10 @@ import os
 import subprocess
 import sys
 
-from .common import FULL, emit, p
+from repro.core.simulator import SCALE_BASE_NODES as BASE_NODES
+from repro.core.simulator import build_scaled_cluster
+
+from .common import FULL, emit, on_tpu, p
 
 try:  # parent-only import cycle guard: the child imports this module too
     from .bench_sourcing_latency import BENCH_JSON
@@ -58,8 +62,8 @@ except ImportError:  # pragma: no cover - running as a script
     BENCH_JSON = pathlib.Path(__file__).resolve().parent.parent / "BENCH_sourcing.json"
 
 SIZES = (24, 128, 1024, 10240)
+#: forced host-device count of the CPU child
 DEVICES = 8
-BASE_NODES = 128          # tiling block for sizes above it
 ENGINES = ("imp_batched", "imp_sharded")
 #: full-sweep oracle twin per production engine (shortlist front-end off)
 FULL_ENGINES = {"imp_batched": "imp_batched_full",
@@ -80,42 +84,12 @@ _MARK = "SCALE_RESULT_JSON:"
 # ---------------------------------------------------------------------------
 
 def _decision_key(dec):
-    return (str(dec.kind), dec.node, tuple(dec.victims),
-            None if dec.placement is None else dec.placement.tier, dec.hit)
-
-
-def build_scaled_cluster(num_nodes: int, seed: int = 0, fill: float = 1.0):
-    """A saturated (or ``fill``-fraction) cluster at any node count.
-
-    Up to `BASE_NODES` the regular seeded random saturation runs directly;
-    larger sizes replay a BASE_NODES-sized base pattern per block so
-    construction stays O(num_nodes) instead of O(num_nodes^2).
-    """
-    from repro.core.cluster import Cluster
-    from repro.core.placement import Placement
-    from repro.core.simulator import SimConfig, build_saturated_cluster
-    from repro.core.workload import TABLE3_INITIAL_INSTANCES, table3_workloads
-
-    base_nodes = min(num_nodes, BASE_NODES)
-    cfg = SimConfig(num_nodes=base_nodes, seed=seed)
-    if fill >= 1.0:
-        base = build_saturated_cluster(cfg)
-    else:
-        workloads = table3_workloads()
-        scale = base_nodes / 100.0 * fill
-        counts = {k: max(0, round(v * scale))
-                  for k, v in TABLE3_INITIAL_INSTANCES.items()}
-        base = build_saturated_cluster(cfg, workloads, counts)
-    if num_nodes == base_nodes:
-        return base
-    big = Cluster(base.spec, num_nodes)
-    for blk in range(num_nodes // base_nodes):
-        off = blk * base_nodes
-        for inst in base.instances.values():
-            big.bind(inst.workload, inst.node + off,
-                     Placement(gpu_mask=inst.gpu_mask,
-                               cg_mask=inst.cg_mask, tier=0))
-    return big
+    """What parity compares: kind, node, victims, hit and the placement's
+    concrete masks and tier."""
+    return (str(dec.kind), dec.node, tuple(dec.victims), dec.hit,
+            None if dec.placement is None else
+            (dec.placement.gpu_mask, dec.placement.cg_mask,
+             dec.placement.tier))
 
 
 def _parity_sequence(sched, wl, batch: int):
@@ -138,13 +112,15 @@ def _parity_sequence(sched, wl, batch: int):
     return keys
 
 
-def _child_main() -> None:
+def measure(protocol: str) -> dict:
+    """The whole sweep in this process, on whatever devices JAX has."""
     import time
+
+    import jax
 
     from repro.core import TopoScheduler, table3_workloads
     from repro.core.simulator import CompileWatch
 
-    protocol = os.environ.get("SCALE_PROTOCOL", "small")
     per_size = _SAMPLES_FULL if protocol == "full" else _SAMPLES_SMALL
     wl = {w.name: w for w in table3_workloads()}
     watch = CompileWatch.get()
@@ -152,9 +128,6 @@ def _child_main() -> None:
     parity: dict[str, bool] = {}
     shortlist_parity: dict[str, bool] = {}
     shortlist_meta: dict = {}
-
-    import jax
-    assert len(jax.devices()) == DEVICES, jax.devices()
 
     for n in SIZES:
         samples, rounds, n_samples = per_size[n]
@@ -252,17 +225,30 @@ def _child_main() -> None:
         print(f"# scale n={n} done (parity={parity[str(n)]})",
               file=sys.stderr, flush=True)
 
+    dev = jax.devices()[0]
+    return {"protocol": protocol, "platform": dev.platform,
+            "device_kind": dev.device_kind, "devices": len(jax.devices()),
+            "sizes": list(SIZES), "base_nodes": BASE_NODES, "rows": rows,
+            "parity": parity, "shortlist": shortlist_meta,
+            "shortlist_parity": shortlist_parity}
+
+
+def _child_main() -> None:
+    from repro.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
+    import jax
+
+    assert len(jax.devices()) == DEVICES, jax.devices()
     print(_MARK + json.dumps(
-        {"protocol": protocol, "devices": DEVICES, "sizes": list(SIZES),
-         "base_nodes": BASE_NODES, "rows": rows, "parity": parity,
-         "shortlist": shortlist_meta, "shortlist_parity": shortlist_parity}))
+        measure(os.environ.get("SCALE_PROTOCOL", "small"))))
 
 
 # ---------------------------------------------------------------------------
-# parent: spawn the 8-device child, merge + emit
+# parent: in-process on a TPU, else spawn the 8-device CPU child; merge + emit
 # ---------------------------------------------------------------------------
 
-def run(full: bool = FULL) -> dict:
+def _spawn_child(full: bool) -> dict:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count={DEVICES}").strip()
@@ -283,6 +269,12 @@ def run(full: bool = FULL) -> dict:
     if payload is None:
         raise RuntimeError(f"no scale result in child output:\n"
                            f"{proc.stdout[-2000:]}\n{proc.stderr[-2000:]}")
+    return payload
+
+
+def run(full: bool = FULL) -> dict:
+    payload = (measure("full" if full else "small") if on_tpu()
+               else _spawn_child(full))
     for row in payload["rows"]:
         emit(f"scale_{row['nodes']}_{row['engine']}_{row['metric']}",
              row["p50_us"],

@@ -37,6 +37,9 @@ INT_METRICS = ("elastic_admitted", "elastic_ejected", "elastic_completed",
 
 
 def main() -> int:
+    from repro.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
     if not BENCH_JSON.exists():
         print(f"FAIL: no committed baseline at {BENCH_JSON}")
         return 1

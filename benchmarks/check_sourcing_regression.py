@@ -208,6 +208,9 @@ def check_scale(baseline: dict) -> int:
 
 
 def main() -> int:
+    from repro.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
     if not BENCH_JSON.exists():
         print(f"FAIL: no committed baseline at {BENCH_JSON}")
         return 1

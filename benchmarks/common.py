@@ -7,6 +7,15 @@ import sys
 FULL = os.environ.get("BENCH_FULL", "") == "1"
 
 
+def on_tpu() -> bool:
+    """True when JAX's backend is a TPU (this initializes the backend, so
+    the calling process then holds the chip: run the work in-process and
+    start no JAX child that needs it)."""
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
 def emit(name: str, us_per_call: float, derived: str = "") -> None:
     print(f"{name},{us_per_call:.1f},{derived}", flush=True)
 

@@ -6,7 +6,8 @@ paper-scale protocol (100 nodes, 100x50 preemptions).
   table4_*  — hit rate (paper Table 4)
   table5_*  — candidate-sourcing latency (paper Table 5 / Fig 11)
   scale_*   — plan-latency scale sweep 24..10k nodes, sharded vs fused
-              (8-device subprocess; merges the BENCH_sourcing.json scale block)
+              (in-process on a TPU, an 8-device CPU subprocess otherwise;
+              merges the BENCH_sourcing.json scale block)
   fig10_*   — per-workload sourcing overhead (paper Fig 10)
   fig9_*    — preemption timeline (paper Fig 9)
   fig8_*    — allocation snapshots (paper Fig 8)
@@ -39,6 +40,9 @@ def main(argv: list[str] | None = None) -> None:
     overridden = (args.nodes is not None or args.hours != 24.0
                   or args.seed != 0)
 
+    from repro.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import (bench_allocation_snapshot, bench_colocation,
                    bench_elastic, bench_hit_rate, bench_instance_timeline,
                    bench_roofline, bench_scale_sourcing,

@@ -46,11 +46,13 @@ import numpy as np
 from repro.core.colocation import (ColocationConfig, compare_day_cycle,
                                    compare_two_level, default_policies)
 from repro.configs import get_config
+from repro.jax_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import Request, ServeEngine, TIER_PERF
 
 
 def main() -> None:
+    enable_compile_cache()
     # ---- the simulated day: topology-aware vs topology-unaware A/B -------
     cfg = ColocationConfig(num_nodes=12, seed=0, horizon_hours=24.0)
     print(f"simulating a {cfg.horizon_hours:.0f}h day on {cfg.num_nodes} "

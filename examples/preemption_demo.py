@@ -24,6 +24,7 @@ sys.path.insert(0, str(Path(__file__).parent.parent / "src"))
 from repro.core import (Cluster, RTX4090_SERVER, TopoScheduler,
                         register_engine, registered_engines, table1_workloads)
 from repro.core.preemption import flextopo_imp
+from repro.jax_cache import enable_compile_cache
 
 
 def gpu_map(cluster, node):
@@ -63,6 +64,7 @@ def imp_even_nodes(cluster, workload, node):
 
 
 def main() -> None:
+    enable_compile_cache()
     for engine in ("godel", "imp"):
         cluster, sched, wls = saturated(engine)
         show(cluster, f"saturated cluster (engine={engine})")

@@ -12,11 +12,13 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.data import DataConfig, SyntheticTokenPipeline
+from repro.jax_cache import enable_compile_cache
 from repro.models import build_model, count_params
 from repro.train import OptConfig, TrainConfig, init_train_state, make_train_step
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = get_config("llama3.2-1b", smoke=True)
     api = build_model(cfg)
     state = init_train_state(api, jax.random.PRNGKey(0))

@@ -16,6 +16,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import itertools
+from collections import Counter
 from typing import Callable
 
 import numpy as np
@@ -1112,6 +1113,11 @@ class DeviceClusterState:
         #: host mask, device copy).  Rebuilt lazily by ``rep_classes`` —
         #: a plan window with no commits reuses both arrays untouched.
         self._rep_cache: tuple | None = None
+        #: plans that left the device path: ``host_rows`` counts rows
+        #: sourced by per-node host Python (victims past the stored
+        #: prefix), ``shortlist_full_sweep`` guaranteed-mode shortlist
+        #: checks that re-dispatched the full sweep
+        self.fallbacks: Counter = Counter()
         self._dirty: set[int] = set(range(cluster.num_nodes))
         cluster.add_dirty_listener(self._mark_dirty)
 

@@ -379,7 +379,7 @@ class _ShardedEvaluators:
     bodies (`_plan_pipeline`, `_plan2_pipeline`, `_normal_pipeline`,
     `_gathered_pipeline`, the batch pipelines) jitted with explicit
     sharding constraints.  Node-axis tensors arrive sharded, every
-    aux/patch upload and the request scalars replicate, and the
+    aux/patch upload and the request vector replicate, and the
     int32[`WIN_FIELDS`]-sized winner vectors come back replicated — the
     per-node class math runs shard-local and only the final argmax chain
     (plus the winner-row gather) crosses shards as collectives."""
@@ -395,95 +395,88 @@ class _ShardedEvaluators:
             fn = self._cache[key] = build()
         return fn
 
-    def resident_evaluator(self, spec, m, p, g, thresh, ng, nc, cpb,
-                           alpha):
+    def resident_evaluator(self, spec, m, p, g, alpha):
         def build():
-            def f(nodestate, victims, drain, aux, pbuf):
+            def f(nodestate, victims, drain, aux, pbuf, req):
                 return _pj._plan_pipeline(
-                    nodestate, victims, drain, aux, pbuf, thresh, ng, nc,
-                    cpb, alpha, spec=spec, m=m, p=p, g=g)
-
-            return jax.jit(f, in_shardings=(
-                self.node_sh, self.victim_sh, self.node_sh, self.repl,
-                self.repl), out_shardings=self.repl)
-
-        return self._get(("res", spec, m, p, g, thresh, ng, nc, cpb,
-                          alpha), build)
-
-    def plan_evaluator(self, spec, m, p, g, thresh, ng, nc, cpb, alpha):
-        def build():
-            def f(nodestate, victims, drain, aux, pbuf):
-                return _pj._plan2_pipeline(
-                    nodestate, victims, drain, aux, pbuf, thresh, ng, nc,
-                    cpb, alpha, spec=spec, m=m, p=p, g=g)
-
-            return jax.jit(f, in_shardings=(
-                self.node_sh, self.victim_sh, self.node_sh, self.repl,
-                self.repl), out_shardings=self.repl)
-
-        return self._get(("plan", spec, m, p, g, thresh, ng, nc, cpb,
-                          alpha), build)
-
-    def normal_evaluator(self, spec, p, ng, nc, cpb):
-        def build():
-            def f(nodestate, aux, pbuf):
-                return _pj._normal_pipeline(nodestate, aux, pbuf, ng, nc,
-                                            cpb, spec=spec, p=p)
-
-            return jax.jit(f, in_shardings=(
-                self.node_sh, self.repl, self.repl),
-                out_shardings=self.repl)
-
-        return self._get(("norm", spec, p, ng, nc, cpb), build)
-
-    def gathered_evaluator(self, spec, m, p, thresh, ng, nc, cpb, alpha):
-        def build():
-            def f(nodestate, victims, drain, pidx, pbuf, gidx):
-                return _pj._gathered_pipeline(
-                    nodestate, victims, drain, pidx, pbuf, gidx, thresh,
-                    ng, nc, cpb, alpha, spec=spec, m=m, p=p)
+                    nodestate, victims, drain, aux, pbuf,
+                    *_pj._req_args(req), alpha, spec=spec, m=m, p=p, g=g)
 
             return jax.jit(f, in_shardings=(
                 self.node_sh, self.victim_sh, self.node_sh, self.repl,
                 self.repl, self.repl), out_shardings=self.repl)
 
-        return self._get(("gath", spec, m, p, thresh, ng, nc, cpb, alpha),
-                         build)
+        return self._get(("res", spec, m, p, g, alpha), build)
+
+    def plan_evaluator(self, spec, m, p, g, alpha):
+        def build():
+            def f(nodestate, victims, drain, aux, pbuf, req):
+                return _pj._plan2_pipeline(
+                    nodestate, victims, drain, aux, pbuf,
+                    *_pj._req_args(req), alpha, spec=spec, m=m, p=p, g=g)
+
+            return jax.jit(f, in_shardings=(
+                self.node_sh, self.victim_sh, self.node_sh, self.repl,
+                self.repl, self.repl), out_shardings=self.repl)
+
+        return self._get(("plan", spec, m, p, g, alpha), build)
+
+    def normal_evaluator(self, spec, p):
+        def build():
+            def f(nodestate, aux, pbuf, req):
+                _, ng, nc, cpb = _pj._req_args(req)
+                return _pj._normal_pipeline(nodestate, aux, pbuf, ng, nc,
+                                            cpb, spec=spec, p=p)
+
+            return jax.jit(f, in_shardings=(
+                self.node_sh, self.repl, self.repl, self.repl),
+                out_shardings=self.repl)
+
+        return self._get(("norm", spec, p), build)
+
+    def gathered_evaluator(self, spec, m, p, alpha):
+        def build():
+            def f(nodestate, victims, drain, pidx, pbuf, gidx, req):
+                return _pj._gathered_pipeline(
+                    nodestate, victims, drain, pidx, pbuf, gidx,
+                    *_pj._req_args(req), alpha, spec=spec, m=m, p=p)
+
+            return jax.jit(f, in_shardings=(
+                self.node_sh, self.victim_sh, self.node_sh, self.repl,
+                self.repl, self.repl, self.repl), out_shardings=self.repl)
+
+        return self._get(("gath", spec, m, p, alpha), build)
 
     @property
     def _rep_sh(self):
         """1-D row sharding of the equivalence-class rep mask."""
         return NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
 
-    def shortlist_evaluator(self, spec, k, p, f, thresh, ng, nc, cpb,
-                            alpha):
+    def shortlist_evaluator(self, spec, k, p, f, alpha):
         def build():
-            def fn(nodestate, victims, drain, rep, aux, pbuf):
+            def fn(nodestate, victims, drain, rep, aux, pbuf, req):
                 return _pj._shortlist_pipeline(
-                    nodestate, victims, drain, rep, aux, pbuf, thresh, ng,
-                    nc, cpb, alpha, spec=spec, k=k, p=p, f=f)
+                    nodestate, victims, drain, rep, aux, pbuf,
+                    *_pj._req_args(req), alpha, spec=spec, k=k, p=p, f=f)
 
             return jax.jit(fn, in_shardings=(
                 self.node_sh, self.victim_sh, self.node_sh, self._rep_sh,
-                self.repl, self.repl), out_shardings=self.repl)
+                self.repl, self.repl, self.repl), out_shardings=self.repl)
 
-        return self._get(("sl", spec, k, p, f, thresh, ng, nc, cpb,
-                          alpha), build)
+        return self._get(("sl", spec, k, p, f, alpha), build)
 
-    def shortlist_plan_evaluator(self, spec, k, p, f, thresh, ng, nc,
-                                 cpb, alpha):
+    def shortlist_plan_evaluator(self, spec, k, p, f, alpha):
         def build():
-            def fn(nodestate, victims, drain, rep, aux, pbuf):
+            def fn(nodestate, victims, drain, rep, aux, pbuf, req):
                 return _pj._shortlist_plan2_pipeline(
-                    nodestate, victims, drain, rep, aux, pbuf, thresh, ng,
-                    nc, cpb, alpha, spec=spec, k=k, p=p, f=f)
+                    nodestate, victims, drain, rep, aux, pbuf,
+                    *_pj._req_args(req), alpha, spec=spec, k=k, p=p, f=f)
 
             return jax.jit(fn, in_shardings=(
                 self.node_sh, self.victim_sh, self.node_sh, self._rep_sh,
-                self.repl, self.repl), out_shardings=self.repl)
+                self.repl, self.repl, self.repl), out_shardings=self.repl)
 
-        return self._get(("slplan", spec, k, p, f, thresh, ng, nc, cpb,
-                          alpha), build)
+        return self._get(("slplan", spec, k, p, f, alpha), build)
 
     def batch_class_evaluator(self, spec, m, alpha):
         def build():
@@ -502,43 +495,39 @@ class _ShardedEvaluators:
 
         return self._get(("bcls", spec, m, alpha), build)
 
-    def batch_merge_evaluator(self, spec, m, dpad, g, thresh, ng, nc, cpb,
-                              alpha):
+    def batch_merge_evaluator(self, spec, m, dpad, g, alpha):
         def build():
             def f(anyc, cb, pp, um, kn, cnt, nodestate, victims, drain, i,
-                  aux, pbuf):
+                  aux, pbuf, req):
                 return _pj._batch_merge_pipeline(
                     anyc, cb, pp, um, kn, cnt, nodestate, victims, drain,
-                    i, aux, pbuf, thresh, ng, nc, cpb, alpha, spec=spec,
+                    i, aux, pbuf, *_pj._req_args(req), alpha, spec=spec,
                     m=m, dpad=dpad, g=g)
 
             cw3, cw2 = self.victim_sh, self.node_sh
             return jax.jit(f, in_shardings=(
                 cw3, cw3, cw3, cw3, cw2, cw2, self.node_sh, self.victim_sh,
-                self.node_sh, self.repl, self.repl, self.repl),
+                self.node_sh, self.repl, self.repl, self.repl, self.repl),
                 out_shardings=self.repl)
 
-        return self._get(("bmerge", spec, m, dpad, g, thresh, ng, nc, cpb,
-                          alpha), build)
+        return self._get(("bmerge", spec, m, dpad, g, alpha), build)
 
-    def batch_plan_evaluator(self, spec, m, dpad, g, p, thresh, ng, nc,
-                             cpb, alpha):
+    def batch_plan_evaluator(self, spec, m, dpad, g, p, alpha):
         def build():
             def f(anyc, cb, pp, um, kn, cnt, nodestate, victims, drain, i,
-                  aux, pbuf):
+                  aux, pbuf, req):
                 return _pj._batch_plan_pipeline(
                     anyc, cb, pp, um, kn, cnt, nodestate, victims, drain,
-                    i, aux, pbuf, thresh, ng, nc, cpb, alpha, spec=spec,
+                    i, aux, pbuf, *_pj._req_args(req), alpha, spec=spec,
                     m=m, dpad=dpad, g=g, p=p)
 
             cw3, cw2 = self.victim_sh, self.node_sh
             return jax.jit(f, in_shardings=(
                 cw3, cw3, cw3, cw3, cw2, cw2, self.node_sh, self.victim_sh,
-                self.node_sh, self.repl, self.repl, self.repl),
+                self.node_sh, self.repl, self.repl, self.repl, self.repl),
                 out_shardings=self.repl)
 
-        return self._get(("bplan", spec, m, dpad, g, p, thresh, ng, nc,
-                          cpb, alpha), build)
+        return self._get(("bplan", spec, m, dpad, g, p, alpha), build)
 
 
 @lru_cache(maxsize=None)

@@ -280,15 +280,22 @@ def normal_cycle_core(nodestate, ng, nc, cpb, *, spec: ServerSpec):
     nid = jnp.where(sel, node_ids, big)
     row = jnp.argmin(nid)
     found = jnp.any(cnt_ok)
-    p_ok, p_tier, p_g, p_c = place_core(free_g[row], free_c[row],
-                                        ng, nc, cpb, spec=spec)
-    use_place = placeable[row] & p_ok
+    # the winner's column in ONE dynamic slice: XLA:CPU's fusion emitter
+    # miscompiled the per-vector ``x[row]`` reads feeding this stack when
+    # the request was a compile-time constant (wrong gpu_mask)
+    win = jax.lax.dynamic_index_in_dim(
+        jnp.stack([free_g, free_c, node_ids, placeable.astype(jnp.int32),
+                   b_tier, b_g, b_c]), row, axis=1, keepdims=False)
+    w_fg, w_fc, w_id, w_placeable, w_bt, w_bg, w_bc = (
+        win[i] for i in range(7))
+    p_ok, p_tier, p_g, p_c = place_core(w_fg, w_fc, ng, nc, cpb, spec=spec)
+    use_place = (w_placeable > 0) & p_ok
     return jnp.stack([
         found.astype(jnp.int32),
-        node_ids[row],
-        jnp.where(use_place, p_tier, b_tier[row]),
-        jnp.where(use_place, p_g, b_g[row]),
-        jnp.where(use_place, p_c, b_c[row]),
+        w_id,
+        jnp.where(use_place, p_tier, w_bt),
+        jnp.where(use_place, p_g, w_bg),
+        jnp.where(use_place, p_c, w_bc),
     ])
 
 

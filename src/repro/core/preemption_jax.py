@@ -49,6 +49,7 @@ from __future__ import annotations
 
 import dataclasses
 import sys
+from collections import Counter
 from functools import lru_cache, partial
 from typing import NamedTuple
 
@@ -221,10 +222,14 @@ def split_dense_nodes(
     return dense, overflow, per_node
 
 
-def _overflow_candidates(cluster, workload: WorkloadSpec,
-                         nodes: list[int]) -> list[Candidate]:
+def _overflow_candidates(cluster, workload: WorkloadSpec, nodes: list[int],
+                         fallbacks: Counter | None = None) -> list[Candidate]:
+    """Per-node host IMP for rows the device programs cannot hold;
+    ``fallbacks`` (a `DeviceClusterState.fallbacks`) counts them."""
     from .preemption import flextopo_imp
 
+    if fallbacks is not None:
+        fallbacks["host_rows"] += len(nodes)
     out: list[Candidate] = []
     for node in nodes:
         out.extend(flextopo_imp(cluster, workload, node))
@@ -712,38 +717,47 @@ def _plan2_pipeline(nodestate, victims, drain, aux, pbuf,
     return jnp.concatenate([norm, pre])
 
 
+def _req_args(req):
+    """Unpack the traced int32[4] request vector (`request_vector`)."""
+    return req[0], req[1], req[2], req[3]
+
+
+@lru_cache(maxsize=None)
+def request_vector(thresh: int, ng: int, nc: int, cpb: int) -> jnp.ndarray:
+    """One preemptor class as the device int32[4] ``(priority, need_gpus,
+    need_cgs, cgs_per_bundle)`` every single-request evaluator takes.
+
+    The request is a TRACED argument, never part of the jit key: one
+    compiled program per shape serves every preemptor class (a day of
+    mixed classes compiles each bucket once), and it is the same program
+    the randomized parity suites run for every class.  Cached per class,
+    so a plan uploads nothing for it."""
+    return jnp.asarray(np.array([thresh, ng, nc, cpb], np.int32))
+
+
 @lru_cache(maxsize=None)
 def resident_evaluator(spec: ServerSpec, m: int, p: int, g: int,
-                       thresh: int, ng: int, nc: int, cpb: int,
                        alpha: float):
-    """jit of `_plan_pipeline` with the REQUEST BAKED IN as python scalars.
+    """jit of `_plan_pipeline` (the request is the traced ``req``
+    argument, see `request_vector`; alpha is baked in).  The vmapped
+    `batch_class_evaluator` takes the request as its vmap axis instead."""
 
-    Single-request plans specialize per (preemptor class, alpha) so XLA
-    constant-folds the unused tier branches and the Eq. 1 weighting —
-    measurably cheaper per dispatch than the traced-scalar variant, and
-    workload classes are few so the jit cache stays small.  The vmapped
-    `batch_class_evaluator` keeps the request dynamic (it is the vmap
-    axis)."""
-
-    def f(nodestate, victims, drain, aux, pbuf):
+    def f(nodestate, victims, drain, aux, pbuf, req):
         return _plan_pipeline(nodestate, victims, drain, aux, pbuf,
-                              thresh, ng, nc, cpb, alpha,
+                              *_req_args(req), alpha,
                               spec=spec, m=m, p=p, g=g)
 
     return jax.jit(f)
 
 
 @lru_cache(maxsize=None)
-def plan_evaluator(spec: ServerSpec, m: int, p: int, g: int,
-                   thresh: int, ng: int, nc: int, cpb: int,
-                   alpha: float):
-    """jit of `_plan2_pipeline` (normal cycle chained into sourcing),
-    request baked in as in `resident_evaluator` — the whole
-    ``schedule_or_preempt`` hot path is this one dispatch."""
+def plan_evaluator(spec: ServerSpec, m: int, p: int, g: int, alpha: float):
+    """jit of `_plan2_pipeline` (normal cycle chained into sourcing) — the
+    whole ``schedule_or_preempt`` hot path is this one dispatch."""
 
-    def f(nodestate, victims, drain, aux, pbuf):
+    def f(nodestate, victims, drain, aux, pbuf, req):
         return _plan2_pipeline(nodestate, victims, drain, aux, pbuf,
-                               thresh, ng, nc, cpb, alpha,
+                               *_req_args(req), alpha,
                                spec=spec, m=m, p=p, g=g)
 
     return jax.jit(f)
@@ -757,13 +771,15 @@ def _normal_pipeline(nodestate, aux, pbuf, ng, nc, cpb, *, spec, p):
 
 
 @lru_cache(maxsize=None)
-def normal_evaluator(spec: ServerSpec, p: int, ng: int, nc: int, cpb: int):
-    """jit of `_normal_pipeline`.
+def normal_evaluator(spec: ServerSpec, p: int):
+    """jit of `_normal_pipeline` (``req`` as in `resident_evaluator`; its
+    priority field is unused).
 
     The batch sessions use this as their per-plan normal cycle (one small
     [NODE_FIELDS, N] dispatch instead of the host python node loop)."""
 
-    def f(nodestate, aux, pbuf):
+    def f(nodestate, aux, pbuf, req):
+        _, ng, nc, cpb = _req_args(req)
         return _normal_pipeline(nodestate, aux, pbuf, ng, nc, cpb,
                                 spec=spec, p=p)
 
@@ -791,15 +807,12 @@ def _gathered_pipeline(nodestate, victims, drain, pidx, pbuf, gidx,
 
 
 @lru_cache(maxsize=None)
-def gathered_evaluator(spec: ServerSpec, m: int, p: int,
-                       thresh: int, ng: int, nc: int, cpb: int,
-                       alpha: float):
-    """jit of `_gathered_pipeline`, request baked in as in
-    `resident_evaluator`."""
+def gathered_evaluator(spec: ServerSpec, m: int, p: int, alpha: float):
+    """jit of `_gathered_pipeline` (``req`` as in `resident_evaluator`)."""
 
-    def f(nodestate, victims, drain, pidx, pbuf, gidx):
+    def f(nodestate, victims, drain, pidx, pbuf, gidx, req):
         return _gathered_pipeline(nodestate, victims, drain, pidx, pbuf,
-                                  gidx, thresh, ng, nc, cpb, alpha,
+                                  gidx, *_req_args(req), alpha,
                                   spec=spec, m=m, p=p)
 
     return jax.jit(f)
@@ -988,14 +1001,12 @@ def _shortlist_plan2_pipeline(nodestate, victims, drain, rep, aux, pbuf,
 
 @lru_cache(maxsize=None)
 def shortlist_evaluator(spec: ServerSpec, k: int, p: int, f: int,
-                        thresh: int, ng: int, nc: int, cpb: int,
                         alpha: float):
-    """jit of `_shortlist_pipeline`, request baked in as in
-    `resident_evaluator`."""
+    """jit of `_shortlist_pipeline` (``req`` as in `resident_evaluator`)."""
 
-    def fn(nodestate, victims, drain, rep, aux, pbuf):
+    def fn(nodestate, victims, drain, rep, aux, pbuf, req):
         return _shortlist_pipeline(nodestate, victims, drain, rep, aux,
-                                   pbuf, thresh, ng, nc, cpb, alpha,
+                                   pbuf, *_req_args(req), alpha,
                                    spec=spec, k=k, p=p, f=f)
 
     return jax.jit(fn)
@@ -1003,15 +1014,14 @@ def shortlist_evaluator(spec: ServerSpec, k: int, p: int, f: int,
 
 @lru_cache(maxsize=None)
 def shortlist_plan_evaluator(spec: ServerSpec, k: int, p: int, f: int,
-                             thresh: int, ng: int, nc: int, cpb: int,
                              alpha: float):
     """jit of `_shortlist_plan2_pipeline` — the shortlisted
     ``schedule_or_preempt`` hot path in one dispatch."""
 
-    def fn(nodestate, victims, drain, rep, aux, pbuf):
+    def fn(nodestate, victims, drain, rep, aux, pbuf, req):
         return _shortlist_plan2_pipeline(nodestate, victims, drain, rep,
-                                         aux, pbuf, thresh, ng, nc, cpb,
-                                         alpha, spec=spec, k=k, p=p, f=f)
+                                         aux, pbuf, *_req_args(req), alpha,
+                                         spec=spec, k=k, p=p, f=f)
 
     return jax.jit(fn)
 
@@ -1091,7 +1101,6 @@ def _batch_merge_pipeline(anyc, cb, pp, um, kn, cnt, nodestate, victims,
 
 @lru_cache(maxsize=None)
 def batch_merge_evaluator(spec: ServerSpec, m: int, dpad: int, g: int,
-                          thresh: int, ng: int, nc: int, cpb: int,
                           alpha: float):
     """Per-request device merge for the batch session, ONE dispatch.
 
@@ -1100,11 +1109,10 @@ def batch_merge_evaluator(spec: ServerSpec, m: int, dpad: int, g: int,
     readback, like a single-request plan."""
 
     def f(anyc, cb, pp, um, kn, cnt, nodestate, victims, drain, i, aux,
-          pbuf):
+          pbuf, req):
         return _batch_merge_pipeline(
             anyc, cb, pp, um, kn, cnt, nodestate, victims, drain, i, aux,
-            pbuf, thresh, ng, nc, cpb, alpha, spec=spec, m=m, dpad=dpad,
-            g=g)
+            pbuf, *_req_args(req), alpha, spec=spec, m=m, dpad=dpad, g=g)
 
     return jax.jit(f)
 
@@ -1139,17 +1147,16 @@ def _batch_plan_pipeline(anyc, cb, pp, um, kn, cnt, nodestate, victims,
 
 @lru_cache(maxsize=None)
 def batch_plan_evaluator(spec: ServerSpec, m: int, dpad: int, g: int,
-                         p: int, thresh: int, ng: int, nc: int, cpb: int,
-                         alpha: float):
+                         p: int, alpha: float):
     """jit of `_batch_plan_pipeline` — a batched plan is one dispatch end
     to end, same as a single-request plan."""
 
     def f(anyc, cb, pp, um, kn, cnt, nodestate, victims, drain, i, aux,
-          pbuf):
+          pbuf, req):
         return _batch_plan_pipeline(
             anyc, cb, pp, um, kn, cnt, nodestate, victims, drain, i, aux,
-            pbuf, thresh, ng, nc, cpb, alpha, spec=spec, m=m, dpad=dpad,
-            g=g, p=p)
+            pbuf, *_req_args(req), alpha, spec=spec, m=m, dpad=dpad, g=g,
+            p=p)
 
     return jax.jit(f)
 
@@ -1520,7 +1527,8 @@ def source_candidates_fused(
         delta &= set(nodes)
         patches = {d: encode_row(cluster, d, ctx.cap) for d in sorted(delta)}
     p, pidx, pbuf = _patch_args(dcs, patches)
-    req = (thresh, ng, nc, cpb, float(alpha))
+    alpha = float(alpha)
+    rq = request_vector(thresh, ng, nc, cpb)
     pargs = None     # (pidx, pbuf) on device, built on first gathered use
     pending = []     # dispatches are async: launch all, decode once
     if nodes is None:
@@ -1531,16 +1539,18 @@ def source_candidates_fused(
             split, f, aux_d, pbuf_d = _shortlist_plan_args(
                 dcs, patches, thresh, p, pidx, pbuf)
             rep_dev = dcs.rep_classes()[1]
-            res = ev.shortlist_evaluator(spec, shortlist.k, p, f, *req)(
+            res = ev.shortlist_evaluator(spec, shortlist.k, p, f, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain, rep_dev,
-                aux_d, pbuf_d)
+                aux_d, pbuf_d, rq)
             vals = [int(x) for x in jax.device_get(res)]
             if vals[-1] or shortlist.mode != "guaranteed":
                 sl_vals = vals
+            else:
+                dcs.fallbacks["shortlist_full_sweep"] += 1
         if sl_vals is not None:
             mid = []     # absorbed: stage 2 always runs NARROW_M wide
             out = CandidateShortlist(_overflow_candidates(
-                cluster, workload, split.overflow))
+                cluster, workload, split.overflow, dcs.fallbacks))
             out.n_candidates = len(out)
             pending.append((np.asarray(sl_vals[:WIN_FIELDS], np.int32),
                             {sl_vals[1]: sl_vals[WIN_FIELDS]}))
@@ -1552,11 +1562,11 @@ def source_candidates_fused(
             split, g, aux_d, pbuf_d = _fast_plan_args(dcs, patches, thresh,
                                                       p, pidx, pbuf)
             mid = split.mid
-            out = CandidateShortlist(_overflow_candidates(cluster, workload,
-                                                          split.overflow))
+            out = CandidateShortlist(_overflow_candidates(
+                cluster, workload, split.overflow, dcs.fallbacks))
             out.n_candidates = len(out)
-            res = ev.resident_evaluator(spec, split.m_res, p, g, *req)(
-                dcs.nodestate, dcs.victims, dcs.drain, aux_d, pbuf_d)
+            res = ev.resident_evaluator(spec, split.m_res, p, g, alpha)(
+                dcs.nodestate, dcs.victims, dcs.drain, aux_d, pbuf_d, rq)
             n = dcs.n_rows
             sel = ({n + j: node for j, node in enumerate(mid)}
                    if mid else None)
@@ -1565,25 +1575,25 @@ def source_candidates_fused(
     else:
         split = split_fused_nodes(dcs, patches, thresh, nodes)
         mid = split.mid
-        out = CandidateShortlist(_overflow_candidates(cluster, workload,
-                                                      split.overflow))
+        out = CandidateShortlist(_overflow_candidates(
+            cluster, workload, split.overflow, dcs.fallbacks))
         out.n_candidates = len(out)
         excluded = set(mid) | set(split.wide) | set(split.overflow)
         narrow = [c for c in nodes if c not in excluded]
         if narrow:
             pargs = (jnp.asarray(pidx), jnp.asarray(pbuf))
-            res = ev.gathered_evaluator(spec, split.m_res, p, *req)(
+            res = ev.gathered_evaluator(spec, split.m_res, p, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain, *pargs,
-                jnp.asarray(_pad_idx(narrow)))
+                jnp.asarray(_pad_idx(narrow)), rq)
             pending.append((res, narrow))
     for m, rows in ((NARROW_M, mid), (ctx.cap, split.wide)):
         for lo in range(0, len(rows), MAX_ROWS_WIDE):
             chunk = rows[lo:lo + MAX_ROWS_WIDE]
             if pargs is None:
                 pargs = (jnp.asarray(pidx), jnp.asarray(pbuf))
-            res = ev.gathered_evaluator(spec, m, p, *req)(
+            res = ev.gathered_evaluator(spec, m, p, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain, *pargs,
-                jnp.asarray(_pad_idx(chunk)))
+                jnp.asarray(_pad_idx(chunk)), rq)
             pending.append((res, chunk))
     for res, sel in pending:
         _append_winner(out, res, sel, patches, ctx)
@@ -1643,8 +1653,9 @@ def plan_normal_fused(cluster, workload: WorkloadSpec):
         aux_d, pbuf_d = _empty_patch_args(dcs.cap)
     else:
         aux_d, pbuf_d = jnp.asarray(pidx), jnp.asarray(pbuf)
-    res = ev.normal_evaluator(spec, p, ng, nc, cpb)(dcs.nodestate, aux_d,
-                                                    pbuf_d)
+    res = ev.normal_evaluator(spec, p)(dcs.nodestate, aux_d, pbuf_d,
+                                       request_vector(workload.priority, ng,
+                                                      nc, cpb))
     found, node, tier, gm, cm = (int(x) for x in jax.device_get(res))
     if not found:
         return None
@@ -1691,12 +1702,13 @@ def _plan_fused_shortlist(cluster, workload: WorkloadSpec,
     spec = cluster.spec
     thresh = workload.priority
     ng, nc, cpb = _req_scalars(spec, workload)
-    req = (thresh, ng, nc, cpb, float(alpha))
+    alpha = float(alpha)
+    rq = request_vector(thresh, ng, nc, cpb)
     split, f, aux_d, pbuf_d = _shortlist_plan_args(dcs, patches, thresh,
                                                    p, pidx, pbuf)
     rep_dev = dcs.rep_classes()[1]
-    res = ev.shortlist_plan_evaluator(spec, shortlist.k, p, f, *req)(
-        dcs.nodestate, dcs.victims, dcs.drain, rep_dev, aux_d, pbuf_d)
+    res = ev.shortlist_plan_evaluator(spec, shortlist.k, p, f, alpha)(
+        dcs.nodestate, dcs.victims, dcs.drain, rep_dev, aux_d, pbuf_d, rq)
     vals = [int(x) for x in jax.device_get(res)]
     if (not vals[0] and not vals[-1]
             and shortlist.mode == "guaranteed"):
@@ -1706,21 +1718,21 @@ def _plan_fused_shortlist(cluster, workload: WorkloadSpec,
     sel = {vals[6]: vals[5 + WIN_FIELDS]}
 
     def shortlist_out():
-        out = CandidateShortlist(_overflow_candidates(cluster, workload,
-                                                      split.overflow))
+        out = CandidateShortlist(_overflow_candidates(
+            cluster, workload, split.overflow, dcs.fallbacks))
         out.n_candidates = len(out)
         return out
 
     def wide_chunks():
         for lo in range(0, len(split.wide), MAX_ROWS_WIDE):
             chunk = split.wide[lo:lo + MAX_ROWS_WIDE]
-            yield ev.gathered_evaluator(spec, ctx.cap, p, *req)(
+            yield ev.gathered_evaluator(spec, ctx.cap, p, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain,
                 jnp.asarray(pidx), jnp.asarray(pbuf),
-                jnp.asarray(_pad_idx(chunk))), chunk
+                jnp.asarray(_pad_idx(chunk)), rq), chunk
 
     return _finalize_plan(vals[:5 + WIN_FIELDS], sel, patches, ctx,
-                          shortlist_out, wide_chunks, float(alpha))
+                          shortlist_out, wide_chunks, alpha)
 
 
 def plan_fused(cluster, workload: WorkloadSpec, alpha: float = DEFAULT_ALPHA,
@@ -1765,19 +1777,21 @@ def plan_fused(cluster, workload: WorkloadSpec, alpha: float = DEFAULT_ALPHA,
         if got is not None:
             return got
         # guaranteed-mode certainty check failed: full sweep decides
+        dcs.fallbacks["shortlist_full_sweep"] += 1
     split, g, aux_d, pbuf_d = _fast_plan_args(dcs, patches, thresh,
                                               p, pidx, pbuf)
     mid = split.mid
-    req = (thresh, ng, nc, cpb, float(alpha))
-    res = ev.plan_evaluator(spec, split.m_res, p, g, *req)(
-        dcs.nodestate, dcs.victims, dcs.drain, aux_d, pbuf_d)
+    alpha = float(alpha)
+    rq = request_vector(thresh, ng, nc, cpb)
+    res = ev.plan_evaluator(spec, split.m_res, p, g, alpha)(
+        dcs.nodestate, dcs.victims, dcs.drain, aux_d, pbuf_d, rq)
     vals = [int(x) for x in jax.device_get(res)]
     n = dcs.n_rows
     sel = {n + j: node for j, node in enumerate(mid)} if mid else None
 
     def shortlist():
-        out = CandidateShortlist(_overflow_candidates(cluster, workload,
-                                                      split.overflow))
+        out = CandidateShortlist(_overflow_candidates(
+            cluster, workload, split.overflow, dcs.fallbacks))
         out.n_candidates = len(out)
         return out
 
@@ -1786,10 +1800,10 @@ def plan_fused(cluster, workload: WorkloadSpec, alpha: float = DEFAULT_ALPHA,
         # to have failed — they are unreachable work otherwise
         for lo in range(0, len(split.wide), MAX_ROWS_WIDE):
             chunk = split.wide[lo:lo + MAX_ROWS_WIDE]
-            yield ev.gathered_evaluator(spec, ctx.cap, p, *req)(
+            yield ev.gathered_evaluator(spec, ctx.cap, p, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain,
                 jnp.asarray(pidx), jnp.asarray(pbuf),
-                jnp.asarray(_pad_idx(chunk))), chunk
+                jnp.asarray(_pad_idx(chunk)), rq), chunk
 
     return _finalize_plan(vals, sel, patches, ctx, shortlist, wide_chunks,
                           alpha)
@@ -1910,13 +1924,14 @@ class BatchSourcingSession:
         # section (mid) or the chunked 2^cap re-dispatch (wide)
         (delta, patches, mid, wide, overflow, d_over, d_wide,
          d_dense) = self._route(view, thresh)
-        out = CandidateShortlist(_overflow_candidates(view, workload,
-                                                      overflow))
+        out = CandidateShortlist(_overflow_candidates(
+            view, workload, overflow, self.dcs.fallbacks))
         out.n_candidates = len(out)
-        req = (thresh, ng, nc, cpb, self.alpha)
+        rq = request_vector(thresh, ng, nc, cpb)
         pending = []     # dispatches are async: launch all, decode once
         if d_over:       # delta rows that cannot ride the merged dispatch
-            extra = _overflow_candidates(view, workload, d_over)
+            extra = _overflow_candidates(view, workload, d_over,
+                                         self.dcs.fallbacks)
             out.extend(extra)
             out.n_candidates += len(extra)
         # ONE dispatch: request i's class tensors minus its delta rows,
@@ -1932,9 +1947,9 @@ class BatchSourcingSession:
             aux_d = jnp.asarray(np.concatenate([didx, pidx, gidx]))
             pbuf_d = jnp.asarray(pbuf)
         res = self.ev.batch_merge_evaluator(self.spec, NARROW_M, len(didx),
-                                            len(gidx), *req)(
+                                            len(gidx), self.alpha)(
             *self.class_data, self.dcs.nodestate, self.dcs.victims,
-            self.dcs.drain, jnp.int32(i), aux_d, pbuf_d)
+            self.dcs.drain, jnp.int32(i), aux_d, pbuf_d, rq)
         sel = {n + j: node for j, node in enumerate(gather)}
         pending.append((res, sel))
         # wide rows (9..16 eligible victims): chunked 2^cap dispatches —
@@ -1946,9 +1961,10 @@ class BatchSourcingSession:
             rows = d_wide + wide
             for lo in range(0, len(rows), MAX_ROWS_WIDE):
                 chunk = rows[lo:lo + MAX_ROWS_WIDE]
-                res = self.ev.gathered_evaluator(self.spec, cap, pw, *req)(
+                res = self.ev.gathered_evaluator(self.spec, cap, pw,
+                                                 self.alpha)(
                     self.dcs.nodestate, self.dcs.victims, self.dcs.drain,
-                    *pargs, jnp.asarray(_pad_idx(chunk)))
+                    *pargs, jnp.asarray(_pad_idx(chunk)), rq)
                 pending.append((res, chunk))
         for res, sel in pending:
             _append_winner(out, res, sel, patches, ctx)
@@ -1981,20 +1997,21 @@ class BatchSourcingSession:
         else:
             aux_d = jnp.asarray(np.concatenate([didx, pidx, gidx]))
             pbuf_d = jnp.asarray(pbuf)
-        req = (thresh, ng, nc, cpb, self.alpha)
+        rq = request_vector(thresh, ng, nc, cpb)
         res = self.ev.batch_plan_evaluator(self.spec, NARROW_M, len(didx),
-                                           len(gidx), p, *req)(
+                                           len(gidx), p, self.alpha)(
             *self.class_data, self.dcs.nodestate, self.dcs.victims,
-            self.dcs.drain, jnp.int32(i), aux_d, pbuf_d)
+            self.dcs.drain, jnp.int32(i), aux_d, pbuf_d, rq)
         vals = [int(x) for x in jax.device_get(res)]
         sel = {n + j: node for j, node in enumerate(gather)}
 
         def shortlist():
-            out = CandidateShortlist(_overflow_candidates(view, workload,
-                                                          overflow))
+            out = CandidateShortlist(_overflow_candidates(
+                view, workload, overflow, self.dcs.fallbacks))
             out.n_candidates = len(out)
             if d_over:
-                extra = _overflow_candidates(view, workload, d_over)
+                extra = _overflow_candidates(view, workload, d_over,
+                                             self.dcs.fallbacks)
                 out.extend(extra)
                 out.n_candidates += len(extra)
             return out
@@ -2010,9 +2027,10 @@ class BatchSourcingSession:
             rows = d_wide + wide
             for lo in range(0, len(rows), MAX_ROWS_WIDE):
                 chunk = rows[lo:lo + MAX_ROWS_WIDE]
-                yield self.ev.gathered_evaluator(self.spec, cap, pw, *req)(
+                yield self.ev.gathered_evaluator(self.spec, cap, pw,
+                                                 self.alpha)(
                     self.dcs.nodestate, self.dcs.victims, self.dcs.drain,
-                    *pargs, jnp.asarray(_pad_idx(chunk))), chunk
+                    *pargs, jnp.asarray(_pad_idx(chunk)), rq), chunk
 
         return _finalize_plan(vals, sel, patches, ctx, shortlist,
                               wide_chunks, self.alpha)
@@ -2070,8 +2088,8 @@ def warmup_fused(cluster: Cluster, alpha: float = DEFAULT_ALPHA,
     first plans (cold P90 is compile-dominated otherwise).
 
     ``workloads`` defaults to the Table 3 classes; pass the deployment's
-    own preemptor classes when they differ (the single-request programs
-    specialize per request).
+    own preemptor classes when they differ (the request is traced, but
+    each class's priority picks its own slot-width and gather buckets).
     """
     from .workload import table3_workloads
 

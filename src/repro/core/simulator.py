@@ -17,6 +17,7 @@ import random
 import time
 
 import numpy as np
+from jax import monitoring
 
 from .cluster import Cluster
 from .placement import Placement
@@ -24,6 +25,11 @@ from .scheduler import EngineName, TopoScheduler
 from .topology import RTX4090_SERVER, ServerSpec
 from .workload import (TABLE3_INITIAL_INSTANCES, WorkloadSpec,
                        table3_workloads)
+
+
+#: `jax.monitoring` events `CompileWatch` listens for
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
 @dataclasses.dataclass
@@ -38,23 +44,28 @@ class CompileWatch:
     """Counts XLA backend compiles so timed samples that secretly pay
     compile time (a cold jit bucket hit mid-run) can be tagged instead of
     polluting the latency distribution.  Install once per process; ``mark``
-    /``delta`` bracket a timed region."""
+    /``delta`` bracket a timed region.
+
+    ``count`` counts every executable JAX builds, including those loaded
+    from the persistent compilation cache; ``cache_hits`` counts the
+    loads, so ``count - cache_hits`` programs were compiled from scratch."""
 
     _installed: "CompileWatch | None" = None
 
     def __init__(self) -> None:
         self.count = 0
+        self.cache_hits = 0
 
-        def _cb(event: str, duration: float, **kw) -> None:
-            if event.endswith("backend_compile_duration"):
+        def _on_duration(event: str, duration: float, **kw) -> None:
+            if event == BACKEND_COMPILE_EVENT:
                 self.count += 1
 
-        try:
-            from jax import monitoring
+        def _on_event(event: str, **kw) -> None:
+            if event == CACHE_HIT_EVENT:
+                self.cache_hits += 1
 
-            monitoring.register_event_duration_secs_listener(_cb)
-        except Exception:
-            pass    # no jax (host-only engines): every delta reads 0
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
 
     @classmethod
     def get(cls) -> "CompileWatch":
@@ -228,6 +239,40 @@ def build_saturated_cluster(cfg: SimConfig,
     cluster = Cluster(cfg.spec, cfg.num_nodes)
     saturate(cluster, workloads, counts, random.Random(cfg.seed))
     return cluster
+
+
+#: tiling block of `build_scaled_cluster` for sizes above it
+SCALE_BASE_NODES = 128
+
+
+def build_scaled_cluster(num_nodes: int, seed: int = 0,
+                         fill: float = 1.0) -> Cluster:
+    """A saturated (or ``fill``-fraction) Table 3 cluster at any node count.
+
+    Up to `SCALE_BASE_NODES` the regular seeded random saturation runs
+    directly; larger sizes replay a base pattern of that size per block
+    (``num_nodes`` a multiple of it) so construction stays O(num_nodes)
+    instead of O(num_nodes^2).
+    """
+    base_nodes = min(num_nodes, SCALE_BASE_NODES)
+    cfg = SimConfig(num_nodes=base_nodes, seed=seed)
+    if fill >= 1.0:
+        base = build_saturated_cluster(cfg)
+    else:
+        scale = base_nodes / 100.0 * fill
+        counts = {k: max(0, round(v * scale))
+                  for k, v in TABLE3_INITIAL_INSTANCES.items()}
+        base = build_saturated_cluster(cfg, table3_workloads(), counts)
+    if num_nodes == base_nodes:
+        return base
+    big = Cluster(base.spec, num_nodes)
+    for blk in range(num_nodes // base_nodes):
+        off = blk * base_nodes
+        for inst in base.instances.values():
+            big.bind(inst.workload, inst.node + off,
+                     Placement(gpu_mask=inst.gpu_mask,
+                               cg_mask=inst.cg_mask, tier=0))
+    return big
 
 
 # ---------------------------------------------------------------------------------

@@ -9,8 +9,8 @@ computed per q_block, not masked per-element).
 
 MXU alignment: block_q × head_dim and block_k × head_dim tiles with
 block_q = block_k = 128 by default (multiples of the 128-lane MXU).
-Validated against ``ref.mha_ref`` in interpret mode (CPU container); set
-``interpret=False`` on real TPU.
+Validated against ``ref.mha_ref``; ``interpret=None`` (the default) runs the
+Mosaic interpreter iff the backend is not a TPU, as the other kernels do.
 """
 from __future__ import annotations
 
@@ -19,6 +19,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .topo_score import _interpret_default
 
 NEG_INF = -1e30
 
@@ -46,10 +48,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q: int, block_k: int,
     def body(kb, carry):
         m, l, acc = carry
         k_start = kb * block_k
-        k = pl.load(k_ref, (pl.dslice(k_start, block_k), slice(None))
-                    ).astype(jnp.float32)               # [block_k, d]
-        v = pl.load(v_ref, (pl.dslice(k_start, block_k), slice(None))
-                    ).astype(jnp.float32)
+        k = k_ref[pl.ds(k_start, block_k), :].astype(jnp.float32)  # [bk, d]
+        v = v_ref[pl.ds(k_start, block_k), :].astype(jnp.float32)
         s = q @ k.T                                     # [block_q, block_k]
         rows = q_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
@@ -83,8 +83,10 @@ def flash_attention(
     window: int | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
+    if interpret is None:
+        interpret = _interpret_default()
     B, H, Sq, d = q.shape
     K = k.shape[1]
     Sk = k.shape[2]

@@ -27,15 +27,14 @@ Three kernels share the tier/score math:
 Layout: subsets are padded to (rows, 128) int32.  Outputs: tier (0/1/2,
 3 = infeasible) and the Eq. 1 score (-inf where infeasible).
 
-``interpret`` resolution: the Mosaic interpreter is required off-TPU.  Pass
-``interpret=None`` (default) to auto-detect (interpret unless the JAX
-backend is TPU), or force it with the ``REPRO_PALLAS_INTERPRET`` env var
-("1"/"0"/"auto").
+``interpret=None`` (the default) runs the Mosaic interpreter iff the JAX
+backend is not a TPU (`_interpret_default`): no setting puts a kernel in
+interpret mode on a TPU.  ``interpret=False`` compiles for a TPU whatever
+the backend (the compile rehearsals in tests/test_tpu_compile.py).
 """
 from __future__ import annotations
 
 import dataclasses
-import os
 from functools import partial
 
 import jax
@@ -55,12 +54,7 @@ K_INFEASIBLE = np.int32(2**30)
 
 
 def _interpret_default() -> bool:
-    """Resolve the Mosaic-interpreter flag: env override, else backend."""
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "auto").lower()
-    if env in ("1", "true", "yes"):
-        return True
-    if env in ("0", "false", "no"):
-        return False
+    """Mosaic interpreter iff the backend is not a TPU."""
     return jax.default_backend() != "tpu"
 
 
@@ -153,20 +147,24 @@ def _argmax_kernel(combo_gpu_ref, combo_cg_ref, prio_ref, k_ref, ok_ref,
     k = k_ref[...]
     feas = tier < 3
     big = jnp.int32(K_INFEASIBLE)
+    # per-tile scalars go out as lane-dense (1, LANES) rows (a rank-1
+    # block of one element per tile is refused by the TPU lowering)
     kmin = jnp.min(jnp.where(feas, k, big))
-    kmin_ref[0] = kmin
+    kmin_ref[...] = jnp.full(kmin_ref.shape, kmin, jnp.int32)
     sel = feas & (k == kmin)
     tmin = jnp.min(jnp.where(sel, tier, 3))
-    btier_ref[0] = tmin
+    btier_ref[...] = jnp.full(btier_ref.shape, tmin, jnp.int32)
     sel &= tier == tmin
     smax = jnp.max(jnp.where(sel, score, -jnp.inf))
-    bscore_ref[0] = smax
+    bscore_ref[...] = jnp.full(bscore_ref.shape, smax, jnp.float32)
     sel &= score == smax
     rows, lanes = k.shape
     flat = (jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 0) * lanes
             + jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1))
     local = jnp.min(jnp.where(sel, flat, big))
-    bidx_ref[0] = pl.program_id(0) * (rows * lanes) + local
+    bidx_ref[...] = jnp.full(bidx_ref.shape,
+                             pl.program_id(0) * (rows * lanes) + local,
+                             jnp.int32)
 
 
 def _tiled(x, fill, n_pad, tile):
@@ -181,7 +179,7 @@ def topo_score_pallas(
     prio: jnp.ndarray,
     spec: ServerSpec,
     req: TopoRequest,
-    interpret: bool | None = None,   # None: auto (env/backend detection)
+    interpret: bool | None = None,   # None: interpret iff not on a TPU
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (tier int32[n], score f32[n])."""
     if interpret is None:
@@ -251,7 +249,7 @@ def topo_score_argmax_pallas(
 
     n_tiles = n_pad // tile
     blk = pl.BlockSpec((None, ROWS_PER_TILE, LANES), lambda i: (i, 0, 0))
-    scl = pl.BlockSpec((1,), lambda i: (i,))
+    scl = pl.BlockSpec((None, 1, LANES), lambda i: (i, 0, 0))
     kernel = partial(_argmax_kernel, spec=spec, req=req)
     tier, score, kmin, btier, bscore, bidx = pl.pallas_call(
         kernel,
@@ -261,14 +259,15 @@ def topo_score_argmax_pallas(
         out_shape=[
             jax.ShapeDtypeStruct(cg2.shape, jnp.int32),
             jax.ShapeDtypeStruct(cg2.shape, jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles,), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles,), jnp.int32),
-            jax.ShapeDtypeStruct((n_tiles,), jnp.float32),
-            jax.ShapeDtypeStruct((n_tiles,), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles, 1, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles, 1, LANES), jnp.int32),
+            jax.ShapeDtypeStruct((n_tiles, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles, 1, LANES), jnp.int32),
         ],
         interpret=interpret,
     )(cg2, cc2, pr2, kk2, ok2)
-    return tier.reshape(-1)[:n], score.reshape(-1)[:n], kmin, btier, bscore, bidx
+    return (tier.reshape(-1)[:n], score.reshape(-1)[:n], kmin[:, 0, 0],
+            btier[:, 0, 0], bscore[:, 0, 0], bidx[:, 0, 0])
 
 
 def _place_tier_kernel(free_gpu_ref, free_cg_ref, tier_ref, *,

@@ -146,7 +146,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
         t1 = time.time()
         compiled = lowered.compile()
         t2 = time.time()
-        ca = hlo_util.cost_dict(compiled)
+        ca = compiled.cost_analysis()
         ma = compiled.memory_analysis()
         txt = compiled.as_text()
         rec.update(
@@ -206,7 +206,7 @@ def run_scheduler_cell(mesh_kind: str, out_dir: str, force: bool = False) -> dic
         rec.update(status="ok", compile_s=round(time.time() - t0, 2),
                    memory=_memory_dict(compiled.memory_analysis()),
                    cost={k: float(v) for k, v in
-                         hlo_util.cost_dict(compiled).items()
+                         compiled.cost_analysis().items()
                          if k in ("flops", "bytes accessed")},
                    hlo=hlo_util.summarize(compiled.as_text()))
         t0 = time.time()

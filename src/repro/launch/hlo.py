@@ -99,16 +99,6 @@ def summarize(hlo_text: str) -> dict:
     }
 
 
-def cost_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized across jaxlib versions: newer
-    jaxlibs return the properties dict directly, older ones a one-element
-    list of dicts (one per partition)."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
-
-
 # ---------------------------------------------------------------------------------
 # Full module walk: loop-trip-scaled FLOPs and collective bytes.
 #

@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.jax_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import Request, ServeEngine
 
@@ -28,6 +29,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     api = build_model(cfg)
     params = api.init(jax.random.PRNGKey(args.seed))

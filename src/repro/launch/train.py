@@ -104,6 +104,9 @@ def run(args) -> dict:
 
 
 def main() -> None:
+    from repro.jax_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--smoke", action="store_true", default=True)

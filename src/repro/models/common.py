@@ -209,29 +209,6 @@ def get_mesh():
     return _MESH
 
 
-def shard_map_compat(f, mesh, *, in_specs, out_specs, axis_names=None):
-    """``jax.shard_map`` landed as a top-level API only on newer jax; older
-    jaxlibs expose ``jax.experimental.shard_map.shard_map``, which takes the
-    complement ``auto=`` set instead of ``axis_names=`` (and needs
-    ``check_rep=False`` when any axis stays automatic)."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map
-
-    # check_rep's replication tracking predates scan-carry support (the
-    # error message itself prescribes disabling it) — correctness is still
-    # covered by the equivalence tests.
-    kw = {"check_rep": False}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - set(axis_names)
-        if auto:
-            kw["auto"] = auto
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **kw)
-
-
 def constrain_batch(x: jnp.ndarray) -> jnp.ndarray:
     """Anchor: dim 0 sharded over the declared batch axes, rest unconstrained."""
     if _BATCH_AXES is None:

@@ -90,11 +90,9 @@ def moe_apply(p, cfg: ModelConfig, x):
                 aux = jax.tree.map(lambda a: jax.lax.pmean(a, axes), aux)
                 return out, aux
 
-            from .common import shard_map_compat
-
-            fn = shard_map_compat(
+            fn = jax.shard_map(
                 body,
-                get_mesh(),
+                mesh=get_mesh(),
                 in_specs=(jax.tree.map(lambda _: P(), p),
                           P(axes, None, None)),
                 out_specs=(P(axes, None, None), P()),

@@ -76,10 +76,8 @@ def make_pipelined_fn(stage_fn, mesh: Mesh, n_stages: int,
         # only the last stage holds real outputs; make them global
         return jax.lax.psum(outs, axis)
 
-    from repro.models.common import shard_map_compat
-
-    return shard_map_compat(
-        inner, mesh,
+    return jax.shard_map(
+        inner, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
         axis_names={axis},

@@ -72,8 +72,7 @@ def make_pod_sync(mesh: Mesh, pod_axis: str = "pod"):
     def sync(params, anchor, err, param_specs):
         in_specs = jax.tree.map(lambda s: s.spec if hasattr(s, "spec") else s,
                                 param_specs)
-        from jax.experimental.shard_map import shard_map
-        fn = shard_map(
+        fn = jax.shard_map(
             _sync, mesh=mesh,
             in_specs=(in_specs, in_specs, in_specs),
             out_specs=(in_specs, in_specs),

@@ -27,6 +27,7 @@ def test_small_mesh_train_step_runs():
     """Real sharded execution (not just lowering) on a 4x2 host mesh."""
     print(run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import auto_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_config
         from repro.models import build_model
@@ -34,7 +35,7 @@ def test_small_mesh_train_step_runs():
         from repro import sharding as shd
         from repro.train import TrainConfig, init_train_state, make_train_step
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = auto_mesh((4, 2), ("data", "model"))
         cfg = get_config("llama3.2-1b", smoke=True)
         api = build_model(cfg)
         set_batch_axes(("data",))
@@ -62,6 +63,7 @@ def test_dryrun_cell_multi_pod_small():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         import repro.launch.dryrun as dr
+        from repro.launch.mesh import auto_mesh
         from repro.configs import get_config
         from repro.models import build_model
         from repro.models.common import set_batch_axes
@@ -69,7 +71,7 @@ def test_dryrun_cell_multi_pod_small():
         from repro.train import TrainConfig, make_train_step, train_state_specs
         from repro.configs.shapes import ShapeSpec, batch_specs
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = auto_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = get_config("qwen3-8b", smoke=True)
         api = build_model(cfg)
         set_batch_axes(shd._batch_axes_for(mesh, 8))
@@ -97,10 +99,11 @@ def test_pod_sync_int8_compression():
     feedback keeps long-run bias near zero."""
     print(run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import auto_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.train.sync import make_pod_sync, init_error_state, quantize_int8, dequantize_int8
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = auto_mesh((2, 2, 2), ("pod", "data", "model"))
         sync = make_pod_sync(mesh)
         # params differ per pod: shard a leading axis of 2 over "pod"
         base = np.linspace(-1, 1, 2 * 4 * 4).reshape(2, 4, 4).astype(np.float32)
@@ -127,13 +130,14 @@ def test_cluster_parallel_sourcing_executes():
     unsharded argmax."""
     print(run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import auto_mesh
         from repro.core.cluster_parallel import (make_distributed_source,
             distributed_source_inputs, _source_best)
         from repro.core.preemption_jax import Request
         from repro.core.topology import RTX4090_SERVER
         from functools import partial
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = auto_mesh((4, 2), ("data", "model"))
         req = Request(need_gpus=4, need_cgs=4, bundle_locality=True)
         args = distributed_source_inputs(RTX4090_SERVER, 256, 8, 2, req,
                                          rng=np.random.default_rng(3))

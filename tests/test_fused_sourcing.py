@@ -494,13 +494,14 @@ def test_pallas_engine_parity_with_mixed_eligibility():
 
 
 def test_pallas_interpret_env_flag(monkeypatch):
-    from repro.kernels import topo_score
-
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert topo_score._interpret_default() is True
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert topo_score._interpret_default() is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "auto")
+    """Interpret mode follows the backend alone: the environment can no
+    longer force the Mosaic interpreter onto a TPU (nor compiled kernels
+    onto the CPU)."""
     import jax
 
-    assert topo_score._interpret_default() is (jax.default_backend() != "tpu")
+    from repro.kernels import topo_score
+
+    want = jax.default_backend() != "tpu"
+    for value in ("1", "0", "auto", "true"):
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", value)
+        assert topo_score._interpret_default() is want

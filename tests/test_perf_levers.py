@@ -91,7 +91,7 @@ def test_hlo_walker_scales_scan_bodies():
     stats = hlo.walk_stats(compiled.as_text())
     assert stats["flops_scaled"] == 5 * 2 * 64 ** 3
     # raw cost_analysis counts the body once — the reason the walker exists
-    assert hlo.cost_dict(compiled)["flops"] < stats["flops_scaled"]
+    assert compiled.cost_analysis()["flops"] < stats["flops_scaled"]
 
 
 def test_collective_parser_on_sharded_module():
@@ -108,7 +108,8 @@ def test_collective_parser_on_sharded_module():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch import hlo
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((4,), ("data",))
         sh = NamedSharding(mesh, P("data"))
         f = jax.jit(lambda x: x.sum(), in_shardings=(sh,))
         c = f.lower(jax.ShapeDtypeStruct((64, 8), jnp.float32)).compile()

@@ -14,11 +14,12 @@ def test_gpipe_matches_sequential():
     env["PYTHONPATH"] = SRC
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import auto_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.train.pipeline import make_pipelined_fn
 
         S, M, mb, d = 4, 8, 2, 16
-        mesh = jax.make_mesh((S,), ("stage",))
+        mesh = auto_mesh((S,), ("stage",))
         rng = np.random.default_rng(0)
         # each stage: one dense layer + tanh
         ws = jnp.asarray(rng.standard_normal((S, d, d)) * 0.3, jnp.float32)
@@ -35,7 +36,7 @@ def test_gpipe_matches_sequential():
             return jax.vmap(per_mb)(xs)
 
         pipe = make_pipelined_fn(stage_fn, mesh, S)
-        with mesh:
+        with jax.set_mesh(mesh):
             got = jax.jit(pipe)(ws, xs)
         want = sequential(ws, xs)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -43,11 +44,11 @@ def test_gpipe_matches_sequential():
 
         # gradients flow through the schedule (ppermute is differentiable)
         def loss_pipe(ws):
-            with mesh:
-                return jnp.sum(jax.jit(pipe)(ws, xs) ** 2)
+            return jnp.sum(jax.jit(pipe)(ws, xs) ** 2)
         def loss_seq(ws):
             return jnp.sum(sequential(ws, xs) ** 2)
-        g1 = jax.grad(loss_pipe)(ws)
+        with jax.set_mesh(mesh):
+            g1 = jax.grad(loss_pipe)(ws)
         g2 = jax.grad(loss_seq)(ws)
         np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
                                    atol=1e-4, rtol=1e-4)

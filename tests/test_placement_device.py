@@ -114,6 +114,56 @@ def test_normal_cycle_parity_under_commit_rollback(seed):
     assert seqs["imp"] == seqs["imp_batched"], seqs
 
 
+def _normal_cycle_static_and_traced(nodestate, spec, ng, nc, cpb):
+    """`normal_cycle_core` jitted with the request baked in as constants
+    and with it traced: the two compile differently and must agree."""
+    import jax
+
+    from repro.core.placement_jax import normal_cycle_core
+
+    static = jax.jit(
+        lambda x: normal_cycle_core(x, ng, nc, cpb, spec=spec))(nodestate)
+    traced = jax.jit(
+        lambda x, a, b, c: normal_cycle_core(x, a, b, c, spec=spec))(
+        nodestate, ng, nc, cpb)
+    return [int(v) for v in static], [int(v) for v in traced]
+
+
+def test_normal_cycle_static_vs_traced_request():
+    """Seed 2, 5 nodes, after committing C then B on host imp: the normal
+    cycle for D (request (1, 1, 0)) places on node 2 with masks (2, 2).
+    XLA:CPU once compiled the constant-request program to gpu_mask 0 while
+    the traced one (and host ``place()``) gave 2."""
+    sched = TopoScheduler(_partial_cluster(2, nodes=5), engine="imp")
+    for name in ("C", "B"):
+        sched.plan(WL3[name]).commit()
+    host = sched.plan(WL3["D"]).decision
+    assert host.kind == "placed"
+    spec = sched.cluster.spec
+    nodestate = sched.cluster.device_state().sync().nodestate
+    static, traced = _normal_cycle_static_and_traced(nodestate, spec, 1, 1, 0)
+    want = [1, host.node, host.placement.tier, host.placement.gpu_mask,
+            host.placement.cg_mask]
+    assert want == [1, 2, 0, 2, 2]
+    assert static == traced == want
+
+
+@pytest.mark.parametrize("req", [(1, 0, 0), (2, 4, 0), (3, 4, 0), (4, 4, 0),
+                                 (2, 4, 2)])
+def test_normal_cycle_static_vs_traced_random_state(req):
+    """Random free masks on 7 nodes: the constant-request and the
+    traced-request programs agree for asks that once compiled apart."""
+    spec = RTX4090_SERVER
+    rng = random.Random(0)
+    ns = np.zeros((5, 7), np.int32)
+    for j in range(7):
+        ns[0, j] = rng.randrange(0, spec.all_gpu_mask + 1)
+        ns[1, j] = rng.randrange(0, spec.all_cg_mask + 1)
+        ns[2, j] = j
+    static, traced = _normal_cycle_static_and_traced(ns, spec, *req)
+    assert static == traced, (req, static, traced)
+
+
 def test_fused_plan_falls_through_to_preemption_with_masks():
     """Saturated cluster: the chained dispatch must take the preemptive
     branch and return the same victims AND placement masks as imp."""
