@@ -56,7 +56,6 @@ def _measure(engine: str, node_index: bool, nodes: int = 100,
     sourcing, total = [], []
     # warm up jit caches so compile time isn't counted as scheduling latency
     sched.plan(wls[preemptor])
-    sched.sourcing_us_log.clear()
     for _ in range(samples):
         t0 = time.perf_counter()
         dec = sched.plan(wls[preemptor]).decision   # rollback-free read

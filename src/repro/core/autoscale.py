@@ -98,9 +98,9 @@ class Autoscaler:
         cluster.add_inst_listener(self._index)
         #: amortized per-request wall time of every ``plan_batch`` issued
         #: through this autoscaler — one entry per planned request, the
-        #: SAME metric for host and fused engines (unlike the scheduler's
-        #: ``sourcing_us_log``, which host engines append to only on
-        #: preemptive plans)
+        #: SAME metric for host and fused engines (unlike a decision's
+        #: ``sourcing_us``, which host engines record only on preemptive
+        #: plans)
         self.plan_us: list[float] = []
 
     def _timed_plan_batch(self, workloads, allow_preempt: bool = True,

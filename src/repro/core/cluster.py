@@ -21,6 +21,7 @@ from typing import Callable
 
 import numpy as np
 
+from .. import obs
 from .flextopo import FlexTopo
 from .placement import Placement
 from .topology import ServerSpec
@@ -782,9 +783,7 @@ def _scatter_rows(ns, v, dr, idx, buf):
     from a single flattened upload buffer."""
     global _SCATTER_JIT
     if _SCATTER_JIT is None:
-        import jax
-
-        _SCATTER_JIT = jax.jit(apply_rows)
+        _SCATTER_JIT = obs.program("apply_rows", apply_rows)
     return _SCATTER_JIT(ns, v, dr, idx, buf)
 
 
@@ -890,9 +889,8 @@ def delta_encoder(cap: int, a: int):
     if fn is None:
         import functools
 
-        import jax
-
-        fn = jax.jit(functools.partial(encode_delta_core, cap=cap, a=a))
+        fn = obs.program("delta_encode",
+                         functools.partial(encode_delta_core, cap=cap, a=a))
         _DELTA_ENCODERS[key] = fn
     return fn
 
@@ -1033,8 +1031,6 @@ class ViewDelta:
         """Encode every dense delta row on device: returns ``(didx
         int32[Dp], buf int32[Dp, width])`` pow2-padded, buf still on
         device.  Empty when all delta nodes fell back."""
-        import jax.numpy as jnp
-
         d = len(self.dense)
         if d == 0:
             return None
@@ -1047,13 +1043,12 @@ class ViewDelta:
             width = ((0, dp - d),) + ((0, 0),) * (x.ndim - 1)
             return np.pad(x, width)
 
+        up = obs.upload
         buf = dcs.delta_encode(
-            self.a, jnp.asarray(didx),
-            jnp.asarray(pad(self.rem)), jnp.asarray(pad(self.fog)),
-            jnp.asarray(pad(self.foc)), jnp.asarray(pad(self.og)),
-            jnp.asarray(pad(self.oc)), jnp.asarray(pad(self.addg)),
-            jnp.asarray(pad(self.addc)), jnp.asarray(pad(self.addp)),
-            jnp.asarray(pad(self.addv)))
+            self.a, up(didx),
+            up(pad(self.rem)), up(pad(self.fog)), up(pad(self.foc)),
+            up(pad(self.og)), up(pad(self.oc)), up(pad(self.addg)),
+            up(pad(self.addc)), up(pad(self.addp)), up(pad(self.addv)))
         return didx, buf
 
 
@@ -1139,22 +1134,25 @@ class DeviceClusterState:
         separate scatter dispatch on the plan hot path.  Large pending sets
         are flushed regardless so the overlay bucket stays small.
         """
-        self.mirror.refresh()
-        n = self.cluster.num_nodes
-        if self.nodestate is None or 2 * len(self._dirty) >= max(n, 2):
-            ns, v, dr = pack_context_rows(self.mirror, np.arange(n))
-            self.nodestate, self.victims, self.drain = self._upload_full(
-                ns, v, dr)
-            self._dirty.clear()
-        elif self._dirty and (flush or len(self._dirty) > self.max_pending):
-            rows = sorted(self._dirty)
-            buf = flatten_rows(*pack_context_rows(self.mirror, rows))
-            idx = pad_idx(rows)
-            if len(idx) > len(rows):
-                buf = np.pad(buf, ((0, len(idx) - len(rows)), (0, 0)))
-            self.nodestate, self.victims, self.drain = self._scatter(idx, buf)
-            self._dirty.clear()
-        self.count_max = int(self.mirror.count.max()) if n else 0
+        with obs.span("sched.sync"):
+            self.mirror.refresh()
+            n = self.cluster.num_nodes
+            if self.nodestate is None or 2 * len(self._dirty) >= max(n, 2):
+                ns, v, dr = pack_context_rows(self.mirror, np.arange(n))
+                self.nodestate, self.victims, self.drain = self._upload_full(
+                    ns, v, dr)
+                self._dirty.clear()
+            elif self._dirty and (flush
+                                  or len(self._dirty) > self.max_pending):
+                rows = sorted(self._dirty)
+                buf = flatten_rows(*pack_context_rows(self.mirror, rows))
+                idx = pad_idx(rows)
+                if len(idx) > len(rows):
+                    buf = np.pad(buf, ((0, len(idx) - len(rows)), (0, 0)))
+                self.nodestate, self.victims, self.drain = self._scatter(
+                    idx, buf)
+                self._dirty.clear()
+            self.count_max = int(self.mirror.count.max()) if n else 0
         return self
 
     @property
@@ -1191,22 +1189,16 @@ class DeviceClusterState:
     def _upload_rep(self, rep):
         """Representative-mask upload hook (sharded subclass lays the row
         axis out over the mesh to match the resident tensors)."""
-        import jax.numpy as jnp
-
-        return jnp.asarray(rep)
+        return obs.upload(rep)
 
     def _upload_full(self, ns, v, dr):
         """Full-rebuild upload hook (subclasses re-layout/shard here)."""
-        import jax.numpy as jnp
-
-        return jnp.asarray(ns), jnp.asarray(v), jnp.asarray(dr)
+        return obs.upload(ns), obs.upload(v), obs.upload(dr)
 
     def _scatter(self, idx, buf):
         """Dirty-row scatter hook (subclasses keep the output sharded)."""
-        import jax.numpy as jnp
-
         return _scatter_rows(self.nodestate, self.victims, self.drain,
-                             jnp.asarray(idx), jnp.asarray(buf))
+                             obs.upload(idx), obs.upload(buf))
 
     def delta_encode(self, a: int, didx, *descs):
         """Run the device-side view-delta encoder against the resident

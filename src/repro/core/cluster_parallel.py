@@ -28,6 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import obs
 from .cluster import (DRAIN_FIELDS, IDX_SENTINEL, NODE_FIELDS, NS_FREE_CG,
                       NS_FREE_GPU, NS_NODE_ID, VF_CG, VF_GPU, VICTIM_FIELDS,
                       DeviceClusterState, apply_rows, encode_delta_core)
@@ -174,8 +175,8 @@ def make_distributed_fused_source(
             jnp.int32(request.cgs_per_bundle), jnp.float32(alpha),
             spec=spec, m=m, g=0)
 
-    return jax.jit(
-        fn,
+    return obs.program(
+        "distributed_fused_source", fn,
         in_shardings=(node_sharding, victim_sharding, node_sharding, repl),
         out_shardings=repl,
     )
@@ -206,7 +207,8 @@ def make_distributed_normal_cycle(
             jnp.int32(request.need_cgs),
             jnp.int32(request.cgs_per_bundle), spec=spec)
 
-    return jax.jit(fn, in_shardings=(node_sharding,), out_shardings=repl)
+    return obs.program("distributed_normal_cycle", fn,
+                       in_shardings=(node_sharding,), out_shardings=repl)
 
 
 def lower_distributed_normal_cycle(
@@ -297,9 +299,10 @@ def _sharded_scatter(mesh):
     """jit of the dirty-row scatter with the resident tensors held sharded
     on both sides (the update rows replicate — they are O(dirty), tiny)."""
     node_sh, victim_sh, repl = _mesh_shardings(mesh)
-    return jax.jit(apply_rows,
-                   in_shardings=(node_sh, victim_sh, node_sh, repl, repl),
-                   out_shardings=(node_sh, victim_sh, node_sh))
+    return obs.program(
+        "apply_rows_sharded", apply_rows,
+        in_shardings=(node_sh, victim_sh, node_sh, repl, repl),
+        out_shardings=(node_sh, victim_sh, node_sh))
 
 
 @lru_cache(maxsize=None)
@@ -309,9 +312,10 @@ def _sharded_delta_encoder(mesh, cap: int, a: int):
     rows come back replicated (they are O(delta), tiny), so the base-row
     gather is the only cross-shard traffic."""
     node_sh, victim_sh, repl = _mesh_shardings(mesh)
-    return jax.jit(partial(encode_delta_core, cap=cap, a=a),
-                   in_shardings=(node_sh, victim_sh) + (repl,) * 10,
-                   out_shardings=repl)
+    return obs.program("delta_encode_sharded",
+                       partial(encode_delta_core, cap=cap, a=a),
+                       in_shardings=(node_sh, victim_sh) + (repl,) * 10,
+                       out_shardings=repl)
 
 
 class ShardedDeviceClusterState(DeviceClusterState):
@@ -349,14 +353,14 @@ class ShardedDeviceClusterState(DeviceClusterState):
                 axis=1)
             dr = np.concatenate(
                 [dr, np.zeros((DRAIN_FIELDS, pad), np.int32)], axis=1)
-        return (jax.device_put(np.ascontiguousarray(ns), self._node_sh),
-                jax.device_put(np.ascontiguousarray(v), self._victim_sh),
-                jax.device_put(np.ascontiguousarray(dr), self._node_sh))
+        return (obs.upload(np.ascontiguousarray(ns), self._node_sh),
+                obs.upload(np.ascontiguousarray(v), self._victim_sh),
+                obs.upload(np.ascontiguousarray(dr), self._node_sh))
 
     def _scatter(self, idx, buf):
         return _sharded_scatter(self.mesh)(
             self.nodestate, self.victims, self.drain,
-            jnp.asarray(idx), jnp.asarray(buf))
+            obs.upload(idx), obs.upload(buf))
 
     def delta_encode(self, a: int, didx, *descs):
         return _sharded_delta_encoder(self.mesh, self.cap, a)(
@@ -365,7 +369,7 @@ class ShardedDeviceClusterState(DeviceClusterState):
     def _upload_rep(self, rep):
         """Pin the rep mask 1-D row-sharded so the shortlist prescreen
         stays shard-local up to its top-K collective."""
-        return jax.device_put(
+        return obs.upload(
             np.ascontiguousarray(rep),
             NamedSharding(self.mesh, P(tuple(self.mesh.axis_names))))
 
@@ -402,7 +406,7 @@ class _ShardedEvaluators:
                     nodestate, victims, drain, aux, pbuf,
                     *_pj._req_args(req), alpha, spec=spec, m=m, p=p, g=g)
 
-            return jax.jit(f, in_shardings=(
+            return obs.program("resident_sweep_sharded", f, in_shardings=(
                 self.node_sh, self.victim_sh, self.node_sh, self.repl,
                 self.repl, self.repl), out_shardings=self.repl)
 
@@ -415,7 +419,7 @@ class _ShardedEvaluators:
                     nodestate, victims, drain, aux, pbuf,
                     *_pj._req_args(req), alpha, spec=spec, m=m, p=p, g=g)
 
-            return jax.jit(f, in_shardings=(
+            return obs.program("plan2_sharded", f, in_shardings=(
                 self.node_sh, self.victim_sh, self.node_sh, self.repl,
                 self.repl, self.repl), out_shardings=self.repl)
 
@@ -428,7 +432,7 @@ class _ShardedEvaluators:
                 return _pj._normal_pipeline(nodestate, aux, pbuf, ng, nc,
                                             cpb, spec=spec, p=p)
 
-            return jax.jit(f, in_shardings=(
+            return obs.program("normal_cycle_sharded", f, in_shardings=(
                 self.node_sh, self.repl, self.repl, self.repl),
                 out_shardings=self.repl)
 
@@ -441,7 +445,7 @@ class _ShardedEvaluators:
                     nodestate, victims, drain, pidx, pbuf, gidx,
                     *_pj._req_args(req), alpha, spec=spec, m=m, p=p)
 
-            return jax.jit(f, in_shardings=(
+            return obs.program("gathered_sharded", f, in_shardings=(
                 self.node_sh, self.victim_sh, self.node_sh, self.repl,
                 self.repl, self.repl, self.repl), out_shardings=self.repl)
 
@@ -459,7 +463,7 @@ class _ShardedEvaluators:
                     nodestate, victims, drain, rep, aux, pbuf,
                     *_pj._req_args(req), alpha, spec=spec, k=k, p=p, f=f)
 
-            return jax.jit(fn, in_shardings=(
+            return obs.program("shortlist_sharded", fn, in_shardings=(
                 self.node_sh, self.victim_sh, self.node_sh, self._rep_sh,
                 self.repl, self.repl, self.repl), out_shardings=self.repl)
 
@@ -472,7 +476,7 @@ class _ShardedEvaluators:
                     nodestate, victims, drain, rep, aux, pbuf,
                     *_pj._req_args(req), alpha, spec=spec, k=k, p=p, f=f)
 
-            return jax.jit(fn, in_shardings=(
+            return obs.program("shortlist_plan_sharded", fn, in_shardings=(
                 self.node_sh, self.victim_sh, self.node_sh, self._rep_sh,
                 self.repl, self.repl, self.repl), out_shardings=self.repl)
 
@@ -486,7 +490,7 @@ class _ShardedEvaluators:
                     spec=spec, m=m, narrow_gate=True)
 
             cw3, cw2 = self.victim_sh, self.node_sh
-            return jax.jit(
+            return obs.program("batch_class_sharded", 
                 jax.vmap(f, in_axes=(None, None, None, 0, 0, 0, 0)),
                 in_shardings=(self.node_sh, self.victim_sh, self.node_sh)
                 + (self.repl,) * 4,
@@ -505,7 +509,7 @@ class _ShardedEvaluators:
                     m=m, dpad=dpad, g=g)
 
             cw3, cw2 = self.victim_sh, self.node_sh
-            return jax.jit(f, in_shardings=(
+            return obs.program("batch_merge_sharded", f, in_shardings=(
                 cw3, cw3, cw3, cw3, cw2, cw2, self.node_sh, self.victim_sh,
                 self.node_sh, self.repl, self.repl, self.repl, self.repl),
                 out_shardings=self.repl)
@@ -522,7 +526,7 @@ class _ShardedEvaluators:
                     m=m, dpad=dpad, g=g, p=p)
 
             cw3, cw2 = self.victim_sh, self.node_sh
-            return jax.jit(f, in_shardings=(
+            return obs.program("batch_plan_sharded", f, in_shardings=(
                 cw3, cw3, cw3, cw3, cw2, cw2, self.node_sh, self.victim_sh,
                 self.node_sh, self.repl, self.repl, self.repl, self.repl),
                 out_shardings=self.repl)

@@ -86,6 +86,7 @@ import statistics
 import time
 from collections import deque
 
+from .. import obs
 from .agent import AgentFleet
 from .autoscale import AutoscalePolicy, Autoscaler, diurnal_traffic
 from .cluster import Cluster
@@ -800,46 +801,47 @@ class ColocationSim:
                            _REQUEUE, job)
 
     def _flush(self, end: float) -> None:
-        acc = self._acc
-        served = acc["served"]
-        online = sum(v for k, v in served.items()
-                     if self._kind_of(k) == "online")
-        log = self.auto.plan_us[self._plan_log_base:]
-        row = HourRow(
-            hour=self._row_start,
-            load=self._last_load,
-            counts=dict(self.cluster.count_by_workload()),
-            scheduled_perf=online,
-            preemptor_perf=acc["preemptor_perf"],
-            served=dict(served),
-            offline_goodput=acc["offline_goodput"],
-            placements=acc["placements"],
-            preemptions=acc["preemptions"],
-            hits=acc["hits"],
-            failures=acc["failures"],
-            requeued=acc["requeued"],
-            requeue_replanned=acc["requeue_replanned"],
-            completed_jobs=acc["completed_jobs"],
-            pending=len(self.pending),
-            crd_patches=self.fleet.store.patch_count - self._patch_base,
-            reclaimed_tiers=dict(acc["reclaimed"]),
-            decision_factor_mean=(statistics.fmean(acc["factors"])
-                                  if acc["factors"] else 0.0),
-            plan_p50_us=(statistics.median(log) if log else 0.0),
-            compiled_n=self._watch.delta(self._compile_mark),
-            elastic_admitted=acc["elastic_admitted"],
-            elastic_ejected=acc["elastic_ejected"],
-            elastic_completed=acc["elastic_completed"],
-            elastic_demoted=acc["elastic_demoted"],
-            elastic_goodput=acc["elastic_goodput"],
-            slo=(self.slo.drain_counts() if self.slo is not None else {}),
-        )
-        self.report.hours.append(row)
-        self._row_start = end
-        self._patch_base = self.fleet.store.patch_count
-        self._plan_log_base = len(self.auto.plan_us)
-        self._compile_mark = self._watch.mark()
-        self._reset_acc()
+        with obs.span("day.flush"):
+            acc = self._acc
+            served = acc["served"]
+            online = sum(v for k, v in served.items()
+                         if self._kind_of(k) == "online")
+            log = self.auto.plan_us[self._plan_log_base:]
+            row = HourRow(
+                hour=self._row_start,
+                load=self._last_load,
+                counts=dict(self.cluster.count_by_workload()),
+                scheduled_perf=online,
+                preemptor_perf=acc["preemptor_perf"],
+                served=dict(served),
+                offline_goodput=acc["offline_goodput"],
+                placements=acc["placements"],
+                preemptions=acc["preemptions"],
+                hits=acc["hits"],
+                failures=acc["failures"],
+                requeued=acc["requeued"],
+                requeue_replanned=acc["requeue_replanned"],
+                completed_jobs=acc["completed_jobs"],
+                pending=len(self.pending),
+                crd_patches=self.fleet.store.patch_count - self._patch_base,
+                reclaimed_tiers=dict(acc["reclaimed"]),
+                decision_factor_mean=(statistics.fmean(acc["factors"])
+                                      if acc["factors"] else 0.0),
+                plan_p50_us=(statistics.median(log) if log else 0.0),
+                compiled_n=self._watch.delta(self._compile_mark),
+                elastic_admitted=acc["elastic_admitted"],
+                elastic_ejected=acc["elastic_ejected"],
+                elastic_completed=acc["elastic_completed"],
+                elastic_demoted=acc["elastic_demoted"],
+                elastic_goodput=acc["elastic_goodput"],
+                slo=(self.slo.drain_counts() if self.slo is not None else {}),
+            )
+            self.report.hours.append(row)
+            self._row_start = end
+            self._patch_base = self.fleet.store.patch_count
+            self._plan_log_base = len(self.auto.plan_us)
+            self._compile_mark = self._watch.mark()
+            self._reset_acc()
 
     def _kind_of(self, name: str) -> str:
         kind = self._kind_cache.get(name)
@@ -860,35 +862,36 @@ class ColocationSim:
 
     # ---- handlers --------------------------------------------------------------------
     def _handle_tick(self, t: float) -> None:
-        if t > self._row_start:
-            self._flush(t)
-        self._last_load = diurnal_traffic(t % 24.0)
-        self._next_load = diurnal_traffic((t + self.cfg.tick_hours) % 24.0)
-        if self.pool is not None:
-            # the reversed ladder, step 1 (degrade before kill): online
-            # load reclaims request slots — ejected offline requests land
-            # back in the pending queue — BEFORE the scale executor below
-            # preempts whole instances
-            for jid in self.pool.set_load(self._last_load):
-                self._eject_elastic(jid)
-        if self.cfg.elastic and self.pool is not None:
-            # reversed ladder, step 2: when the ramp's scale-up has no
-            # node-contiguous free block (completions free SCATTERED 1-2
-            # GPU fragments), demote whole offline instances into spare
-            # request slots to assemble one — an instance preemption that
-            # never happens
-            self._harvest_for_ramp()
-        for pol in self.auto.policies:
-            ev = self.auto.scale_to(pol, pol.desired(self._last_load), t)
-            self._acc["failures"] += ev.failures
-            for tier, n in ev.reclaimed_tiers.items():
-                self._acc["reclaimed"][tier] = (
-                    self._acc["reclaimed"].get(tier, 0) + n)
-        if self.pool is not None:
-            self._reconcile_pool()
-        self._drain()
-        if self.pool is not None:
-            self.pool.sample(self._last_load)
+        with obs.span("day.tick"):
+            if t > self._row_start:
+                self._flush(t)
+            self._last_load = diurnal_traffic(t % 24.0)
+            self._next_load = diurnal_traffic((t + self.cfg.tick_hours) % 24.0)
+            if self.pool is not None:
+                # the reversed ladder, step 1 (degrade before kill): online
+                # load reclaims request slots — ejected offline requests land
+                # back in the pending queue — BEFORE the scale executor below
+                # preempts whole instances
+                for jid in self.pool.set_load(self._last_load):
+                    self._eject_elastic(jid)
+            if self.cfg.elastic and self.pool is not None:
+                # reversed ladder, step 2: when the ramp's scale-up has no
+                # node-contiguous free block (completions free SCATTERED 1-2
+                # GPU fragments), demote whole offline instances into spare
+                # request slots to assemble one — an instance preemption that
+                # never happens
+                self._harvest_for_ramp()
+            for pol in self.auto.policies:
+                ev = self.auto.scale_to(pol, pol.desired(self._last_load), t)
+                self._acc["failures"] += ev.failures
+                for tier, n in ev.reclaimed_tiers.items():
+                    self._acc["reclaimed"][tier] = (
+                        self._acc["reclaimed"].get(tier, 0) + n)
+            if self.pool is not None:
+                self._reconcile_pool()
+            self._drain()
+            if self.pool is not None:
+                self.pool.sample(self._last_load)
 
     def _handle_submit(self, job: OfflineJob) -> None:
         self.jobs.append(job)
@@ -896,8 +899,9 @@ class ColocationSim:
         self._drain()
 
     def _handle_requeue(self, job: OfflineJob) -> None:
-        self.pending.append(job)
-        self._drain()
+        with obs.span("day.requeue"):
+            self.pending.append(job)
+            self._drain()
 
     def _drop_offline_index(self, node: int, uid: int) -> None:
         uids = self._offline_by_node.get(node)
@@ -907,19 +911,20 @@ class ColocationSim:
                 del self._offline_by_node[node]
 
     def _handle_complete(self, uid: int) -> None:
-        job = self._running.get(uid)
-        if job is None or job.uid != uid:
-            return               # stale event: the job was preempted earlier
-        del self._running[uid]
-        inst = self.cluster.evict(uid)
-        self._drop_offline_index(inst.node, uid)
-        job.uid = None
-        job.remaining_hours = 0.0
-        job.completed_at = self._now
-        self._acc["completed_jobs"] += 1
-        self._acc["offline_goodput"] += (
-            job.duration_hours * job.workload.gpus_per_instance)
-        self._drain()
+        with obs.span("day.complete"):
+            job = self._running.get(uid)
+            if job is None or job.uid != uid:
+                return       # stale event: the job was preempted earlier
+            del self._running[uid]
+            inst = self.cluster.evict(uid)
+            self._drop_offline_index(inst.node, uid)
+            job.uid = None
+            job.remaining_hours = 0.0
+            job.completed_at = self._now
+            self._acc["completed_jobs"] += 1
+            self._acc["offline_goodput"] += (
+                job.duration_hours * job.workload.gpus_per_instance)
+            self._drain()
 
     def _handle_scale(self, workload: WorkloadSpec) -> None:
         """One explicit Algorithm 1 attempt (the Fig. 8/9 view events)."""
@@ -941,69 +946,70 @@ class ColocationSim:
         reserve).  Instance admission is chunked ``plan_batch`` (normal
         cycle only), FIFO, one pass per trigger; stops as soon as an entire
         chunk fails to place, so a full cluster costs one dispatch."""
-        if self.cfg.elastic and self.pool is not None and self.pending:
-            self._elastic_pack()
-        if not self.pending:
-            return
-        budget = (self._instance_gpu_budget()
-                  if self.cfg.elastic and self.pool is not None else None)
-        queue, self.pending = self.pending, deque()
-        while queue:
-            chunk = []
-            while queue and len(chunk) < self.cfg.backfill_chunk:
-                need = queue[0].workload.gpus_per_instance
-                if budget is not None and need > budget:
-                    break       # FIFO head held by the online reserve
-                chunk.append(queue.popleft())
-                if budget is not None:
-                    budget -= need
-            if not chunk:
-                self.pending.extend(queue)
+        with obs.span("day.drain"):
+            if self.cfg.elastic and self.pool is not None and self.pending:
+                self._elastic_pack()
+            if not self.pending:
                 return
-            any_placed = False
-            if self.cfg.legacy_loop:
-                txns = self.auto._timed_plan_batch(
-                    [j.workload for j in chunk], allow_preempt=False)
-                for job, txn in zip(chunk, txns):
-                    if txn.decision.placed:
-                        dec = txn.commit()
-                        self._start_job(job, dec)
-                        any_placed = True
-                    else:
-                        self.pending.append(job)
-                        if budget is not None:
-                            budget += job.workload.gpus_per_instance
-            else:
-                # count-gated per-job dispatch.  Normal-cycle placement
-                # succeeds iff some node has enough free GPUs AND
-                # coregroups (``_place_on`` always falls back to
-                # ``place_blind``; the fused engines carry the same
-                # degraded blind branch), so a job that fails the count
-                # check would reject without mutating state — skip its
-                # plan entirely.  Feasible jobs plan singly and commit
-                # immediately; the inst-listener refreshes the free-count
-                # index between jobs, which keeps the gate exact AND the
-                # decisions bit-identical to the legacy shared-view batch
-                # (the plan/commit interleave invariant,
-                # ``TopoScheduler.plan_batch``).
-                for job in chunk:
-                    if not self._count_feasible(job.workload):
-                        self.pending.append(job)
-                        if budget is not None:
-                            budget += job.workload.gpus_per_instance
-                        continue
-                    txn = self.auto._timed_plan_batch(
-                        [job.workload], allow_preempt=False)[0]
-                    if txn.decision.placed:
-                        self._start_job(job, txn.commit())
-                        any_placed = True
-                    else:        # count gate is exact; defensive only
-                        self.pending.append(job)
-                        if budget is not None:
-                            budget += job.workload.gpus_per_instance
-            if not any_placed:
-                self.pending.extend(queue)
-                return
+            budget = (self._instance_gpu_budget()
+                      if self.cfg.elastic and self.pool is not None else None)
+            queue, self.pending = self.pending, deque()
+            while queue:
+                chunk = []
+                while queue and len(chunk) < self.cfg.backfill_chunk:
+                    need = queue[0].workload.gpus_per_instance
+                    if budget is not None and need > budget:
+                        break       # FIFO head held by the online reserve
+                    chunk.append(queue.popleft())
+                    if budget is not None:
+                        budget -= need
+                if not chunk:
+                    self.pending.extend(queue)
+                    return
+                any_placed = False
+                if self.cfg.legacy_loop:
+                    txns = self.auto._timed_plan_batch(
+                        [j.workload for j in chunk], allow_preempt=False)
+                    for job, txn in zip(chunk, txns):
+                        if txn.decision.placed:
+                            dec = txn.commit()
+                            self._start_job(job, dec)
+                            any_placed = True
+                        else:
+                            self.pending.append(job)
+                            if budget is not None:
+                                budget += job.workload.gpus_per_instance
+                else:
+                    # count-gated per-job dispatch.  Normal-cycle placement
+                    # succeeds iff some node has enough free GPUs AND
+                    # coregroups (``_place_on`` always falls back to
+                    # ``place_blind``; the fused engines carry the same
+                    # degraded blind branch), so a job that fails the count
+                    # check would reject without mutating state — skip its
+                    # plan entirely.  Feasible jobs plan singly and commit
+                    # immediately; the inst-listener refreshes the free-count
+                    # index between jobs, which keeps the gate exact AND the
+                    # decisions bit-identical to the legacy shared-view batch
+                    # (the plan/commit interleave invariant,
+                    # ``TopoScheduler.plan_batch``).
+                    for job in chunk:
+                        if not self._count_feasible(job.workload):
+                            self.pending.append(job)
+                            if budget is not None:
+                                budget += job.workload.gpus_per_instance
+                            continue
+                        txn = self.auto._timed_plan_batch(
+                            [job.workload], allow_preempt=False)[0]
+                        if txn.decision.placed:
+                            self._start_job(job, txn.commit())
+                            any_placed = True
+                        else:        # count gate is exact; defensive only
+                            self.pending.append(job)
+                            if budget is not None:
+                                budget += job.workload.gpus_per_instance
+                if not any_placed:
+                    self.pending.extend(queue)
+                    return
 
     def _start_job(self, job: OfflineJob, dec) -> None:
         uid = dec.instance.uid

@@ -21,6 +21,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Literal
 
+from .. import obs
 from .cluster import Cluster
 from .placement import Placement
 from .workload import Instance, WorkloadSpec
@@ -59,6 +60,9 @@ class SchedulingDecision:
     #: comparisons across engines stay meaningful.
     sourcing_provenance: dict = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
+    #: the planning scheduler's ``seq`` for this decision: its
+    #: ``sched.plan`` and ``sched.commit`` spans carry the same number
+    seq: int = dataclasses.field(default=0, repr=False, compare=False)
     instance: Instance | None = None
     evicted: list[Instance] = dataclasses.field(default_factory=list)
     txn: "Transaction | None" = dataclasses.field(
@@ -114,6 +118,10 @@ class Transaction:
         """
         if self.state != PLANNED:
             raise TransactionError(f"cannot commit a {self.state} transaction")
+        with obs.span("sched.commit", seq=self.decision.seq):
+            return self._commit()
+
+    def _commit(self) -> SchedulingDecision:
         dec = self.decision
         if dec.rejected:
             self.state = COMMITTED

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .placement import INFEASIBLE, Placement
 from .topology import ServerSpec
 
@@ -338,7 +339,7 @@ def _best_tier_jit(spec: ServerSpec):
         tier, _, _ = best_tier_counts(fg, fc, ng, nc, cpb, sl)
         return tier
 
-    return jax.jit(f)
+    return obs.program("best_tier", f)
 
 
 @lru_cache(maxsize=None)
@@ -347,7 +348,7 @@ def _place_jit(spec: ServerSpec):
         ok, tier, g, c = place_core(fg, fc, ng, nc, cpb, spec=spec)
         return jnp.stack([ok.astype(jnp.int32), tier, g, c])
 
-    return jax.jit(f)
+    return obs.program("place", f)
 
 
 @lru_cache(maxsize=None)
@@ -356,7 +357,7 @@ def _place_blind_jit(spec: ServerSpec):
         ok, tier, g, c = place_blind_core(fg, fc, ng, nc, spec=spec)
         return jnp.stack([ok.astype(jnp.int32), tier, g, c])
 
-    return jax.jit(f)
+    return obs.program("place_blind", f)
 
 
 def _i32(x: int) -> jnp.ndarray:

@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from .cluster import (DRAIN_FIELDS, IDX_SENTINEL, MAX_DENSE_VICTIMS,
                       NODE_FIELDS, NS_FREE_CG, NS_FREE_GPU, NS_NEXT_PRIO,
                       NS_NODE_ID, NS_OVERFLOW, VF_CG, VF_GPU, VF_PRIO,
@@ -622,15 +623,17 @@ def _fused_argmax_core(node_ids, cls: ClassWinners, alpha):
 def _overlay(nodestate, victims, drain, pidx, pbuf):
     """Apply flattened view-delta patch rows as a device-side overlay
     (the traced twin of the resident-state scatter)."""
-    return apply_rows(nodestate, victims, drain, pidx, pbuf)
+    with jax.named_scope("overlay"):
+        return apply_rows(nodestate, victims, drain, pidx, pbuf)
 
 
 def _overlay_ns(nodestate, idx, buf):
     """Overlay patch rows onto the nodestate tensor alone (the normal-cycle
     evaluator needs free masks only, not victim/drain rows)."""
     cap = (buf.shape[1] - NODE_FIELDS - DRAIN_FIELDS) // VICTIM_FIELDS
-    a, _, _ = unflatten_rows(buf, cap)
-    return nodestate.at[:, idx].set(a, mode="drop")
+    with jax.named_scope("overlay"):
+        a, _, _ = unflatten_rows(buf, cap)
+        return nodestate.at[:, idx].set(a, mode="drop")
 
 
 #: width of a decoded preemption winner: the int32[7] Eq. 2 argmax vector
@@ -648,31 +651,33 @@ def _sorting_winner(nodestate, victims, drain, gidx,
     (`placement_jax.winner_place`) so the host decodes concrete
     GPU/CoreGroup masks instead of re-running ``place()``.  Returns
     int32[`WIN_FIELDS`]."""
-    cls = _fused_class_core(nodestate, victims, drain, thresh, ng, nc,
-                            cpb, alpha, spec=spec, m=m, narrow_gate=True)
-    node_ids = nodestate[NS_NODE_ID]
-    fg_cat = nodestate[NS_FREE_GPU]
-    fc_cat = nodestate[NS_FREE_CG]
-    vg_cat = victims[VF_GPU]
-    vc_cat = victims[VF_CG]
-    if g:
-        ns = jnp.take(nodestate, gidx, axis=1, mode="fill", fill_value=0)
-        vv = jnp.take(victims, gidx, axis=1, mode="fill", fill_value=0)
-        dd = jnp.take(drain, gidx, axis=1, mode="fill", fill_value=0)
-        ns = ns.at[NS_NODE_ID].set(gidx)
-        cls_g = _fused_class_core(ns, vv, dd, thresh, ng, nc, cpb,
-                                  alpha, spec=spec, m=NARROW_M,
-                                  narrow_gate=False)
-        cls = ClassWinners(*(jnp.concatenate([a, b])
-                             for a, b in zip(cls, cls_g)))
-        node_ids = jnp.concatenate([node_ids, ns[NS_NODE_ID]])
-        fg_cat = jnp.concatenate([fg_cat, ns[NS_FREE_GPU]])
-        fc_cat = jnp.concatenate([fc_cat, ns[NS_FREE_CG]])
-        vg_cat = jnp.concatenate([vg_cat, vv[VF_GPU]])
-        vc_cat = jnp.concatenate([vc_cat, vv[VF_CG]])
-    win = _fused_argmax_core(node_ids, cls, alpha)
-    return winner_place(win, fg_cat, fc_cat, vg_cat, vc_cat, ng, nc, cpb,
-                        spec=spec)
+    with jax.named_scope("exact_sweep"):
+        cls = _fused_class_core(nodestate, victims, drain, thresh, ng, nc,
+                                cpb, alpha, spec=spec, m=m, narrow_gate=True)
+        node_ids = nodestate[NS_NODE_ID]
+        fg_cat = nodestate[NS_FREE_GPU]
+        fc_cat = nodestate[NS_FREE_CG]
+        vg_cat = victims[VF_GPU]
+        vc_cat = victims[VF_CG]
+        if g:
+            ns = jnp.take(nodestate, gidx, axis=1, mode="fill", fill_value=0)
+            vv = jnp.take(victims, gidx, axis=1, mode="fill", fill_value=0)
+            dd = jnp.take(drain, gidx, axis=1, mode="fill", fill_value=0)
+            ns = ns.at[NS_NODE_ID].set(gidx)
+            cls_g = _fused_class_core(ns, vv, dd, thresh, ng, nc, cpb,
+                                      alpha, spec=spec, m=NARROW_M,
+                                      narrow_gate=False)
+            cls = ClassWinners(*(jnp.concatenate([a, b])
+                                 for a, b in zip(cls, cls_g)))
+            node_ids = jnp.concatenate([node_ids, ns[NS_NODE_ID]])
+            fg_cat = jnp.concatenate([fg_cat, ns[NS_FREE_GPU]])
+            fc_cat = jnp.concatenate([fc_cat, ns[NS_FREE_CG]])
+            vg_cat = jnp.concatenate([vg_cat, vv[VF_GPU]])
+            vc_cat = jnp.concatenate([vc_cat, vv[VF_CG]])
+        win = _fused_argmax_core(node_ids, cls, alpha)
+    with jax.named_scope("winner_place"):
+        return winner_place(win, fg_cat, fc_cat, vg_cat, vc_cat, ng, nc,
+                            cpb, spec=spec)
 
 
 def _plan_pipeline(nodestate, victims, drain, aux, pbuf,
@@ -703,7 +708,8 @@ def _plan2_pipeline(nodestate, victims, drain, aux, pbuf,
     if p:
         nodestate, victims, drain = _overlay(nodestate, victims, drain,
                                              aux[:p], pbuf)
-    norm = normal_cycle_core(nodestate, ng, nc, cpb, spec=spec)
+    with jax.named_scope("normal_cycle"):
+        norm = normal_cycle_core(nodestate, ng, nc, cpb, spec=spec)
 
     def _skip(_):
         return jnp.zeros(WIN_FIELDS, jnp.int32)
@@ -732,7 +738,7 @@ def request_vector(thresh: int, ng: int, nc: int, cpb: int) -> jnp.ndarray:
     mixed classes compiles each bucket once), and it is the same program
     the randomized parity suites run for every class.  Cached per class,
     so a plan uploads nothing for it."""
-    return jnp.asarray(np.array([thresh, ng, nc, cpb], np.int32))
+    return obs.upload(np.array([thresh, ng, nc, cpb], np.int32))
 
 
 @lru_cache(maxsize=None)
@@ -747,7 +753,7 @@ def resident_evaluator(spec: ServerSpec, m: int, p: int, g: int,
                               *_req_args(req), alpha,
                               spec=spec, m=m, p=p, g=g)
 
-    return jax.jit(f)
+    return obs.program("resident_sweep", f)
 
 
 @lru_cache(maxsize=None)
@@ -760,14 +766,15 @@ def plan_evaluator(spec: ServerSpec, m: int, p: int, g: int, alpha: float):
                                *_req_args(req), alpha,
                                spec=spec, m=m, p=p, g=g)
 
-    return jax.jit(f)
+    return obs.program("plan2", f)
 
 
 def _normal_pipeline(nodestate, aux, pbuf, ng, nc, cpb, *, spec, p):
     """Nodestate-only patch overlay + the normal-cycle scorer."""
     if p:
         nodestate = _overlay_ns(nodestate, aux[:p], pbuf)
-    return normal_cycle_core(nodestate, ng, nc, cpb, spec=spec)
+    with jax.named_scope("normal_cycle"):
+        return normal_cycle_core(nodestate, ng, nc, cpb, spec=spec)
 
 
 @lru_cache(maxsize=None)
@@ -783,7 +790,7 @@ def normal_evaluator(spec: ServerSpec, p: int):
         return _normal_pipeline(nodestate, aux, pbuf, ng, nc, cpb,
                                 spec=spec, p=p)
 
-    return jax.jit(f)
+    return obs.program("normal_cycle", f)
 
 
 def _gathered_pipeline(nodestate, victims, drain, pidx, pbuf, gidx,
@@ -795,15 +802,17 @@ def _gathered_pipeline(nodestate, victims, drain, pidx, pbuf, gidx,
     if p:
         nodestate, victims, drain = _overlay(nodestate, victims, drain,
                                              pidx, pbuf)
-    ns = jnp.take(nodestate, gidx, axis=1, mode="fill", fill_value=0)
-    vv = jnp.take(victims, gidx, axis=1, mode="fill", fill_value=0)
-    dd = jnp.take(drain, gidx, axis=1, mode="fill", fill_value=0)
-    ns = ns.at[NS_NODE_ID].set(gidx)
-    cls = _fused_class_core(ns, vv, dd, thresh, ng, nc, cpb, alpha,
-                            spec=spec, m=m, narrow_gate=False)
-    win = _fused_argmax_core(ns[NS_NODE_ID], cls, alpha)
-    return winner_place(win, ns[NS_FREE_GPU], ns[NS_FREE_CG],
-                        vv[VF_GPU], vv[VF_CG], ng, nc, cpb, spec=spec)
+    with jax.named_scope("exact_sweep"):
+        ns = jnp.take(nodestate, gidx, axis=1, mode="fill", fill_value=0)
+        vv = jnp.take(victims, gidx, axis=1, mode="fill", fill_value=0)
+        dd = jnp.take(drain, gidx, axis=1, mode="fill", fill_value=0)
+        ns = ns.at[NS_NODE_ID].set(gidx)
+        cls = _fused_class_core(ns, vv, dd, thresh, ng, nc, cpb, alpha,
+                                spec=spec, m=m, narrow_gate=False)
+        win = _fused_argmax_core(ns[NS_NODE_ID], cls, alpha)
+    with jax.named_scope("winner_place"):
+        return winner_place(win, ns[NS_FREE_GPU], ns[NS_FREE_CG],
+                            vv[VF_GPU], vv[VF_CG], ng, nc, cpb, spec=spec)
 
 
 @lru_cache(maxsize=None)
@@ -815,7 +824,7 @@ def gathered_evaluator(spec: ServerSpec, m: int, p: int, alpha: float):
                                   gidx, *_req_args(req), alpha,
                                   spec=spec, m=m, p=p)
 
-    return jax.jit(f)
+    return obs.program("gathered", f)
 
 
 # ---------------------------------------------------------------------------------
@@ -935,18 +944,22 @@ def _shortlist_winner(nodestate, victims, drain, rep,
     winner, iff nothing was left outside), which proves the full sweep
     could not have decided differently.
     """
-    gidx, excl_ub = _prescreen_core(nodestate, victims, drain, rep,
-                                    thresh, ng, nc, cpb, alpha,
-                                    spec=spec, k=k)
-    ns = jnp.take(nodestate, gidx, axis=1, mode="fill", fill_value=0)
-    vv = jnp.take(victims, gidx, axis=1, mode="fill", fill_value=0)
-    dd = jnp.take(drain, gidx, axis=1, mode="fill", fill_value=0)
-    ns = ns.at[NS_NODE_ID].set(gidx)
-    cls = _fused_class_core(ns, vv, dd, thresh, ng, nc, cpb, alpha,
-                            spec=spec, m=NARROW_M, narrow_gate=False)
-    win = _fused_argmax_core(ns[NS_NODE_ID], cls, alpha)
-    placed = winner_place(win, ns[NS_FREE_GPU], ns[NS_FREE_CG],
-                          vv[VF_GPU], vv[VF_CG], ng, nc, cpb, spec=spec)
+    with jax.named_scope("prescreen"):
+        gidx, excl_ub = _prescreen_core(nodestate, victims, drain, rep,
+                                        thresh, ng, nc, cpb, alpha,
+                                        spec=spec, k=k)
+    with jax.named_scope("topk_gather"):
+        ns = jnp.take(nodestate, gidx, axis=1, mode="fill", fill_value=0)
+        vv = jnp.take(victims, gidx, axis=1, mode="fill", fill_value=0)
+        dd = jnp.take(drain, gidx, axis=1, mode="fill", fill_value=0)
+        ns = ns.at[NS_NODE_ID].set(gidx)
+    with jax.named_scope("exact_sweep"):
+        cls = _fused_class_core(ns, vv, dd, thresh, ng, nc, cpb, alpha,
+                                spec=spec, m=NARROW_M, narrow_gate=False)
+        win = _fused_argmax_core(ns[NS_NODE_ID], cls, alpha)
+    with jax.named_scope("winner_place"):
+        placed = winner_place(win, ns[NS_FREE_GPU], ns[NS_FREE_CG],
+                              vv[VF_GPU], vv[VF_CG], ng, nc, cpb, spec=spec)
     found = win[0] > 0
     tier_vals = jnp.asarray(tuple(TIER_SCORES), jnp.float32)
     pp = win[4]
@@ -985,7 +998,8 @@ def _shortlist_plan2_pipeline(nodestate, victims, drain, rep, aux, pbuf,
                                              aux[:p], pbuf)
     if f:
         rep = rep.at[aux[p:p + f]].set(True, mode="drop")
-    norm = normal_cycle_core(nodestate, ng, nc, cpb, spec=spec)
+    with jax.named_scope("normal_cycle"):
+        norm = normal_cycle_core(nodestate, ng, nc, cpb, spec=spec)
 
     def _skip(_):
         return jnp.zeros(WIN_FIELDS + 2, jnp.int32)
@@ -1009,7 +1023,7 @@ def shortlist_evaluator(spec: ServerSpec, k: int, p: int, f: int,
                                    pbuf, *_req_args(req), alpha,
                                    spec=spec, k=k, p=p, f=f)
 
-    return jax.jit(fn)
+    return obs.program("shortlist", fn)
 
 
 @lru_cache(maxsize=None)
@@ -1023,7 +1037,7 @@ def shortlist_plan_evaluator(spec: ServerSpec, k: int, p: int, f: int,
                                          aux, pbuf, *_req_args(req), alpha,
                                          spec=spec, k=k, p=p, f=f)
 
-    return jax.jit(fn)
+    return obs.program("shortlist_plan", fn)
 
 
 @lru_cache(maxsize=None)
@@ -1038,7 +1052,8 @@ def batch_class_evaluator(spec: ServerSpec, m: int, alpha: float):
                                  cpb, alpha, spec=spec, m=m,
                                  narrow_gate=True)
 
-    return jax.jit(jax.vmap(f, in_axes=(None, None, None, 0, 0, 0, 0)))
+    return obs.program("batch_class",
+                       jax.vmap(f, in_axes=(None, None, None, 0, 0, 0, 0)))
 
 
 def _masked_class_winner(anyc, cb, pp, um, kn, cnt, nodestate, victims,
@@ -1053,33 +1068,35 @@ def _masked_class_winner(anyc, cb, pp, um, kn, cnt, nodestate, victims,
     the Eq. 2 argmax + winner placement.  Class-data rows that can win are
     non-delta rows, where the overlaid arrays equal the raw resident state
     — safe placement inputs."""
-    n = anyc.shape[1]
-    mask = jnp.ones(n, bool).at[didx].set(False, mode="drop")
-    cls = ClassWinners(anyc[i] & mask[:, None], cb[i], pp[i], um[i],
-                       kn[i], cnt[i] * mask)
-    node_ids = jnp.arange(n, dtype=jnp.int32)
-    fg_cat = nodestate[NS_FREE_GPU]
-    fc_cat = nodestate[NS_FREE_CG]
-    vg_cat = victims[VF_GPU]
-    vc_cat = victims[VF_CG]
-    if g:
-        ns = jnp.take(nodestate, gidx, axis=1, mode="fill", fill_value=0)
-        vv = jnp.take(victims, gidx, axis=1, mode="fill", fill_value=0)
-        dd = jnp.take(drain, gidx, axis=1, mode="fill", fill_value=0)
-        ns = ns.at[NS_NODE_ID].set(gidx)
-        cls_g = _fused_class_core(ns, vv, dd, thresh, ng, nc, cpb,
-                                  alpha, spec=spec, m=m,
-                                  narrow_gate=False)
-        cls = ClassWinners(*(jnp.concatenate([a, b])
-                             for a, b in zip(cls, cls_g)))
-        node_ids = jnp.concatenate([node_ids, ns[NS_NODE_ID]])
-        fg_cat = jnp.concatenate([fg_cat, ns[NS_FREE_GPU]])
-        fc_cat = jnp.concatenate([fc_cat, ns[NS_FREE_CG]])
-        vg_cat = jnp.concatenate([vg_cat, vv[VF_GPU]])
-        vc_cat = jnp.concatenate([vc_cat, vv[VF_CG]])
-    win = _fused_argmax_core(node_ids, cls, alpha)
-    return winner_place(win, fg_cat, fc_cat, vg_cat, vc_cat,
-                        ng, nc, cpb, spec=spec)
+    with jax.named_scope("exact_sweep"):
+        n = anyc.shape[1]
+        mask = jnp.ones(n, bool).at[didx].set(False, mode="drop")
+        cls = ClassWinners(anyc[i] & mask[:, None], cb[i], pp[i], um[i],
+                           kn[i], cnt[i] * mask)
+        node_ids = jnp.arange(n, dtype=jnp.int32)
+        fg_cat = nodestate[NS_FREE_GPU]
+        fc_cat = nodestate[NS_FREE_CG]
+        vg_cat = victims[VF_GPU]
+        vc_cat = victims[VF_CG]
+        if g:
+            ns = jnp.take(nodestate, gidx, axis=1, mode="fill", fill_value=0)
+            vv = jnp.take(victims, gidx, axis=1, mode="fill", fill_value=0)
+            dd = jnp.take(drain, gidx, axis=1, mode="fill", fill_value=0)
+            ns = ns.at[NS_NODE_ID].set(gidx)
+            cls_g = _fused_class_core(ns, vv, dd, thresh, ng, nc, cpb,
+                                      alpha, spec=spec, m=m,
+                                      narrow_gate=False)
+            cls = ClassWinners(*(jnp.concatenate([a, b])
+                                 for a, b in zip(cls, cls_g)))
+            node_ids = jnp.concatenate([node_ids, ns[NS_NODE_ID]])
+            fg_cat = jnp.concatenate([fg_cat, ns[NS_FREE_GPU]])
+            fc_cat = jnp.concatenate([fc_cat, ns[NS_FREE_CG]])
+            vg_cat = jnp.concatenate([vg_cat, vv[VF_GPU]])
+            vc_cat = jnp.concatenate([vc_cat, vv[VF_CG]])
+        win = _fused_argmax_core(node_ids, cls, alpha)
+    with jax.named_scope("winner_place"):
+        return winner_place(win, fg_cat, fc_cat, vg_cat, vc_cat,
+                            ng, nc, cpb, spec=spec)
 
 
 def _batch_merge_pipeline(anyc, cb, pp, um, kn, cnt, nodestate, victims,
@@ -1114,7 +1131,7 @@ def batch_merge_evaluator(spec: ServerSpec, m: int, dpad: int, g: int,
             anyc, cb, pp, um, kn, cnt, nodestate, victims, drain, i, aux,
             pbuf, *_req_args(req), alpha, spec=spec, m=m, dpad=dpad, g=g)
 
-    return jax.jit(f)
+    return obs.program("batch_merge", f)
 
 
 def _batch_plan_pipeline(anyc, cb, pp, um, kn, cnt, nodestate, victims,
@@ -1130,7 +1147,8 @@ def _batch_plan_pipeline(anyc, cb, pp, um, kn, cnt, nodestate, victims,
     if p:
         nodestate, victims, drain = _overlay(nodestate, victims, drain,
                                              aux[dpad:dpad + p], pbuf)
-    norm = normal_cycle_core(nodestate, ng, nc, cpb, spec=spec)
+    with jax.named_scope("normal_cycle"):
+        norm = normal_cycle_core(nodestate, ng, nc, cpb, spec=spec)
 
     def _skip(_):
         return jnp.zeros(WIN_FIELDS, jnp.int32)
@@ -1158,7 +1176,7 @@ def batch_plan_evaluator(spec: ServerSpec, m: int, dpad: int, g: int,
             pbuf, *_req_args(req), alpha, spec=spec, m=m, dpad=dpad, g=g,
             p=p)
 
-    return jax.jit(f)
+    return obs.program("batch_plan", f)
 
 
 #: node-axis chunk size for the widest (m=16) victim bucket: keeps the
@@ -1201,7 +1219,7 @@ def _empty_patch_args(cap: int):
     """Cached zero-size device patch arrays for the p=0 (no view deltas)
     fast path — the common case allocates nothing per plan."""
     _, pidx, pbuf = _pack_patches({}, cap)
-    return jnp.asarray(pidx), jnp.asarray(pbuf)
+    return obs.upload(pidx), obs.upload(pbuf)
 
 
 def _evals(dcs: DeviceClusterState):
@@ -1241,6 +1259,11 @@ def _patch_elig(patches, thresh: int):
              for n, r in patches.items()},
             {n: bool(r.overflow and r.next_priority < thresh)
              for n, r in patches.items()})
+
+
+#: the device-encoded patch rows followed by the host-packed ones
+_patch_concat = obs.program("patch_concat",
+                            lambda dbuf, hbuf: jnp.concatenate([dbuf, hbuf]))
 
 
 def _patch_args(dcs: DeviceClusterState, patches):
@@ -1291,7 +1314,7 @@ def _patch_args(dcs: DeviceClusterState, patches):
     # disjoint node sets by construction; sentinel pads drop out of the
     # overlay scatter, so per-section pow2 buckets concatenate directly
     idx = np.concatenate([didx, hidx])
-    return len(idx), idx, jnp.concatenate([dbuf, jnp.asarray(hbuf)])
+    return len(idx), idx, _patch_concat(dbuf, obs.upload(hbuf))
 
 
 def _pad_idx(ids, floor: int = 4) -> np.ndarray:
@@ -1388,8 +1411,7 @@ def _append_winner(out: CandidateShortlist, res, sel_nodes, patches, ctx):
     Dispatches run asynchronously; callers queue (res, sel_nodes) pairs and
     decode them together at the end so one device sync covers all of them.
     """
-    found, row, tier, combo, prio, _k, ncand, pgm, pcm = (
-        int(x) for x in jax.device_get(res))
+    found, row, tier, combo, prio, _k, ncand, pgm, pcm = obs.readback(res)
     out.n_candidates += ncand
     if not found:
         return
@@ -1417,20 +1439,21 @@ def _fast_plan_args(dcs: DeviceClusterState, patches: dict, thresh: int,
     plans against unchanged state skip the host eligibility scan AND the
     per-plan host→device upload of the gather indices — the whole host
     side of a plan is then one dict lookup."""
-    cached = dcs.plan_cache.get(thresh) if p == 0 else None
-    if cached is not None and cached[0] == dcs.version:
-        return cached[1:]
-    split = split_fused_nodes(dcs, patches, thresh)
-    gidx = _pad_idx(split.mid) if split.mid else np.zeros(0, np.int32)
-    g = len(gidx)
-    if p == 0 and g == 0:
-        aux_d, pbuf_d = _empty_patch_args(dcs.cap)
-    else:
-        aux_d = jnp.asarray(np.concatenate([pidx, gidx]))
-        pbuf_d = jnp.asarray(pbuf)
-    if p == 0:
-        dcs.plan_cache[thresh] = (dcs.version, split, g, aux_d, pbuf_d)
-    return split, g, aux_d, pbuf_d
+    with obs.span("sched.route"):
+        cached = dcs.plan_cache.get(thresh) if p == 0 else None
+        if cached is not None and cached[0] == dcs.version:
+            return cached[1:]
+        split = split_fused_nodes(dcs, patches, thresh)
+        gidx = _pad_idx(split.mid) if split.mid else np.zeros(0, np.int32)
+        g = len(gidx)
+        if p == 0 and g == 0:
+            aux_d, pbuf_d = _empty_patch_args(dcs.cap)
+        else:
+            aux_d = obs.upload(np.concatenate([pidx, gidx]))
+            pbuf_d = obs.upload(pbuf)
+        if p == 0:
+            dcs.plan_cache[thresh] = (dcs.version, split, g, aux_d, pbuf_d)
+        return split, g, aux_d, pbuf_d
 
 
 def _forced_rows(dcs: DeviceClusterState, patches) -> list[int]:
@@ -1466,26 +1489,32 @@ def _shortlist_plan_args(dcs: DeviceClusterState, patches, thresh: int,
                          p: int, pidx, pbuf):
     """`_fast_plan_args`'s shortlist twin: wide/overflow routing split +
     forced-rep indices + the combined aux upload, cached per preemptor
-    priority while the state version holds (the delta-free steady state
-    pays two dict probes per plan: this and `rep_classes`)."""
-    cached = dcs.plan_cache.get(("shortlist", thresh)) if p == 0 else None
-    if cached is not None and cached[0] == dcs.version:
-        return cached[1:]
-    # gate=NARROW_M: the shortlist's stage 2 always runs NARROW_M wide, so
-    # only genuinely wide (elig > NARROW_M) and overflow rows route out
-    split = split_fused_nodes(dcs, patches, thresh, gate=NARROW_M)
-    forced = _forced_rows(dcs, patches)
-    fidx = _pad_idx(forced) if forced else np.zeros(0, np.int32)
-    f = len(fidx)
-    if p == 0 and f == 0:
-        aux_d, pbuf_d = _empty_patch_args(dcs.cap)
-    else:
-        aux_d = jnp.asarray(np.concatenate([pidx, fidx]))
-        pbuf_d = jnp.asarray(pbuf)
-    if p == 0:
-        dcs.plan_cache[("shortlist", thresh)] = (dcs.version, split, f,
-                                                 aux_d, pbuf_d)
-    return split, f, aux_d, pbuf_d
+    priority while the state version holds, and the device rep mask
+    (`DeviceClusterState.rep_classes`; the delta-free steady state pays
+    two dict probes per plan).  Returns ``(split, f, aux_d, pbuf_d,
+    rep_dev)``."""
+    with obs.span("sched.route"):
+        rep_dev = dcs.rep_classes()[1]
+        cached = (dcs.plan_cache.get(("shortlist", thresh)) if p == 0
+                  else None)
+        if cached is not None and cached[0] == dcs.version:
+            return cached[1:] + (rep_dev,)
+        # gate=NARROW_M: the shortlist's stage 2 always runs NARROW_M wide,
+        # so only genuinely wide (elig > NARROW_M) and overflow rows route
+        # out
+        split = split_fused_nodes(dcs, patches, thresh, gate=NARROW_M)
+        forced = _forced_rows(dcs, patches)
+        fidx = _pad_idx(forced) if forced else np.zeros(0, np.int32)
+        f = len(fidx)
+        if p == 0 and f == 0:
+            aux_d, pbuf_d = _empty_patch_args(dcs.cap)
+        else:
+            aux_d = obs.upload(np.concatenate([pidx, fidx]))
+            pbuf_d = obs.upload(pbuf)
+        if p == 0:
+            dcs.plan_cache[("shortlist", thresh)] = (dcs.version, split, f,
+                                                     aux_d, pbuf_d)
+        return split, f, aux_d, pbuf_d, rep_dev
 
 
 def source_candidates_fused(
@@ -1518,15 +1547,16 @@ def source_candidates_fused(
     ctx = dcs.mirror
     thresh = workload.priority
     ng, nc, cpb = _req_scalars(spec, workload)
-    if nodes is None:
-        patches = _view_patches_of(cluster, dcs)
-    else:
-        delta = set(cluster.delta_nodes()) if hasattr(cluster,
-                                                      "delta_nodes") \
-            else set()
-        delta &= set(nodes)
-        patches = {d: encode_row(cluster, d, ctx.cap) for d in sorted(delta)}
-    p, pidx, pbuf = _patch_args(dcs, patches)
+    with obs.span("sched.patch"):
+        if nodes is None:
+            patches = _view_patches_of(cluster, dcs)
+        else:
+            delta = (set(cluster.delta_nodes())
+                     if hasattr(cluster, "delta_nodes") else set())
+            delta &= set(nodes)
+            patches = {d: encode_row(cluster, d, ctx.cap)
+                       for d in sorted(delta)}
+        p, pidx, pbuf = _patch_args(dcs, patches)
     alpha = float(alpha)
     rq = request_vector(thresh, ng, nc, cpb)
     pargs = None     # (pidx, pbuf) on device, built on first gathered use
@@ -1536,13 +1566,12 @@ def source_candidates_fused(
         if shortlist is not None and dcs.n_rows > shortlist.k:
             # stage 1+2 shortlist dispatch; the decoded certainty flag
             # decides whether the full sweep is still required
-            split, f, aux_d, pbuf_d = _shortlist_plan_args(
+            split, f, aux_d, pbuf_d, rep_dev = _shortlist_plan_args(
                 dcs, patches, thresh, p, pidx, pbuf)
-            rep_dev = dcs.rep_classes()[1]
             res = ev.shortlist_evaluator(spec, shortlist.k, p, f, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain, rep_dev,
                 aux_d, pbuf_d, rq)
-            vals = [int(x) for x in jax.device_get(res)]
+            vals = obs.readback(res)
             if vals[-1] or shortlist.mode != "guaranteed":
                 sl_vals = vals
             else:
@@ -1581,19 +1610,19 @@ def source_candidates_fused(
         excluded = set(mid) | set(split.wide) | set(split.overflow)
         narrow = [c for c in nodes if c not in excluded]
         if narrow:
-            pargs = (jnp.asarray(pidx), jnp.asarray(pbuf))
+            pargs = (obs.upload(pidx), obs.upload(pbuf))
             res = ev.gathered_evaluator(spec, split.m_res, p, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain, *pargs,
-                jnp.asarray(_pad_idx(narrow)), rq)
+                obs.upload(_pad_idx(narrow)), rq)
             pending.append((res, narrow))
     for m, rows in ((NARROW_M, mid), (ctx.cap, split.wide)):
         for lo in range(0, len(rows), MAX_ROWS_WIDE):
             chunk = rows[lo:lo + MAX_ROWS_WIDE]
             if pargs is None:
-                pargs = (jnp.asarray(pidx), jnp.asarray(pbuf))
+                pargs = (obs.upload(pidx), obs.upload(pbuf))
             res = ev.gathered_evaluator(spec, m, p, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain, *pargs,
-                jnp.asarray(_pad_idx(chunk)), rq)
+                obs.upload(_pad_idx(chunk)), rq)
             pending.append((res, chunk))
     for res, sel in pending:
         _append_winner(out, res, sel, patches, ctx)
@@ -1646,17 +1675,18 @@ def plan_normal_fused(cluster, workload: WorkloadSpec):
     base = getattr(cluster, "base", cluster)
     dcs = base.device_state().sync(flush=False)
     ev = _evals(dcs)
-    patches = _view_patches_of(cluster, dcs)
-    p, pidx, pbuf = _patch_args(dcs, patches)
+    with obs.span("sched.patch"):
+        patches = _view_patches_of(cluster, dcs)
+        p, pidx, pbuf = _patch_args(dcs, patches)
+        if p == 0:
+            aux_d, pbuf_d = _empty_patch_args(dcs.cap)
+        else:
+            aux_d, pbuf_d = obs.upload(pidx), obs.upload(pbuf)
     ng, nc, cpb = _req_scalars(spec, workload)
-    if p == 0:
-        aux_d, pbuf_d = _empty_patch_args(dcs.cap)
-    else:
-        aux_d, pbuf_d = jnp.asarray(pidx), jnp.asarray(pbuf)
     res = ev.normal_evaluator(spec, p)(dcs.nodestate, aux_d, pbuf_d,
                                        request_vector(workload.priority, ng,
                                                       nc, cpb))
-    found, node, tier, gm, cm = (int(x) for x in jax.device_get(res))
+    found, node, tier, gm, cm = obs.readback(res)
     if not found:
         return None
     return node, Placement(gpu_mask=gm & 0xFFFFFFFF,
@@ -1672,22 +1702,25 @@ def _finalize_plan(vals, sel, patches, ctx, shortlist_fn, wide_chunks_fn,
     re-dispatches as ``(res, chunk)`` pairs — both LAZY, consumed only when
     the normal cycle placed nothing, so a placed plan never pays for them.
     """
-    nfound, nnode, ntier, ngm, ncm = vals[:5]
-    if nfound:
-        return FusedPlanResult("placed", nnode, Placement(
-            gpu_mask=ngm & 0xFFFFFFFF, cg_mask=ncm & 0xFFFFFFFF,
-            tier=ntier))
-    out = shortlist_fn()
-    _append_winner(out, np.asarray(vals[5:], np.int32), sel, patches, ctx)
-    for res, chunk in wide_chunks_fn():
-        _append_winner(out, res, chunk, patches, ctx)
-    if not out:
-        return FusedPlanResult("rejected", n_candidates=out.n_candidates)
-    chosen = select_best(out, alpha)
-    return FusedPlanResult(
-        "preempted", chosen.node,
-        out.placements.get((chosen.node, chosen.victims)),
-        chosen.victims, out.n_candidates)
+    with obs.span("sched.decode"):
+        nfound, nnode, ntier, ngm, ncm = vals[:5]
+        if nfound:
+            return FusedPlanResult("placed", nnode, Placement(
+                gpu_mask=ngm & 0xFFFFFFFF, cg_mask=ncm & 0xFFFFFFFF,
+                tier=ntier))
+        out = shortlist_fn()
+        _append_winner(out, np.asarray(vals[5:], np.int32), sel, patches,
+                       ctx)
+        for res, chunk in wide_chunks_fn():
+            _append_winner(out, res, chunk, patches, ctx)
+        if not out:
+            return FusedPlanResult("rejected",
+                                   n_candidates=out.n_candidates)
+        chosen = select_best(out, alpha)
+        return FusedPlanResult(
+            "preempted", chosen.node,
+            out.placements.get((chosen.node, chosen.victims)),
+            chosen.victims, out.n_candidates)
 
 
 def _plan_fused_shortlist(cluster, workload: WorkloadSpec,
@@ -1704,12 +1737,11 @@ def _plan_fused_shortlist(cluster, workload: WorkloadSpec,
     ng, nc, cpb = _req_scalars(spec, workload)
     alpha = float(alpha)
     rq = request_vector(thresh, ng, nc, cpb)
-    split, f, aux_d, pbuf_d = _shortlist_plan_args(dcs, patches, thresh,
-                                                   p, pidx, pbuf)
-    rep_dev = dcs.rep_classes()[1]
+    split, f, aux_d, pbuf_d, rep_dev = _shortlist_plan_args(
+        dcs, patches, thresh, p, pidx, pbuf)
     res = ev.shortlist_plan_evaluator(spec, shortlist.k, p, f, alpha)(
         dcs.nodestate, dcs.victims, dcs.drain, rep_dev, aux_d, pbuf_d, rq)
-    vals = [int(x) for x in jax.device_get(res)]
+    vals = obs.readback(res)
     if (not vals[0] and not vals[-1]
             and shortlist.mode == "guaranteed"):
         return None
@@ -1728,8 +1760,8 @@ def _plan_fused_shortlist(cluster, workload: WorkloadSpec,
             chunk = split.wide[lo:lo + MAX_ROWS_WIDE]
             yield ev.gathered_evaluator(spec, ctx.cap, p, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain,
-                jnp.asarray(pidx), jnp.asarray(pbuf),
-                jnp.asarray(_pad_idx(chunk)), rq), chunk
+                obs.upload(pidx), obs.upload(pbuf),
+                obs.upload(_pad_idx(chunk)), rq), chunk
 
     return _finalize_plan(vals[:5 + WIN_FIELDS], sel, patches, ctx,
                           shortlist_out, wide_chunks, alpha)
@@ -1768,8 +1800,9 @@ def plan_fused(cluster, workload: WorkloadSpec, alpha: float = DEFAULT_ALPHA,
     ctx = dcs.mirror
     thresh = workload.priority
     ng, nc, cpb = _req_scalars(spec, workload)
-    patches = _view_patches_of(cluster, dcs)
-    p, pidx, pbuf = _patch_args(dcs, patches)
+    with obs.span("sched.patch"):
+        patches = _view_patches_of(cluster, dcs)
+        p, pidx, pbuf = _patch_args(dcs, patches)
     if shortlist is not None and dcs.n_rows > shortlist.k:
         got = _plan_fused_shortlist(cluster, workload, dcs, ev, ctx,
                                     patches, p, pidx, pbuf, alpha,
@@ -1785,7 +1818,7 @@ def plan_fused(cluster, workload: WorkloadSpec, alpha: float = DEFAULT_ALPHA,
     rq = request_vector(thresh, ng, nc, cpb)
     res = ev.plan_evaluator(spec, split.m_res, p, g, alpha)(
         dcs.nodestate, dcs.victims, dcs.drain, aux_d, pbuf_d, rq)
-    vals = [int(x) for x in jax.device_get(res)]
+    vals = obs.readback(res)
     n = dcs.n_rows
     sel = {n + j: node for j, node in enumerate(mid)} if mid else None
 
@@ -1802,8 +1835,8 @@ def plan_fused(cluster, workload: WorkloadSpec, alpha: float = DEFAULT_ALPHA,
             chunk = split.wide[lo:lo + MAX_ROWS_WIDE]
             yield ev.gathered_evaluator(spec, ctx.cap, p, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain,
-                jnp.asarray(pidx), jnp.asarray(pbuf),
-                jnp.asarray(_pad_idx(chunk)), rq), chunk
+                obs.upload(pidx), obs.upload(pbuf),
+                obs.upload(_pad_idx(chunk)), rq), chunk
 
     return _finalize_plan(vals, sel, patches, ctx, shortlist, wide_chunks,
                           alpha)
@@ -1865,8 +1898,8 @@ class BatchSourcingSession:
         self.class_data = self.ev.batch_class_evaluator(self.spec, self.gate,
                                                         self.alpha)(
             self.dcs.nodestate, self.dcs.victims, self.dcs.drain,
-            jnp.asarray(th), jnp.asarray(ng), jnp.asarray(nc),
-            jnp.asarray(cpb))
+            obs.upload(th), obs.upload(ng), obs.upload(nc),
+            obs.upload(cpb))
 
     def reset_view_caches(self) -> None:
         """Drop per-view state before serving a new ``plan_batch`` call
@@ -1944,12 +1977,13 @@ class BatchSourcingSession:
         if len(didx) == 0 and len(gidx) == 0:
             aux_d, pbuf_d = _empty_patch_args(cap)
         else:
-            aux_d = jnp.asarray(np.concatenate([didx, pidx, gidx]))
-            pbuf_d = jnp.asarray(pbuf)
+            aux_d = obs.upload(np.concatenate([didx, pidx, gidx]))
+            pbuf_d = obs.upload(pbuf)
         res = self.ev.batch_merge_evaluator(self.spec, NARROW_M, len(didx),
                                             len(gidx), self.alpha)(
             *self.class_data, self.dcs.nodestate, self.dcs.victims,
-            self.dcs.drain, jnp.int32(i), aux_d, pbuf_d, rq)
+            self.dcs.drain, obs.upload(np.asarray(i, np.int32)), aux_d,
+            pbuf_d, rq)
         sel = {n + j: node for j, node in enumerate(gather)}
         pending.append((res, sel))
         # wide rows (9..16 eligible victims): chunked 2^cap dispatches —
@@ -1957,14 +1991,14 @@ class BatchSourcingSession:
         if d_wide or wide:
             pw, pwidx, pwbuf = _pack_patches(
                 {d: patches[d] for d in d_wide}, cap)
-            pargs = (jnp.asarray(pwidx), jnp.asarray(pwbuf))
+            pargs = (obs.upload(pwidx), obs.upload(pwbuf))
             rows = d_wide + wide
             for lo in range(0, len(rows), MAX_ROWS_WIDE):
                 chunk = rows[lo:lo + MAX_ROWS_WIDE]
                 res = self.ev.gathered_evaluator(self.spec, cap, pw,
                                                  self.alpha)(
                     self.dcs.nodestate, self.dcs.victims, self.dcs.drain,
-                    *pargs, jnp.asarray(_pad_idx(chunk)), rq)
+                    *pargs, obs.upload(_pad_idx(chunk)), rq)
                 pending.append((res, chunk))
         for res, sel in pending:
             _append_winner(out, res, sel, patches, ctx)
@@ -1984,25 +2018,27 @@ class BatchSourcingSession:
         ctx = self.ctx
         cap = ctx.cap
         n = self.dcs.n_rows
-        (delta, patches, mid, wide, overflow, d_over, d_wide,
-         d_dense) = self._route(view, thresh)
-        # ALL delta rows ride the overlay (wide/overflow included): the
-        # normal cycle needs the view's exact free masks everywhere
-        p, pidx, pbuf = _pack_patches(patches, cap)
-        gather = sorted(d_dense) + mid
-        didx = _pad_idx(delta) if delta else np.zeros(0, np.int32)
-        gidx = _pad_idx(gather) if gather else np.zeros(0, np.int32)
-        if len(didx) == 0 and len(gidx) == 0 and p == 0:
-            aux_d, pbuf_d = _empty_patch_args(cap)
-        else:
-            aux_d = jnp.asarray(np.concatenate([didx, pidx, gidx]))
-            pbuf_d = jnp.asarray(pbuf)
+        with obs.span("sched.patch"):
+            (delta, patches, mid, wide, overflow, d_over, d_wide,
+             d_dense) = self._route(view, thresh)
+            # ALL delta rows ride the overlay (wide/overflow included): the
+            # normal cycle needs the view's exact free masks everywhere
+            p, pidx, pbuf = _pack_patches(patches, cap)
+            gather = sorted(d_dense) + mid
+            didx = _pad_idx(delta) if delta else np.zeros(0, np.int32)
+            gidx = _pad_idx(gather) if gather else np.zeros(0, np.int32)
+            if len(didx) == 0 and len(gidx) == 0 and p == 0:
+                aux_d, pbuf_d = _empty_patch_args(cap)
+            else:
+                aux_d = obs.upload(np.concatenate([didx, pidx, gidx]))
+                pbuf_d = obs.upload(pbuf)
         rq = request_vector(thresh, ng, nc, cpb)
         res = self.ev.batch_plan_evaluator(self.spec, NARROW_M, len(didx),
                                            len(gidx), p, self.alpha)(
             *self.class_data, self.dcs.nodestate, self.dcs.victims,
-            self.dcs.drain, jnp.int32(i), aux_d, pbuf_d, rq)
-        vals = [int(x) for x in jax.device_get(res)]
+            self.dcs.drain, obs.upload(np.asarray(i, np.int32)), aux_d,
+            pbuf_d, rq)
+        vals = obs.readback(res)
         sel = {n + j: node for j, node in enumerate(gather)}
 
         def shortlist():
@@ -2023,14 +2059,14 @@ class BatchSourcingSession:
                 return
             pw, pwidx, pwbuf = _pack_patches(
                 {d: patches[d] for d in d_wide}, cap)
-            pargs = (jnp.asarray(pwidx), jnp.asarray(pwbuf))
+            pargs = (obs.upload(pwidx), obs.upload(pwbuf))
             rows = d_wide + wide
             for lo in range(0, len(rows), MAX_ROWS_WIDE):
                 chunk = rows[lo:lo + MAX_ROWS_WIDE]
                 yield self.ev.gathered_evaluator(self.spec, cap, pw,
                                                  self.alpha)(
                     self.dcs.nodestate, self.dcs.victims, self.dcs.drain,
-                    *pargs, jnp.asarray(_pad_idx(chunk)), rq), chunk
+                    *pargs, obs.upload(_pad_idx(chunk)), rq), chunk
 
         return _finalize_plan(vals, sel, patches, ctx, shortlist,
                               wide_chunks, self.alpha)
@@ -2068,7 +2104,8 @@ def persistent_batch_session(cluster: Cluster, workloads,
     if session is not None and session.cache_key == key:
         session.reset_view_caches()
         return session
-    session = BatchSourcingSession(cluster, workloads, alpha)
+    with obs.span("sched.batch_session"):
+        session = BatchSourcingSession(cluster, workloads, alpha)
     entry["session"] = session
     return session
 

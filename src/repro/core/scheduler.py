@@ -81,6 +81,7 @@ import time
 import warnings
 from typing import Callable, Iterable
 
+from .. import obs
 from . import preemption, preemption_jax  # noqa: F401  (self-register engines)
 from .cluster import Cluster, ClusterView
 from .decisions import SchedulingDecision, Transaction
@@ -224,7 +225,9 @@ class TopoScheduler:
         self._fused_place = (self.topology_aware
                              and bool(getattr(self._engine, "fused_place",
                                               False)))
-        self.sourcing_us_log: list[float] = []
+        #: number of the latest ``plan()``; its decision carries it, so the
+        #: ``sched.plan`` and ``sched.commit`` spans of one decision share it
+        self.seq = 0
         self.listeners: list[Callable[[SchedulingDecision, str], None]] = []
         if warmup:
             warm = getattr(self._engine, "warmup", None)
@@ -372,7 +375,6 @@ class TopoScheduler:
             else:
                 candidates = self._engine.source_all(view, workload, nodes)
         sourcing_us = (time.perf_counter() - t0) * 1e6
-        self.sourcing_us_log.append(sourcing_us)
         if not candidates:
             return SchedulingDecision(kind="rejected", workload=workload,
                                       sourcing_us=sourcing_us), None
@@ -415,7 +417,7 @@ class TopoScheduler:
     def _plan_fused(
         self, workload: WorkloadSpec, view: ClusterView,
         allow_preempt: bool, session=None, index: int = 0,
-    ) -> tuple[SchedulingDecision, int | None]:
+    ) -> Transaction:
         """One-dispatch Algorithm 1 for ``fused_place`` engines.
 
         The engine's chained program (or the batch session's merged
@@ -435,22 +437,39 @@ class TopoScheduler:
             res = self._engine.plan_fused(view, workload, self.alpha,
                                           allow_preempt)
         sourcing_us = (time.perf_counter() - t0) * 1e6
-        self.sourcing_us_log.append(sourcing_us)
-        if res.kind == "rejected":
-            return SchedulingDecision(kind="rejected", workload=workload,
-                                      sourcing_us=sourcing_us,
-                                      num_candidates=res.n_candidates), None
-        if res.kind == "placed":
-            planned = view.plan_bind(workload, res.node, res.placement)
-            return SchedulingDecision(
-                kind="placed", workload=workload, node=res.node,
-                placement=res.placement,
-                hit=self._hit(workload, res.placement),
-                sourcing_us=sourcing_us), planned.uid
-        # res.placement is None for python-fallback winners: host place()
-        return self._bind_preemption(
-            workload, view, res.node, res.victims, res.placement,
-            sourcing_us, res.n_candidates)
+        with obs.span("sched.bind"):
+            planned_uid = None
+            if res.kind == "rejected":
+                decision = SchedulingDecision(
+                    kind="rejected", workload=workload,
+                    sourcing_us=sourcing_us, num_candidates=res.n_candidates)
+            elif res.kind == "placed":
+                planned_uid = view.plan_bind(workload, res.node,
+                                             res.placement).uid
+                decision = SchedulingDecision(
+                    kind="placed", workload=workload, node=res.node,
+                    placement=res.placement,
+                    hit=self._hit(workload, res.placement),
+                    sourcing_us=sourcing_us)
+            else:
+                # res.placement is None for python-fallback winners: host
+                # place()
+                decision, planned_uid = self._bind_preemption(
+                    workload, view, res.node, res.victims, res.placement,
+                    sourcing_us, res.n_candidates)
+            return self._transaction(workload, decision, view, planned_uid)
+
+    def _transaction(self, workload: WorkloadSpec,
+                     decision: SchedulingDecision | None, view: ClusterView,
+                     planned_uid: int | None) -> Transaction:
+        """Wrap a planned decision (None: rejected) in its `Transaction`."""
+        if decision is None:
+            decision = SchedulingDecision(kind="rejected", workload=workload)
+        decision.sourcing_provenance = dict(self._provenance)
+        decision.seq = self.seq
+        return Transaction(cluster=self.cluster, decision=decision,
+                           on_event=self._notify, view=view,
+                           planned_uid=planned_uid)
 
     # ---- the transactional entry points --------------------------------------------
     def plan(self, workload: WorkloadSpec, *, view: ClusterView | None = None,
@@ -465,45 +484,43 @@ class TopoScheduler:
         free independent evaluation.  Pass a shared ``view`` to compose
         several plans against one snapshot (see ``plan_batch``).
         """
+        self.seq += 1
+        with obs.span("sched.plan", seq=self.seq):
+            return self._plan(workload, view, allow_normal, allow_preempt,
+                              _session, _index)
+
+    def _plan(self, workload: WorkloadSpec, view: ClusterView | None,
+              allow_normal: bool, allow_preempt: bool, session,
+              index: int) -> Transaction:
         view = view if view is not None else ClusterView(self.cluster)
-        decision: SchedulingDecision | None = None
-        planned_uid: int | None = None
         if (self._fused_place and allow_normal
-                and (_session is None or hasattr(_session, "plan"))):
+                and (session is None or hasattr(session, "plan"))):
             # end-to-end device-resident Algorithm 1: BOTH cycles — the
             # normal-cycle argmin, Filtering, Sorting, Eq. 2 selection AND
             # the §3.4 placement masks — run in ONE dispatch (the engine's
             # chained program, or the batch session's merged per-request
             # dispatch)
-            decision, planned_uid = self._plan_fused(
-                workload, view, allow_preempt, session=_session,
-                index=_index)
-        else:
-            if allow_normal:
-                # fused_place engines run the normal cycle on device even
-                # when a custom session lacks the merged plan; host
-                # engines loop here
-                normal = (self._engine.plan_normal(view, workload)
-                          if self._fused_place
-                          else self._plan_normal(workload, view))
-                if normal is not None:
-                    node, placement = normal
-                    planned_uid = view.plan_bind(workload, node,
-                                                 placement).uid
-                    decision = SchedulingDecision(
-                        kind="placed", workload=workload, node=node,
-                        placement=placement,
-                        hit=self._hit(workload, placement),
-                    )
-            if decision is None and allow_preempt:
-                decision, planned_uid = self._plan_preempt(
-                    workload, view, session=_session, index=_index)
-        if decision is None:
-            decision = SchedulingDecision(kind="rejected", workload=workload)
-        decision.sourcing_provenance = dict(self._provenance)
-        return Transaction(cluster=self.cluster, decision=decision,
-                           on_event=self._notify, view=view,
-                           planned_uid=planned_uid)
+            return self._plan_fused(workload, view, allow_preempt,
+                                    session=session, index=index)
+        decision: SchedulingDecision | None = None
+        planned_uid: int | None = None
+        if allow_normal:
+            # fused_place engines run the normal cycle on device even when
+            # a custom session lacks the merged plan; host engines loop here
+            normal = (self._engine.plan_normal(view, workload)
+                      if self._fused_place
+                      else self._plan_normal(workload, view))
+            if normal is not None:
+                node, placement = normal
+                planned_uid = view.plan_bind(workload, node, placement).uid
+                decision = SchedulingDecision(
+                    kind="placed", workload=workload, node=node,
+                    placement=placement, hit=self._hit(workload, placement),
+                )
+        if decision is None and allow_preempt:
+            decision, planned_uid = self._plan_preempt(
+                workload, view, session=session, index=index)
+        return self._transaction(workload, decision, view, planned_uid)
 
     def plan_batch(self, workloads: Iterable[WorkloadSpec],
                    allow_preempt: bool = True) -> list[Transaction]:

@@ -1,0 +1,127 @@
+"""In-program spans and counters (`repro.obs`): recorded under `jax.profiler`
+on the CPU at 128 nodes, with ``imp_batched`` and with ``imp_sharded`` on a
+one-device mesh, and silent while off."""
+from pathlib import Path
+
+import jax
+import pytest
+
+from repro import obs
+from repro.core import TopoScheduler, table3_workloads
+from repro.core.colocation import (ColocationConfig, ColocationSim,
+                                   default_policies)
+from repro.core.simulator import build_scaled_cluster
+
+WLS = {w.name: w for w in table3_workloads()}
+#: online scale-ups that preempt on the saturated fleet, and offline work
+#: the normal cycle places where a preemption left room
+ORDER = ["A", "B", "D", "A", "C", "B"]
+PLAN_STAGES = {"sched.sync", "sched.patch", "sched.route", "sched.dispatch",
+               "sched.readback", "sched.decode", "sched.bind"}
+
+
+@pytest.fixture
+def obs_on():
+    before = obs.enabled
+    obs.enable(True)
+    try:
+        yield
+    finally:
+        obs.enable(before)
+
+
+def _scheduler(engine):
+    # shortlist k below the node count, so the shortlist path runs
+    return TopoScheduler(build_scaled_cluster(128, seed=0), engine=engine,
+                         shortlist_k=32)
+
+
+def _decide(sched, rounds):
+    keys = []
+    for name in ORDER * rounds:
+        dec = sched.plan(WLS[name]).commit()
+        p = dec.placement
+        keys.append((dec.kind, dec.node, dec.victims, dec.hit,
+                     None if p is None else (p.gpu_mask, p.cg_mask, p.tier)))
+    return keys
+
+
+def _program_spans(log_dir):
+    """``(name, start, end, stats)`` of every program span in the trace."""
+    from jax.profiler import ProfileData
+
+    (path,) = Path(log_dir).rglob("*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for p in data.planes if not p.name.startswith("/device")
+            for ln in p.lines for e in ln.events
+            if e.name.startswith(("sched.", "day."))]
+
+
+def _traced(log_dir, fn):
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        return fn()
+    finally:
+        jax.profiler.stop_trace()
+
+
+@pytest.mark.parametrize("engine", ["imp_batched", "imp_sharded"])
+def test_plan_spans_nest_under_their_plan_and_share_its_seq(
+        engine, tmp_path, obs_on):
+    sched = _scheduler(engine)
+    obs.enable(False)
+    _decide(sched, 2)                    # warm-up: every bucket compiled
+    obs.enable(True)
+    dispatches = obs.counters["dispatches"]
+    keys = _traced(tmp_path, lambda: _decide(sched, 1))
+    assert {k[0] for k in keys} >= {"preempted"}
+    spans = _program_spans(tmp_path)
+    plans = [s for s in spans if s[0] == "sched.plan"]
+    commits = [s for s in spans if s[0] == "sched.commit"]
+    assert len(plans) == len(commits) == len(ORDER)
+    assert [p[3]["seq"] for p in plans] == [c[3]["seq"] for c in commits]
+    assert len({p[3]["seq"] for p in plans}) == len(plans)
+    inner = [s for s in spans if s[0] not in ("sched.plan", "sched.commit")]
+    assert {s[0] for s in inner} >= PLAN_STAGES
+    for name, s0, s1, stats in inner:
+        assert any(p0 <= s0 and s1 <= p1 for _, p0, p1, _ in plans), name
+        if name == "sched.dispatch":
+            assert stats["program"] != "f" and stats["program"].endswith(
+                "_sharded") == (engine == "imp_sharded")
+    for _, c0, c1, _ in commits:
+        assert not any(p0 < c1 and c0 < p1 for _, p0, p1, _ in plans)
+    assert obs.counters["dispatches"] - dispatches >= len(plans)
+
+
+@pytest.mark.parametrize("engine", ["imp_batched", "imp_sharded"])
+def test_off_records_nothing_and_decisions_match_on(engine):
+    assert not obs.enabled
+    before = dict(obs.counters)
+    off = _decide(_scheduler(engine), 2)
+    assert dict(obs.counters) == before
+    obs.enable(True)
+    try:
+        on = _decide(_scheduler(engine), 2)
+    finally:
+        obs.enable(False)
+    assert on == off
+    assert obs.counters["dispatches"] > before.get("dispatches", 0)
+    assert obs.counters["h2d_bytes"] > before.get("h2d_bytes", 0)
+
+
+def test_day_spans_hold_the_scheduler_spans(tmp_path, obs_on):
+    cfg = ColocationConfig(num_nodes=12, seed=0, engine="imp_batched",
+                           horizon_hours=3.0)
+    sim = ColocationSim(cfg, policies=default_policies(cfg))
+    _traced(tmp_path, sim.run)
+    spans = _program_spans(tmp_path)
+    names = {s[0] for s in spans}
+    assert {"day.tick", "day.drain", "day.flush", "sched.plan",
+            "sched.commit"} <= names
+    day = [s for s in spans if s[0].startswith("day.")]
+    for name, s0, s1, _ in spans:
+        if name in ("sched.plan", "sched.commit"):
+            assert any(d0 <= s0 and s1 <= d1 for _, d0, d1, _ in day)
