@@ -13,6 +13,7 @@ re-uploads only those rows as one ``.at[rows].set()`` scatter per sync, so a
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import hashlib
 import itertools
@@ -409,6 +410,8 @@ class SourcingContext:
         self.overflow = np.zeros(n, bool)           # count > cap: truncated
         self.next_prio = np.full(n, 2**31 - 1, np.int32)  # 1st unstored prio
         self.fp = np.zeros(n, np.int64)             # equivalence-class hash
+        #: fingerprint classes (`ClassIndex`), built by the first `classes`
+        self._classes: ClassIndex | None = None
         self._dirty: set[int] = set(range(n))
         # journal-driven refresh: the exact mutation stream since the last
         # refresh, plus a per-node dirty-mark counter.  A dirty row whose
@@ -569,8 +572,11 @@ class SourcingContext:
         argmax's uid tie-break, which fires after the node-id refinement
         and therefore never distinguishes across nodes.  Maintained
         incrementally at the same refresh choke points as the rows
-        themselves, so the cost is O(dirty rows) per commit window.
+        themselves, so the cost is O(dirty rows) per commit window.  Once
+        the class index exists, each row whose fingerprint changed is
+        recorded with its old value for `classes` to consume.
         """
+        moved = None if self._classes is None else self._classes.moved
         for node in rows:
             h = hashlib.blake2b(digest_size=8)
             h.update(self.free_gpu[node].tobytes())
@@ -582,7 +588,25 @@ class SourcingContext:
             h.update(self.vc[node].tobytes())
             h.update(self.vp[node].tobytes())
             h.update(self.stored[node].tobytes())
-            self.fp[node] = np.frombuffer(h.digest(), np.int64)[0]
+            new = int(np.frombuffer(h.digest(), np.int64)[0])
+            if moved is not None:
+                old = int(self.fp[node])
+                if new != old:
+                    moved.setdefault(int(node), old)
+            self.fp[node] = new
+
+    def classes(self) -> "ClassIndex":
+        """The fingerprint equivalence classes of ``fp``, up to date.
+
+        Built by one ``np.unique`` on the first call; later calls consume
+        only the rows `refingerprint` recorded since, so a plan after a few
+        commits costs O(changed rows), not O(nodes).  Call after
+        ``refresh()``."""
+        if self._classes is None:
+            self._classes = ClassIndex(self.fp)
+        else:
+            self._classes.update(self.fp)
+        return self._classes
 
     def refresh_row(self, node: int, source) -> None:
         """Fill one row from ``source`` (the base cluster or a ClusterView)."""
@@ -600,6 +624,78 @@ class SourcingContext:
         self.vu[node] = row.vu
         self.rank[node] = row.rank
         self.refingerprint((node,))
+
+
+class ClassIndex:
+    """Fingerprint equivalence classes of a `SourcingContext`'s rows.
+
+    ``members`` maps each fingerprint to its member rows in ascending order;
+    ``rep`` is True exactly at every class's lowest member.  `update` files
+    each row recorded in ``moved`` (row -> the fingerprint it is filed under)
+    into its new class, at a cost bounded by the two classes' sizes; a change
+    to most rows rebuilds from scratch.  ``gen`` moves whenever a bit of
+    ``rep`` flipped, so device copies of the mask are re-uploaded only then.
+    """
+
+    def __init__(self, fp: np.ndarray) -> None:
+        self.moved: dict[int, int] = {}
+        self.gen = 0
+        self._build(fp)
+
+    def _build(self, fp: np.ndarray) -> None:
+        obs.count("rep_rebuilds")
+        uniq, first, inv = np.unique(fp, return_index=True,
+                                     return_inverse=True)
+        order = np.argsort(inv, kind="stable")
+        cuts = np.cumsum(np.bincount(inv, minlength=len(uniq)))[:-1]
+        self.members: dict[int, list[int]] = {
+            int(v): m.tolist()
+            for v, m in zip(uniq.tolist(), np.split(order, cuts))}
+        self.rep = np.zeros(len(fp), bool)
+        self.rep[first] = True
+        self.moved.clear()
+        self.gen += 1
+
+    def update(self, fp: np.ndarray) -> None:
+        moved = self.moved
+        if not moved:
+            return
+        if 2 * len(moved) >= max(len(fp), 2):
+            self._build(fp)
+            return
+        rep, members = self.rep, self.members
+        was: dict[int, bool] = {}       # first value of every bit touched
+
+        def put(row: int, bit: bool) -> None:
+            was.setdefault(row, bool(rep[row]))
+            rep[row] = bit
+
+        for row, old in moved.items():
+            new = int(fp[row])
+            if new == old:
+                continue
+            mem = members[old]
+            i = bisect.bisect_left(mem, row)
+            del mem[i]
+            if i == 0:
+                put(row, False)
+                if mem:
+                    put(mem[0], True)
+                else:
+                    del members[old]
+            mem = members.get(new)
+            if mem is None:
+                members[new] = [row]
+                put(row, True)
+                continue
+            i = bisect.bisect_left(mem, row)
+            mem.insert(i, row)
+            if i == 0:
+                put(mem[1], False)
+                put(row, True)
+        moved.clear()
+        if any(bool(rep[row]) != bit for row, bit in was.items()):
+            self.gen += 1
 
 
 @dataclasses.dataclass
@@ -1104,10 +1200,9 @@ class DeviceClusterState:
         #: the version they were built at and are ignored once it moves
         self.version = 0
         self.plan_cache: dict = {}
-        #: per-version equivalence-class cache: (version, rep bool[n_rows]
-        #: host mask, device copy).  Rebuilt lazily by ``rep_classes`` —
-        #: a plan window with no commits reuses both arrays untouched.
-        self._rep_cache: tuple | None = None
+        #: the last ``rep_classes`` answer: (version, rep bool[n_rows] host
+        #: mask, device copy, the class index's ``gen`` it was uploaded at)
+        self._rep: tuple | None = None
         #: plans that left the device path: ``host_rows`` counts rows
         #: sourced by per-node host Python (victims past the stored
         #: prefix), ``shortlist_full_sweep`` guaranteed-mode shortlist
@@ -1171,19 +1266,26 @@ class DeviceClusterState:
         id before any uid comparison, the full-sweep winner inside a
         class is always its lowest-id member — sweeping representatives
         only is exact.  Call after ``sync()`` (the mirror fingerprints
-        must be fresh); cached per ``version`` so plan-only windows pay
-        a dict probe.  Rows past ``num_nodes`` (sharded padding) carry
-        sentinel node ids and stay False.
+        must be fresh).  The mask comes from the mirror's class index
+        (`SourcingContext.classes`), which files only the rows whose
+        fingerprint changed since the last call; the same ``version``
+        returns the same arrays, a new one a new host array, and the device
+        copy is re-uploaded only when a bit of the mask flipped.  Rows past
+        ``num_nodes`` (sharded padding) carry sentinel node ids and stay
+        False.
         """
-        cache = self._rep_cache
-        if cache is not None and cache[0] == self.version:
-            return cache[1], cache[2]
-        n = self.cluster.num_nodes
+        last = self._rep
+        if last is not None and last[0] == self.version:
+            return last[1], last[2]
+        idx = self.mirror.classes()
         rep = np.zeros(self.n_rows, bool)
-        _, first = np.unique(self.mirror.fp[:n], return_index=True)
-        rep[first] = True
-        rep_dev = self._upload_rep(rep)
-        self._rep_cache = (self.version, rep, rep_dev)
+        rep[:self.cluster.num_nodes] = idx.rep
+        if last is not None and last[3] == idx.gen:
+            rep_dev = last[2]
+        else:
+            obs.count("rep_uploads")
+            rep_dev = self._upload_rep(rep)
+        self._rep = (self.version, rep, rep_dev, idx.gen)
         return rep, rep_dev
 
     def _upload_rep(self, rep):

@@ -1368,18 +1368,25 @@ def split_fused_nodes(dcs: DeviceClusterState, patches: dict, thresh: int,
     Eligible victims are a prefix of each (priority, uid)-sorted row, so
     every row is classified by one vectorized count over the host mirror
     (patched rows overridden).  ``gate`` pins the main-dispatch width
-    (the batch session precomputes class data at `NARROW_M`); when None,
+    (the shortlist and the batch session run at a fixed width); when None,
     the width adapts: `MIN_M` if at most `MAX_ROWS_WIDE` rows exceed it.
-    When no node stores more than `MIN_M` victims (``dcs.count_max``) the
-    whole scan is skipped.
+
+    The [n, cap] scan is skipped when no row can route out: when no node
+    stores more victims (``dcs.count_max``), and no patch row holds more,
+    than the width ``w`` (``gate`` capped at `NARROW_M`, or `MIN_M` when
+    adaptive).  Then no row has ``elig > w`` (mid, wide) and none is
+    truncated (``count > cap >= w``), so the split is exactly empty.
     """
     ctx = dcs.mirror
+    m = MIN_M if gate is None else gate
+    w = min(m, NARROW_M)
     if isinstance(patches, ViewDelta):
-        patch_big = [n for n in patches if patches.count(n) > MIN_M]
+        patch_big = any(patches.count(n) > w for n in patches)
     else:
-        patch_big = [n for n, r in patches.items() if r.count > MIN_M]
-    if dcs.count_max <= MIN_M and not patch_big:
-        return FusedSplit(MIN_M, [], [], [])
+        patch_big = any(r.count > w for r in patches.values())
+    if dcs.count_max <= w and not patch_big:
+        return FusedSplit(m, [], [], [])
+    obs.count("route_scans")
     n = dcs.cluster.num_nodes
     elig = ((ctx.vp < thresh) & ctx.stored).sum(axis=1)
     bad = ctx.overflow & (ctx.next_prio < thresh)
@@ -1470,17 +1477,19 @@ def _forced_rows(dcs: DeviceClusterState, patches) -> list[int]:
     these two corrections close exactly the ways one can go missing.
     Pending rows (``sync(flush=False)`` leftovers) need nothing: their
     mirror fingerprints are fresh, so the rep assignment already matches
-    the content the overlay installs.
+    the content the overlay installs.  Class members come from the
+    mirror's class index (`SourcingContext.classes`), in ascending order.
     """
     pset = {int(n) for n in patches} if patches else set()
     if not pset:
         return []
-    fp = dcs.mirror.fp
+    ctx = dcs.mirror
+    members = ctx.classes().members
     forced = set(pset)
     for d in pset:
-        for mbr in np.nonzero(fp == fp[d])[0]:
-            if int(mbr) not in pset:
-                forced.add(int(mbr))
+        for mbr in members[int(ctx.fp[d])]:
+            if mbr not in pset:
+                forced.add(mbr)
                 break
     return sorted(forced)
 
@@ -1490,9 +1499,11 @@ def _shortlist_plan_args(dcs: DeviceClusterState, patches, thresh: int,
     """`_fast_plan_args`'s shortlist twin: wide/overflow routing split +
     forced-rep indices + the combined aux upload, cached per preemptor
     priority while the state version holds, and the device rep mask
-    (`DeviceClusterState.rep_classes`; the delta-free steady state pays
-    two dict probes per plan).  Returns ``(split, f, aux_d, pbuf_d,
-    rep_dev)``."""
+    (`DeviceClusterState.rep_classes`).  After commits the host work
+    follows the rows that changed: the class index files only those,
+    the mask is re-uploaded only when a bit of it flipped, and the
+    routing scan runs only when some row stores more than `NARROW_M`
+    victims.  Returns ``(split, f, aux_d, pbuf_d, rep_dev)``."""
     with obs.span("sched.route"):
         rep_dev = dcs.rep_classes()[1]
         cached = (dcs.plan_cache.get(("shortlist", thresh)) if p == 0

@@ -1,6 +1,7 @@
 """In-program spans and counters (`repro.obs`): recorded under `jax.profiler`
 on the CPU at 128 nodes, with ``imp_batched`` and with ``imp_sharded`` on a
 one-device mesh, and silent while off."""
+from collections import Counter
 from pathlib import Path
 
 import jax
@@ -125,3 +126,27 @@ def test_day_spans_hold_the_scheduler_spans(tmp_path, obs_on):
     for name, s0, s1, _ in spans:
         if name in ("sched.plan", "sched.commit"):
             assert any(d0 <= s0 and s1 <= d1 for _, d0, d1, _ in day)
+
+
+@pytest.mark.parametrize("engine", ["imp_batched", "imp_sharded"])
+def test_route_counters_follow_the_changed_rows(engine, obs_on):
+    """On a saturated fleet planned through the shortlist, the first plan
+    builds the class index; after it no plan scans the routing split or
+    rebuilds the index, and the rep mask is uploaded at most once a plan.
+    (k=64 of 128 rows: every shortlist winner is certain, so no plan takes
+    the full-sweep fallback, whose adaptive width may scan.)"""
+    sched = TopoScheduler(build_scaled_cluster(128, seed=0), engine=engine,
+                          shortlist_k=64)
+    before = Counter(obs.counters)
+    sched.plan(WLS["A"]).commit()
+    first = Counter(obs.counters)
+    assert first["rep_rebuilds"] - before["rep_rebuilds"] == 1
+    assert first["rep_uploads"] - before["rep_uploads"] == 1
+    keys = _decide(sched, 2)
+    assert {k[0] for k in keys} >= {"preempted"}
+    got = {name: obs.counters[name] - first[name]
+           for name in ("route_scans", "rep_rebuilds", "rep_uploads")}
+    assert got["route_scans"] == 0 and got["rep_rebuilds"] == 0
+    assert got["rep_uploads"] <= len(keys)
+    dcs = sched.cluster.device_state(sharded=engine == "imp_sharded")
+    assert dcs.fallbacks["shortlist_full_sweep"] == 0

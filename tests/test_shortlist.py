@@ -12,12 +12,17 @@ import random
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core import (Cluster, ColocationConfig, ShortlistConfig, SPECS,
                         TopoScheduler, run_day_cycle, table3_workloads)
-from repro.core.cluster import SourcingContext
+from repro.core import preemption_jax as pj
+from repro.core.cluster import SourcingContext, ViewDelta, encode_row
+from repro.core.cluster_parallel import ShardedDeviceClusterState
 from repro.core.placement import Placement
+from repro.core.preemption_jax import NARROW_M
 from repro.core.simulator import SimConfig, build_saturated_cluster
-from repro.core.workload import WorkloadSpec
+from repro.core.topology import ServerSpec
+from repro.core.workload import TopoPolicy, WorkloadSpec
 
 WL3 = {w.name: w for w in table3_workloads()}
 
@@ -327,3 +332,264 @@ def test_day_cycle_guaranteed_shortlist_matches_full_sweep():
     assert sl.placements == full.placements
     assert sl.scheduled_perf == pytest.approx(full.scheduled_perf)
     assert sl.offline_goodput == pytest.approx(full.offline_goodput)
+
+
+# ---------------------------------------------------------------------------------
+# Incremental class index against the from-scratch classes
+# ---------------------------------------------------------------------------------
+
+class _PaddedSharded(ShardedDeviceClusterState):
+    """A one-device sharded state whose node axis pads to a multiple of
+    four, as it does on a four-device mesh."""
+
+    @property
+    def n_rows(self) -> int:
+        return -(-max(self.cluster.num_nodes, 1) // 4) * 4
+
+
+def _rep_from_scratch(dcs) -> np.ndarray:
+    """The lowest-index member of every fingerprint class, by `np.unique`."""
+    n = dcs.cluster.num_nodes
+    rep = np.zeros(dcs.n_rows, bool)
+    _, first = np.unique(dcs.mirror.fp[:n], return_index=True)
+    rep[first] = True
+    return rep
+
+
+def _forced_by_scan(dcs, patches) -> list[int]:
+    """`_forced_rows` by a scan of the whole fingerprint column."""
+    pset = {int(n) for n in patches}
+    fp = dcs.mirror.fp
+    forced = set(pset)
+    for d in pset:
+        for mbr in np.nonzero(fp == fp[d])[0]:
+            if int(mbr) not in pset:
+                forced.add(int(mbr))
+                break
+    return sorted(forced)
+
+
+def _fits(cluster, inst) -> bool:
+    """Whether an evicted instance's GPUs and CoreGroups are free again."""
+    fg, fc = cluster.free_masks(inst.node)
+    return (fg & inst.gpu_mask == inst.gpu_mask
+            and fc & inst.cg_mask == inst.cg_mask)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("engine", ["imp_batched", "imp_sharded"])
+def test_class_index_matches_from_scratch(engine, seed, monkeypatch):
+    """Seeded binds, evicts, restores, commits, rollbacks, plan_batch
+    sessions and plans on a shared view (view-delta patch rows): after
+    every sync the incrementally
+    kept rep mask, host and device, equals the from-scratch mask bit for
+    bit (23 nodes: the sharded axis pads to 24, and the pad row stays
+    False), and `_forced_rows` equals the scan, in every plan's own call
+    too.  A step that changes most rows rebuilds the index."""
+    sharded = engine == "imp_sharded"
+    cluster = _sat(num_nodes=23, seed=seed)
+    n = cluster.num_nodes
+    if sharded:
+        cluster._device_state = _PaddedSharded(cluster)
+    forced_calls = []
+    real_forced = pj._forced_rows
+
+    def forced_checked(dcs, patches):
+        got = real_forced(dcs, patches)
+        assert got == _forced_by_scan(dcs, patches)
+        forced_calls.append(len(patches))
+        return got
+
+    monkeypatch.setattr(pj, "_forced_rows", forced_checked)
+    sched = TopoScheduler(cluster, engine=engine, shortlist_k=4)
+    dcs = cluster.device_state(sharded=sharded)
+    rng = random.Random(seed)
+    stash = []          # evicted instances, restored by later steps
+
+    def check():
+        dcs.sync()
+        rep, rep_dev = dcs.rep_classes()
+        want = _rep_from_scratch(dcs)
+        assert np.array_equal(rep, want)
+        assert np.array_equal(np.asarray(rep_dev), want)
+        patched = dict.fromkeys(rng.sample(range(n), 3))
+        assert real_forced(dcs, patched) == _forced_by_scan(dcs, patched)
+
+    def victims():
+        return [i for i in cluster.instances.values() if i.preemptible]
+
+    def step(op):
+        if op in ("commit", "rollback"):
+            txn = sched.plan(WL3[rng.choice("ABCD")])
+            if txn.decision.kind != "rejected":
+                txn.commit()
+                if op == "rollback":
+                    txn.rollback()
+        elif op in ("batch", "view"):
+            wls = [WL3[rng.choice("ABC")] for _ in range(3)]
+            if op == "batch":
+                txns = sched.plan_batch(wls)
+            else:       # plans on one shared view, without a session
+                view = cluster.view()
+                txns = [sched.plan(wl, view=view) for wl in wls]
+            for t in txns:
+                if t.decision.kind != "rejected":
+                    t.commit()
+        elif op == "evict":
+            stash.append(cluster.evict(rng.choice(victims()).uid))
+        elif op == "restore":
+            fits = [i for i in stash if _fits(cluster, i)]
+            if fits:
+                inst = rng.choice(fits)
+                stash.remove(inst)
+                cluster.restore(inst)
+        elif op == "bind":
+            for node in rng.sample(range(n), n):
+                fg, fc = cluster.free_masks(node)
+                both = fg & fc
+                if both:
+                    bit = both & -both
+                    cluster.bind(WL3["D"], node, Placement(bit, bit, 0))
+                    break
+
+    check()
+    ops = ["commit", "rollback", "batch", "view", "evict", "restore", "bind"]
+    for op in ops + [rng.choice(ops) for _ in range(8)]:
+        step(op)
+        check()
+    # majority-dirty: one instance leaves every node that holds one
+    by_node = {}
+    for inst in cluster.instances.values():
+        by_node.setdefault(inst.node, inst)
+    assert 2 * len(by_node) >= n
+    for inst in by_node.values():
+        stash.append(cluster.evict(inst.uid))
+    obs.enable(True)
+    try:
+        before = obs.counters["rep_rebuilds"]
+        check()
+        assert obs.counters["rep_rebuilds"] == before + 1
+    finally:
+        obs.enable(False)
+    for op in ("restore", "view", "batch", "commit", "restore", "rollback"):
+        step(op)
+        check()
+    assert any(forced_calls)    # view-delta patch rows reached the index
+
+
+# ---------------------------------------------------------------------------------
+# Routing gate at the dispatch's width
+# ---------------------------------------------------------------------------------
+
+BIG_CG = ServerSpec(name="bigcg", num_sockets=2, num_numa=8, num_cores=192,
+                    num_gpus=8, coregroup_size=8)   # 24 CoreGroups
+
+
+def _cpu_job(prio: int) -> WorkloadSpec:
+    return WorkloadSpec(f"cpu{prio}", priority=prio, gpus_per_instance=0,
+                        cores_per_instance=8, preemptible=True,
+                        numa_policy=TopoPolicy.NONE,
+                        socket_policy=TopoPolicy.NONE, critical=False,
+                        kind="offline")
+
+
+def _bind_victim(cluster, node: int, rng: random.Random) -> None:
+    """One more preemptible instance on ``node`` of a cluster or a view: a
+    D on a free GPU while the first four last, then CPU-only jobs (priority
+    200 or 300) on the CoreGroups past the GPUs'."""
+    bind = getattr(cluster, "plan_bind", None) or cluster.bind
+    fg, fc = cluster.free_masks(node)
+    low = fg & fc & 0xF
+    if low:
+        bit = low & -low
+        bind(WL3["D"], node, Placement(bit, bit, 0))
+        return
+    cg = fc & ~0xFF
+    bit = cg & -cg
+    bind(_cpu_job(rng.choice((200, 300))), node, Placement(0, bit, 0))
+
+
+def _gate_fleet(top: int, seed: int) -> Cluster:
+    """20 nodes of 0..``top`` victims each, ``top`` reached on one node;
+    past `NARROW_M`, one node is also truncated (18 > `MAX_DENSE_VICTIMS`)."""
+    rng = random.Random(seed)
+    cluster = Cluster(BIG_CG, 20)
+    counts = [rng.randint(0, min(top, NARROW_M)) for _ in range(20)]
+    counts[3] = 0
+    counts[7] = top
+    if top > NARROW_M:
+        counts[11] = 18
+    for node, k in enumerate(counts):
+        for _ in range(k):
+            _bind_victim(cluster, node, rng)
+    return cluster
+
+
+def _split_by_scan(dcs, patches, thresh, nodes, gate):
+    """The routing split from every row's exact content."""
+    ctx = dcs.mirror
+    n = dcs.cluster.num_nodes
+    elig = np.zeros(n, np.int64)
+    bad = np.zeros(n, bool)
+    for node in range(n):
+        if node in patches:
+            r = pj._patch_row(patches, node)
+            elig[node] = ((r.vp < thresh) & r.stored).sum()
+            bad[node] = r.overflow and r.next_priority < thresh
+        else:
+            elig[node] = ((ctx.vp[node] < thresh) & ctx.stored[node]).sum()
+            bad[node] = ctx.overflow[node] and ctx.next_prio[node] < thresh
+    allowed = np.zeros(n, bool)
+    allowed[list(range(n)) if nodes is None else list(nodes)] = True
+    ok = allowed & ~bad
+    m_res = gate
+    if m_res is None:
+        m_res = (pj.MIN_M if int((ok & (elig > pj.MIN_M)).sum())
+                 <= pj.MAX_ROWS_WIDE else NARROW_M)
+    return (m_res,
+            np.nonzero(ok & (elig > m_res) & (elig <= NARROW_M))[0].tolist(),
+            np.nonzero(ok & (elig > NARROW_M))[0].tolist(),
+            np.nonzero(allowed & bad)[0].tolist())
+
+
+@pytest.mark.parametrize("level", [-1, 0, 2])
+@pytest.mark.parametrize("kind", ["dict", "view_delta"])
+@pytest.mark.parametrize("subset", [False, True])
+@pytest.mark.parametrize("gate", [None, NARROW_M])
+def test_routing_gate_matches_full_scan(gate, subset, kind, level):
+    """`split_fused_nodes` equals the full scan on fleets whose largest row
+    sits below, at and above the dispatch width, with no patch rows and
+    with patch rows on both sides of it; the scan runs exactly when some
+    row, stored or patched, holds more victims than the width."""
+    w = pj.MIN_M if gate is None else gate
+    cluster = _gate_fleet(w + level, seed=w + level)
+    dcs = cluster.device_state().sync()
+    assert dcs.count_max == w + level or w + level > NARROW_M
+    rng = random.Random(level)
+    nodes = sorted(rng.sample(range(20), 12)) + [3, 7] if subset else None
+    low = cluster.view()                # patch rows at or under the width
+    _bind_victim(low, 3, rng)
+    low.plan_evict(next(i.uid for i in cluster.instances_on(7)
+                        if i.preemptible))
+    high = cluster.view()               # a patch row past the width
+    for _ in range(w + 1):
+        _bind_victim(high, 3, rng)
+    obs.enable(True)
+    try:
+        for view in (cluster.view(), low, high):
+            if kind == "dict":
+                patches = {d: encode_row(view, d, dcs.cap)
+                           for d in sorted(view.delta_nodes())}
+            else:
+                patches = ViewDelta(view, dcs.mirror, dcs.pending)
+            big = max((pj._patch_row(patches, d).count for d in patches),
+                      default=0)
+            for thresh in (150, 250, 350, 600):
+                scans = obs.counters["route_scans"]
+                got = pj.split_fused_nodes(dcs, patches, thresh, nodes, gate)
+                assert tuple(got) == _split_by_scan(dcs, patches, thresh,
+                                                    nodes, gate)
+                scanned = obs.counters["route_scans"] - scans
+                assert scanned == int(dcs.count_max > w or big > w)
+    finally:
+        obs.enable(False)
