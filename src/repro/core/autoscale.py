@@ -102,9 +102,13 @@ class Autoscaler:
         #: ``sourcing_us``, which host engines record only on preemptive
         #: plans)
         self.plan_us: list[float] = []
+        #: the scale-ups' share of ``plan_us``: one entry per request of a
+        #: `scale_to` chunk, and the number of those ``plan_batch`` calls
+        self.scaleup_us: list[float] = []
+        self.scaleup_calls = 0
 
     def _timed_plan_batch(self, workloads, allow_preempt: bool = True,
-                          pad_to: int = 0):
+                          pad_to: int = 0, log: list | None = None):
         reqs = list(workloads)
         n = len(reqs)
         if pad_to > n:
@@ -116,6 +120,8 @@ class Autoscaler:
         txns = self.scheduler.plan_batch(reqs, allow_preempt=allow_preempt)
         per_req = (time.perf_counter() - t0) * 1e6 / max(1, n)
         self.plan_us.extend([per_req] * n)
+        if log is not None:
+            log.extend([per_req] * n)
         return txns[:n]
 
     def _index(self, delta: int, inst: Instance) -> None:
@@ -177,8 +183,10 @@ class Autoscaler:
             while done < delta:
                 n = min(chunk, delta - done)
                 pad = chunk if 1 < n < chunk else 0
+                self.scaleup_calls += 1
                 for txn in self._timed_plan_batch([policy.workload] * n,
-                                                  pad_to=pad):
+                                                  pad_to=pad,
+                                                  log=self.scaleup_us):
                     dec = txn.commit()
                     if dec.rejected:
                         failures += 1

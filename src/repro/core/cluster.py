@@ -184,6 +184,14 @@ class Cluster:
             self._device_state = DeviceClusterState(self)
         return self._device_state
 
+    def device_counts(self) -> Counter:
+        """The device state's ``counts`` and ``fallbacks`` together; empty
+        (and no state created) when no plan has used the device."""
+        dcs = self._device_state
+        if dcs is None:
+            return Counter()
+        return dcs.counts + dcs.fallbacks
+
     # ---- queries --------------------------------------------------------------------
     def free_masks(self, node: int) -> tuple[int, int]:
         if self.node_index:
@@ -1208,6 +1216,12 @@ class DeviceClusterState:
         #: prefix), ``shortlist_full_sweep`` guaranteed-mode shortlist
         #: checks that re-dispatched the full sweep
         self.fallbacks: Counter = Counter()
+        #: plans and batch sessions that stayed on the device path:
+        #: ``shortlist_plans`` two-stage shortlist dispatches (the
+        #: denominator of ``fallbacks["shortlist_full_sweep"]``),
+        #: ``batch_builds`` / ``batch_reuses`` new against reused
+        #: `persistent_batch_session` sessions
+        self.counts: Counter = Counter()
         self._dirty: set[int] = set(range(cluster.num_nodes))
         cluster.add_dirty_listener(self._mark_dirty)
 

@@ -241,6 +241,19 @@ class ColocationReport:
     num_nodes: int
     horizon_hours: float
     hours: list[HourRow] = dataclasses.field(default_factory=list)
+    #: day totals of the scale-ups and the device path, set at the end of
+    #: the run and kept out of `key_metrics` like ``plan_p50_us`` (wall
+    #: clock, and counts that differ between engines): the median
+    #: per-request plan time of the autoscaler's scale-up ``plan_batch``
+    #: calls alone, and how many such calls there were
+    scaleup_plan_p50_us: float = 0.0
+    scaleup_calls: int = 0
+    #: `persistent_batch_session` sessions built against reused
+    batch_builds: int = 0
+    batch_reuses: int = 0
+    #: two-stage shortlist plans, and those that fell back to the full sweep
+    shortlist_plans: int = 0
+    shortlist_full_sweeps: int = 0
     # fold-forward aggregate cache: day-total properties read from here
     # instead of rescanning every hour row on each access (`compare_*`
     # calls them repeatedly, and a 10k-node day has 24+ rows x ~20
@@ -882,7 +895,9 @@ class ColocationSim:
                 # never happens
                 self._harvest_for_ramp()
             for pol in self.auto.policies:
-                ev = self.auto.scale_to(pol, pol.desired(self._last_load), t)
+                with obs.span("day.scale"):
+                    ev = self.auto.scale_to(pol, pol.desired(self._last_load),
+                                            t)
                 self._acc["failures"] += ev.failures
                 for tier, n in ev.reclaimed_tiers.items():
                     self._acc["reclaimed"][tier] = (
@@ -1256,6 +1271,7 @@ class ColocationSim:
         if self._ran:
             raise RuntimeError("ColocationSim.run() is one-shot")
         self._ran = True
+        counts0 = self.cluster.device_counts()
         horizon = self.cfg.horizon_hours
         handlers = {
             _TICK: lambda t, p: self._handle_tick(t),
@@ -1291,6 +1307,15 @@ class ColocationSim:
             handlers[kind](t, payload)
         self._advance(horizon)
         self._flush(horizon)
+        rep, auto = self.report, self.auto
+        counts = self.cluster.device_counts() - counts0
+        rep.scaleup_plan_p50_us = (statistics.median(auto.scaleup_us)
+                                   if auto.scaleup_us else 0.0)
+        rep.scaleup_calls = auto.scaleup_calls
+        rep.batch_builds = counts["batch_builds"]
+        rep.batch_reuses = counts["batch_reuses"]
+        rep.shortlist_plans = counts["shortlist_plans"]
+        rep.shortlist_full_sweeps = counts["shortlist_full_sweep"]
         # the run is one-shot: detach from the scheduler so a caller that
         # keeps using it does not stream decisions into a finished report
         self.sched.remove_listener(self._on_decision)

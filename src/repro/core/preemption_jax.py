@@ -1579,6 +1579,7 @@ def source_candidates_fused(
             # decides whether the full sweep is still required
             split, f, aux_d, pbuf_d, rep_dev = _shortlist_plan_args(
                 dcs, patches, thresh, p, pidx, pbuf)
+            dcs.counts["shortlist_plans"] += 1
             res = ev.shortlist_evaluator(spec, shortlist.k, p, f, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain, rep_dev,
                 aux_d, pbuf_d, rq)
@@ -1750,6 +1751,7 @@ def _plan_fused_shortlist(cluster, workload: WorkloadSpec,
     rq = request_vector(thresh, ng, nc, cpb)
     split, f, aux_d, pbuf_d, rep_dev = _shortlist_plan_args(
         dcs, patches, thresh, p, pidx, pbuf)
+    dcs.counts["shortlist_plans"] += 1
     res = ev.shortlist_plan_evaluator(spec, shortlist.k, p, f, alpha)(
         dcs.nodestate, dcs.victims, dcs.drain, rep_dev, aux_d, pbuf_d, rq)
     vals = obs.readback(res)
@@ -2114,9 +2116,13 @@ def persistent_batch_session(cluster: Cluster, workloads,
     session = entry["session"]
     if session is not None and session.cache_key == key:
         session.reset_view_caches()
+        session.dcs.counts["batch_reuses"] += 1
+        obs.count("batch_reuses")
         return session
     with obs.span("sched.batch_session"):
         session = BatchSourcingSession(cluster, workloads, alpha)
+    session.dcs.counts["batch_builds"] += 1
+    obs.count("batch_builds")
     entry["session"] = session
     return session
 

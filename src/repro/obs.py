@@ -28,7 +28,8 @@ import numpy as np
 
 #: spans and counters record only while this is True
 enabled = False
-#: ``dispatches`` and ``h2d_bytes``, summed while enabled
+#: every `count` name (``dispatches``, ``h2d_bytes``, ...), summed while
+#: enabled
 counters: Counter = Counter()
 
 _OFF = nullcontext()

@@ -111,6 +111,11 @@ def test_scheduled_perf_positive_and_bounded():
             v for k, v in row.served.items() if k in ("A", "B")))
 
 
+#: day totals that are wall clock or differ between engines
+DAY_COUNTERS = ("scaleup_plan_p50_us", "scaleup_calls", "batch_builds",
+                "batch_reuses", "shortlist_plans", "shortlist_full_sweeps")
+
+
 def test_report_plan_latency_excluded_from_key_metrics():
     _, rep = day("imp", num_nodes=8, horizon=6.0)
     row = rep.hours[0]
@@ -120,6 +125,76 @@ def test_report_plan_latency_excluded_from_key_metrics():
     # stays out of the deterministic metric set too
     assert "compiled_n" not in row.key_metrics()
     assert "compiled_n" in dataclasses.asdict(row)
+    # so do the scale-up and device-path day totals
+    assert rep.scaleup_calls > 0
+    for name in DAY_COUNTERS:
+        assert name not in rep.key_metrics()
+        assert name in dataclasses.asdict(rep)
+
+
+@pytest.mark.parametrize("tick_hours", [1.0, 3.0])
+def test_day_counters_equal_direct_counts(monkeypatch, tick_hours):
+    """The report's scale-up, batch-session and shortlist totals equal
+    counts taken around the calls themselves.  Hourly ticks scale up one
+    replica at a time (single plans through the shortlist, which falls
+    back to the full sweep at 24 rows); three-hour ticks scale up in
+    batches, through `persistent_batch_session`."""
+    from repro.core import engines
+    from repro.core import preemption_jax as pj
+
+    sessions = {"built": 0, "reused": 0}
+
+    def counting_factory(cluster, workloads, alpha):
+        slot = getattr(cluster, "_batch_session_slot", None)
+        before = slot["session"] if slot else None
+        session = pj.persistent_batch_session(cluster, workloads, alpha)
+        sessions["reused" if session is before else "built"] += 1
+        return session
+
+    spec = engines.get_engine("imp_batched")
+    monkeypatch.setitem(engines._REGISTRY, "imp_batched", dataclasses.replace(
+        spec, batch_factory=counting_factory))
+    shortlist = {"plans": 0, "full_sweeps": 0}
+    plan_shortlist = pj._plan_fused_shortlist
+
+    def counting_shortlist(*a, **kw):
+        got = plan_shortlist(*a, **kw)
+        shortlist["plans"] += 1
+        shortlist["full_sweeps"] += got is None
+        return got
+
+    monkeypatch.setattr(pj, "_plan_fused_shortlist", counting_shortlist)
+    scaling, calls = [], []
+    scale_to, plan_batch = Autoscaler.scale_to, TopoScheduler.plan_batch
+
+    def counting_scale_to(self, *a, **kw):
+        scaling.append(True)
+        try:
+            return scale_to(self, *a, **kw)
+        finally:
+            scaling.pop()
+
+    def counting_plan_batch(self, *a, **kw):
+        calls.append(bool(scaling))
+        return plan_batch(self, *a, **kw)
+
+    monkeypatch.setattr(Autoscaler, "scale_to", counting_scale_to)
+    monkeypatch.setattr(TopoScheduler, "plan_batch", counting_plan_batch)
+    cfg = ColocationConfig(num_nodes=24, seed=0, engine="imp_batched",
+                           shortlist_k=8, tick_hours=tick_hours)
+    sim = ColocationSim(cfg, policies=default_policies(cfg))
+    rep = sim.run()
+    assert rep.scaleup_calls == sum(calls) > 0
+    assert rep.scaleup_plan_p50_us > 0
+    assert len(sim.auto.scaleup_us) < len(sim.auto.plan_us)
+    assert rep.batch_builds == sessions["built"]
+    assert rep.batch_reuses == sessions["reused"]
+    assert rep.shortlist_plans == shortlist["plans"] > 0
+    assert rep.shortlist_full_sweeps == shortlist["full_sweeps"]
+    assert rep.shortlist_full_sweeps == (
+        sim.cluster.device_state().fallbacks["shortlist_full_sweep"])
+    if tick_hours > 1.0:
+        assert rep.batch_builds > 0
 
 
 # ---- the O(delta) event loop -------------------------------------------------------

@@ -113,19 +113,37 @@ def test_off_records_nothing_and_decisions_match_on(engine):
     assert obs.counters["h2d_bytes"] > before.get("h2d_bytes", 0)
 
 
-def test_day_spans_hold_the_scheduler_spans(tmp_path, obs_on):
-    cfg = ColocationConfig(num_nodes=12, seed=0, engine="imp_batched",
-                           horizon_hours=3.0)
+@pytest.mark.parametrize("num_nodes,horizon,tick,min_builds", [
+    (12, 3.0, 1.0, 0),
+    # three-hour ticks scale up in batches, through new batch sessions
+    (24, 12.0, 3.0, 1),
+])
+def test_day_spans_hold_the_scheduler_spans(tmp_path, obs_on, num_nodes,
+                                            horizon, tick, min_builds):
+    cfg = ColocationConfig(num_nodes=num_nodes, seed=0, engine="imp_batched",
+                           horizon_hours=horizon, tick_hours=tick)
     sim = ColocationSim(cfg, policies=default_policies(cfg))
-    _traced(tmp_path, sim.run)
+    before = Counter(obs.counters)
+    rep = _traced(tmp_path, sim.run)
     spans = _program_spans(tmp_path)
     names = {s[0] for s in spans}
-    assert {"day.tick", "day.drain", "day.flush", "sched.plan",
+    assert {"day.tick", "day.drain", "day.flush", "day.scale", "sched.plan",
             "sched.commit"} <= names
     day = [s for s in spans if s[0].startswith("day.")]
     for name, s0, s1, _ in spans:
         if name in ("sched.plan", "sched.commit"):
             assert any(d0 <= s0 and s1 <= d1 for _, d0, d1, _ in day)
+    # each batch session is counted once, in the report and in the
+    # program's counters, and opens inside a scale-up
+    assert rep.batch_builds >= min_builds
+    for name in ("batch_builds", "batch_reuses"):
+        assert obs.counters[name] - before[name] == getattr(rep, name)
+    scale = [s for s in spans if s[0] == "day.scale"]
+    assert len(scale) == len(sim.auto.policies) * int(horizon / tick)
+    sessions = [s for s in spans if s[0] == "sched.batch_session"]
+    assert len(sessions) == rep.batch_builds
+    for _, s0, s1, _ in sessions:
+        assert any(d0 <= s0 and s1 <= d1 for _, d0, d1, _ in scale)
 
 
 @pytest.mark.parametrize("engine", ["imp_batched", "imp_sharded"])
