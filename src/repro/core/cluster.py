@@ -641,13 +641,11 @@ class ClassIndex:
     ``rep`` is True exactly at every class's lowest member.  `update` files
     each row recorded in ``moved`` (row -> the fingerprint it is filed under)
     into its new class, at a cost bounded by the two classes' sizes; a change
-    to most rows rebuilds from scratch.  ``gen`` moves whenever a bit of
-    ``rep`` flipped, so device copies of the mask are re-uploaded only then.
+    to most rows rebuilds from scratch.
     """
 
     def __init__(self, fp: np.ndarray) -> None:
         self.moved: dict[int, int] = {}
-        self.gen = 0
         self._build(fp)
 
     def _build(self, fp: np.ndarray) -> None:
@@ -662,7 +660,6 @@ class ClassIndex:
         self.rep = np.zeros(len(fp), bool)
         self.rep[first] = True
         self.moved.clear()
-        self.gen += 1
 
     def update(self, fp: np.ndarray) -> None:
         moved = self.moved
@@ -672,12 +669,6 @@ class ClassIndex:
             self._build(fp)
             return
         rep, members = self.rep, self.members
-        was: dict[int, bool] = {}       # first value of every bit touched
-
-        def put(row: int, bit: bool) -> None:
-            was.setdefault(row, bool(rep[row]))
-            rep[row] = bit
-
         for row, old in moved.items():
             new = int(fp[row])
             if new == old:
@@ -686,24 +677,22 @@ class ClassIndex:
             i = bisect.bisect_left(mem, row)
             del mem[i]
             if i == 0:
-                put(row, False)
+                rep[row] = False
                 if mem:
-                    put(mem[0], True)
+                    rep[mem[0]] = True
                 else:
                     del members[old]
             mem = members.get(new)
             if mem is None:
                 members[new] = [row]
-                put(row, True)
+                rep[row] = True
                 continue
             i = bisect.bisect_left(mem, row)
             mem.insert(i, row)
             if i == 0:
-                put(mem[1], False)
-                put(row, True)
+                rep[mem[1]] = False
+                rep[row] = True
         moved.clear()
-        if any(bool(rep[row]) != bit for row, bit in was.items()):
-            self.gen += 1
 
 
 @dataclasses.dataclass
@@ -868,6 +857,11 @@ def unflatten_rows(buf, cap: int):
     return ns, v, dr
 
 
+def row_width(cap: int) -> int:
+    """int32 words of one flattened row (`flatten_rows`)."""
+    return NODE_FIELDS + VICTIM_FIELDS * cap + DRAIN_FIELDS
+
+
 def apply_rows(ns, v, dr, idx, buf):
     """Scatter flattened rows onto the three stacked tensors (jnp ``.at``
     semantics; `IDX_SENTINEL` pad entries are dropped).  The single shared
@@ -879,16 +873,29 @@ def apply_rows(ns, v, dr, idx, buf):
             dr.at[:, idx].set(c, mode="drop"))
 
 
+def pack_scatter(idx, buf) -> np.ndarray:
+    """A scatter's row indices and flattened rows as ONE int32 buffer
+    (`apply_packed_rows` splits it again): one host→device transfer."""
+    return np.concatenate([idx, buf.reshape(-1)]).astype(np.int32, copy=False)
+
+
+def apply_packed_rows(ns, v, dr, packed):
+    """`apply_rows` from a `pack_scatter` buffer: ``q`` indices, then
+    ``q`` rows (``q`` follows from the static length and row width)."""
+    q = packed.shape[0] // (1 + row_width(v.shape[2]))
+    return apply_rows(ns, v, dr, packed[:q], packed[q:].reshape(q, -1))
+
+
 _SCATTER_JIT = None
 
 
-def _scatter_rows(ns, v, dr, idx, buf):
+def _scatter_rows(ns, v, dr, packed):
     """One jitted scatter updating every dirty row of all three tensors
-    from a single flattened upload buffer."""
+    from a single `pack_scatter` upload."""
     global _SCATTER_JIT
     if _SCATTER_JIT is None:
-        _SCATTER_JIT = obs.program("apply_rows", apply_rows)
-    return _SCATTER_JIT(ns, v, dr, idx, buf)
+        _SCATTER_JIT = obs.program("apply_rows", apply_packed_rows)
+    return _SCATTER_JIT(ns, v, dr, packed)
 
 
 def _pad_pow2(n: int) -> int:
@@ -896,6 +903,28 @@ def _pad_pow2(n: int) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def pack_bits(mask) -> np.ndarray:
+    """``bool[n]`` as ``int32[ceil(n / 32)]`` words: mask bit ``i`` is bit
+    ``i & 31`` of word ``i >> 5`` (`unpack_bits` is the traced inverse);
+    bits past ``n`` are 0."""
+    bits = np.zeros(-(-len(mask) // 32) * 32, bool)
+    bits[:len(mask)] = mask
+    return np.packbits(bits, bitorder="little").view("<i4").astype(
+        np.int32, copy=False)
+
+
+def unpack_bits(words, n: int):
+    """Traced inverse of `pack_bits`: ``bool[n]`` from its int32 words.
+    Each word is shifted by every bit position and the ``[words, 32]``
+    result read in row-major order: elementwise work and a reshape, no
+    gather, which the TPU runs slowly."""
+    import jax.numpy as jnp
+
+    shifts = jnp.arange(32, dtype=jnp.int32)
+    bits = (words[:, None] >> shifts[None, :]) & 1
+    return bits.reshape(-1)[:n] != 0
 
 
 def pad_idx(ids, floor: int = 1) -> np.ndarray:
@@ -1208,8 +1237,7 @@ class DeviceClusterState:
         #: the version they were built at and are ignored once it moves
         self.version = 0
         self.plan_cache: dict = {}
-        #: the last ``rep_classes`` answer: (version, rep bool[n_rows] host
-        #: mask, device copy, the class index's ``gen`` it was uploaded at)
+        #: the last ``rep_classes`` answer: (version, rep bool[n_rows])
         self._rep: tuple | None = None
         #: plans that left the device path: ``host_rows`` counts rows
         #: sourced by per-node host Python (victims past the stored
@@ -1270,42 +1298,30 @@ class DeviceClusterState:
         sharded subclass pads to a multiple of the device count)."""
         return self.cluster.num_nodes
 
-    def rep_classes(self):
+    def rep_classes(self) -> np.ndarray:
         """Equivalence-class representative mask over the node axis.
 
-        Returns ``(rep_host, rep_dev)``: a ``bool[n_rows]`` mask that is
-        True exactly for the LOWEST-index member of every fingerprint
-        class (`SourcingContext.refingerprint`), host- and device-side.
-        Because the fused winner argmax breaks score ties by lower node
-        id before any uid comparison, the full-sweep winner inside a
-        class is always its lowest-id member — sweeping representatives
-        only is exact.  Call after ``sync()`` (the mirror fingerprints
-        must be fresh).  The mask comes from the mirror's class index
-        (`SourcingContext.classes`), which files only the rows whose
-        fingerprint changed since the last call; the same ``version``
-        returns the same arrays, a new one a new host array, and the device
-        copy is re-uploaded only when a bit of the mask flipped.  Rows past
-        ``num_nodes`` (sharded padding) carry sentinel node ids and stay
-        False.
+        A host ``bool[n_rows]`` mask that is True exactly for the
+        LOWEST-index member of every fingerprint class
+        (`SourcingContext.refingerprint`).  Because the fused winner argmax
+        breaks score ties by lower node id before any uid comparison, the
+        full-sweep winner inside a class is always its lowest-id member —
+        sweeping representatives only is exact.  Call after ``sync()`` (the
+        mirror fingerprints must be fresh).  The mask comes from the
+        mirror's class index (`SourcingContext.classes`), which files only
+        the rows whose fingerprint changed since the last call; the same
+        ``version`` returns the same array, a new one a new array.  Rows
+        past ``num_nodes`` (sharded padding) carry sentinel node ids and
+        stay False.  The mask travels bit-packed inside each shortlist
+        plan's argument buffer (`pack_bits`).
         """
         last = self._rep
         if last is not None and last[0] == self.version:
-            return last[1], last[2]
-        idx = self.mirror.classes()
+            return last[1]
         rep = np.zeros(self.n_rows, bool)
-        rep[:self.cluster.num_nodes] = idx.rep
-        if last is not None and last[3] == idx.gen:
-            rep_dev = last[2]
-        else:
-            obs.count("rep_uploads")
-            rep_dev = self._upload_rep(rep)
-        self._rep = (self.version, rep, rep_dev, idx.gen)
-        return rep, rep_dev
-
-    def _upload_rep(self, rep):
-        """Representative-mask upload hook (sharded subclass lays the row
-        axis out over the mesh to match the resident tensors)."""
-        return obs.upload(rep)
+        rep[:self.cluster.num_nodes] = self.mirror.classes().rep
+        self._rep = (self.version, rep)
+        return rep
 
     def _upload_full(self, ns, v, dr):
         """Full-rebuild upload hook (subclasses re-layout/shard here)."""
@@ -1314,7 +1330,7 @@ class DeviceClusterState:
     def _scatter(self, idx, buf):
         """Dirty-row scatter hook (subclasses keep the output sharded)."""
         return _scatter_rows(self.nodestate, self.victims, self.drain,
-                             obs.upload(idx), obs.upload(buf))
+                             obs.upload(pack_scatter(idx, buf)))
 
     def delta_encode(self, a: int, didx, *descs):
         """Run the device-side view-delta encoder against the resident

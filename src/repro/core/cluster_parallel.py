@@ -31,7 +31,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from .. import obs
 from .cluster import (DRAIN_FIELDS, IDX_SENTINEL, NODE_FIELDS, NS_FREE_CG,
                       NS_FREE_GPU, NS_NODE_ID, VF_CG, VF_GPU, VICTIM_FIELDS,
-                      DeviceClusterState, apply_rows, encode_delta_core)
+                      DeviceClusterState, apply_packed_rows,
+                      encode_delta_core, pack_scatter)
 from .engines import register_engine
 from .placement_jax import normal_cycle_core, winner_place
 from .preemption_jax import (Request, _evaluate_subsets_core,
@@ -297,11 +298,12 @@ def _mesh_shardings(mesh):
 @lru_cache(maxsize=None)
 def _sharded_scatter(mesh):
     """jit of the dirty-row scatter with the resident tensors held sharded
-    on both sides (the update rows replicate — they are O(dirty), tiny)."""
+    on both sides (the packed update rows replicate — they are O(dirty),
+    tiny)."""
     node_sh, victim_sh, repl = _mesh_shardings(mesh)
     return obs.program(
-        "apply_rows_sharded", apply_rows,
-        in_shardings=(node_sh, victim_sh, node_sh, repl, repl),
+        "apply_rows_sharded", apply_packed_rows,
+        in_shardings=(node_sh, victim_sh, node_sh, repl),
         out_shardings=(node_sh, victim_sh, node_sh))
 
 
@@ -360,18 +362,11 @@ class ShardedDeviceClusterState(DeviceClusterState):
     def _scatter(self, idx, buf):
         return _sharded_scatter(self.mesh)(
             self.nodestate, self.victims, self.drain,
-            obs.upload(idx), obs.upload(buf))
+            obs.upload(pack_scatter(idx, buf)))
 
     def delta_encode(self, a: int, didx, *descs):
         return _sharded_delta_encoder(self.mesh, self.cap, a)(
             self.nodestate, self.victims, didx, *descs)
-
-    def _upload_rep(self, rep):
-        """Pin the rep mask 1-D row-sharded so the shortlist prescreen
-        stays shard-local up to its top-K collective."""
-        return obs.upload(
-            np.ascontiguousarray(rep),
-            NamedSharding(self.mesh, P(tuple(self.mesh.axis_names))))
 
 
 # ---------------------------------------------------------------------------------
@@ -382,8 +377,8 @@ class _ShardedEvaluators:
     """Drop-in namespace for `preemption_jax._evals`: the SAME pipeline
     bodies (`_plan_pipeline`, `_plan2_pipeline`, `_normal_pipeline`,
     `_gathered_pipeline`, the batch pipelines) jitted with explicit
-    sharding constraints.  Node-axis tensors arrive sharded, every
-    aux/patch upload and the request vector replicate, and the
+    sharding constraints.  Node-axis tensors arrive sharded, each plan's
+    packed argument buffer and the request vector replicate, and the
     int32[`WIN_FIELDS`]-sized winner vectors come back replicated — the
     per-node class math runs shard-local and only the final argmax chain
     (plus the winner-row gather) crosses shards as collectives."""
@@ -401,84 +396,88 @@ class _ShardedEvaluators:
 
     def resident_evaluator(self, spec, m, p, g, alpha):
         def build():
-            def f(nodestate, victims, drain, aux, pbuf, req):
+            def f(nodestate, victims, drain, aux, req):
                 return _pj._plan_pipeline(
-                    nodestate, victims, drain, aux, pbuf,
+                    nodestate, victims, drain, aux,
                     *_pj._req_args(req), alpha, spec=spec, m=m, p=p, g=g)
 
             return obs.program("resident_sweep_sharded", f, in_shardings=(
                 self.node_sh, self.victim_sh, self.node_sh, self.repl,
-                self.repl, self.repl), out_shardings=self.repl)
+                self.repl), out_shardings=self.repl)
 
         return self._get(("res", spec, m, p, g, alpha), build)
 
     def plan_evaluator(self, spec, m, p, g, alpha):
         def build():
-            def f(nodestate, victims, drain, aux, pbuf, req):
+            def f(nodestate, victims, drain, aux, req):
                 return _pj._plan2_pipeline(
-                    nodestate, victims, drain, aux, pbuf,
+                    nodestate, victims, drain, aux,
                     *_pj._req_args(req), alpha, spec=spec, m=m, p=p, g=g)
 
             return obs.program("plan2_sharded", f, in_shardings=(
                 self.node_sh, self.victim_sh, self.node_sh, self.repl,
-                self.repl, self.repl), out_shardings=self.repl)
+                self.repl), out_shardings=self.repl)
 
         return self._get(("plan", spec, m, p, g, alpha), build)
 
     def normal_evaluator(self, spec, p):
         def build():
-            def f(nodestate, aux, pbuf, req):
+            def f(nodestate, aux, req):
                 _, ng, nc, cpb = _pj._req_args(req)
-                return _pj._normal_pipeline(nodestate, aux, pbuf, ng, nc,
-                                            cpb, spec=spec, p=p)
+                return _pj._normal_pipeline(nodestate, aux, ng, nc, cpb,
+                                            spec=spec, p=p)
 
             return obs.program("normal_cycle_sharded", f, in_shardings=(
-                self.node_sh, self.repl, self.repl, self.repl),
+                self.node_sh, self.repl, self.repl),
                 out_shardings=self.repl)
 
         return self._get(("norm", spec, p), build)
 
     def gathered_evaluator(self, spec, m, p, alpha):
         def build():
-            def f(nodestate, victims, drain, pidx, pbuf, gidx, req):
+            def f(nodestate, victims, drain, aux, req):
                 return _pj._gathered_pipeline(
-                    nodestate, victims, drain, pidx, pbuf, gidx,
+                    nodestate, victims, drain, aux,
                     *_pj._req_args(req), alpha, spec=spec, m=m, p=p)
 
             return obs.program("gathered_sharded", f, in_shardings=(
                 self.node_sh, self.victim_sh, self.node_sh, self.repl,
-                self.repl, self.repl, self.repl), out_shardings=self.repl)
+                self.repl), out_shardings=self.repl)
 
         return self._get(("gath", spec, m, p, alpha), build)
 
     @property
     def _rep_sh(self):
-        """1-D row sharding of the equivalence-class rep mask."""
+        """1-D row sharding of the equivalence-class rep mask, unpacked on
+        the device so the shortlist prescreen stays shard-local up to its
+        top-K collective."""
         return NamedSharding(self.mesh, P(tuple(self.mesh.axis_names)))
 
     def shortlist_evaluator(self, spec, k, p, f, alpha):
         def build():
-            def fn(nodestate, victims, drain, rep, aux, pbuf, req):
+            def fn(nodestate, victims, drain, aux, req):
                 return _pj._shortlist_pipeline(
-                    nodestate, victims, drain, rep, aux, pbuf,
-                    *_pj._req_args(req), alpha, spec=spec, k=k, p=p, f=f)
+                    nodestate, victims, drain, aux,
+                    *_pj._req_args(req), alpha, spec=spec, k=k, p=p, f=f,
+                    rep_sh=self._rep_sh)
 
             return obs.program("shortlist_sharded", fn, in_shardings=(
-                self.node_sh, self.victim_sh, self.node_sh, self._rep_sh,
-                self.repl, self.repl, self.repl), out_shardings=self.repl)
+                self.node_sh, self.victim_sh, self.node_sh, self.repl,
+                self.repl), out_shardings=self.repl)
 
         return self._get(("sl", spec, k, p, f, alpha), build)
 
     def shortlist_plan_evaluator(self, spec, k, p, f, alpha):
         def build():
-            def fn(nodestate, victims, drain, rep, aux, pbuf, req):
+            def fn(nodestate, victims, drain, aux, req):
                 return _pj._shortlist_plan2_pipeline(
-                    nodestate, victims, drain, rep, aux, pbuf,
-                    *_pj._req_args(req), alpha, spec=spec, k=k, p=p, f=f)
+                    nodestate, victims, drain, aux,
+                    *_pj._req_args(req), alpha, spec=spec, k=k, p=p, f=f,
+                    rep_sh=self._rep_sh)
 
             return obs.program("shortlist_plan_sharded", fn, in_shardings=(
-                self.node_sh, self.victim_sh, self.node_sh, self._rep_sh,
-                self.repl, self.repl, self.repl), out_shardings=self.repl)
+                self.node_sh, self.victim_sh, self.node_sh, self.repl,
+                self.repl), out_shardings=self.repl)
 
         return self._get(("slplan", spec, k, p, f, alpha), build)
 

@@ -63,8 +63,9 @@ from .cluster import (DRAIN_FIELDS, IDX_SENTINEL, MAX_DENSE_VICTIMS,
                       NS_NODE_ID, NS_OVERFLOW, VF_CG, VF_GPU, VF_PRIO,
                       VF_RANK, VF_STORED, VICTIM_FIELDS, Cluster,
                       DeviceClusterState, VictimRow, ViewDelta, _pad_pow2,
-                      apply_rows, encode_row, flatten_rows,
-                      pack_context_rows, pack_rows, pad_idx, unflatten_rows)
+                      apply_rows, encode_row, flatten_rows, pack_bits,
+                      pack_context_rows, pack_rows, pad_idx, row_width,
+                      unflatten_rows, unpack_bits)
 from .engines import register_engine
 from .placement import Placement
 from .placement_jax import (normal_cycle_core, spec_constants,
@@ -636,6 +637,25 @@ def _overlay_ns(nodestate, idx, buf):
         return nodestate.at[:, idx].set(a, mode="drop")
 
 
+def _split_aux(aux, p: int, s: int, width: int):
+    """Static slices of a plan's packed argument buffer (`_plan_aux`):
+    ``(pidx int32[p], sel int32[s], pbuf int32[p, width], tail)`` — the
+    patch indices, the gather or forced-rep indices, the flattened patch
+    rows, and what follows them (the shortlist's rep-mask words)."""
+    o = p + s
+    e = o + p * width
+    return aux[:p], aux[p:o], aux[o:e].reshape(p, width), aux[e:]
+
+
+def _rep_mask(words, n_rows: int, rep_sh=None):
+    """The rep mask unpacked from its `pack_bits` words, laid out by
+    ``rep_sh`` (the sharded twins' row sharding) when given."""
+    rep = unpack_bits(words, n_rows)
+    if rep_sh is not None:
+        rep = jax.lax.with_sharding_constraint(rep, rep_sh)
+    return rep
+
+
 #: width of a decoded preemption winner: the int32[7] Eq. 2 argmax vector
 #: plus the winner's (gpu_mask, cg_mask) placement from the device scorer
 WIN_FIELDS = 9
@@ -680,19 +700,21 @@ def _sorting_winner(nodestate, victims, drain, gidx,
                             cpb, spec=spec)
 
 
-def _plan_pipeline(nodestate, victims, drain, aux, pbuf,
+def _plan_pipeline(nodestate, victims, drain, aux,
                    thresh, ng, nc, cpb, alpha, *, spec, m, p, g):
     """The preemption phase as one traced pipeline: overlay ``p`` patch
     rows (view deltas + unflushed dirty rows), then `_sorting_winner` —
-    a single dispatch and a single int32[`WIN_FIELDS`] readback."""
+    a single dispatch and a single int32[`WIN_FIELDS`] readback.  ``aux``
+    is the plan's packed ``[pidx | gidx | pbuf]`` buffer."""
+    pidx, gidx, pbuf, _ = _split_aux(aux, p, g, row_width(victims.shape[2]))
     if p:
         nodestate, victims, drain = _overlay(nodestate, victims, drain,
-                                             aux[:p], pbuf)
-    return _sorting_winner(nodestate, victims, drain, aux[p:],
+                                             pidx, pbuf)
+    return _sorting_winner(nodestate, victims, drain, gidx,
                            thresh, ng, nc, cpb, alpha, spec=spec, m=m, g=g)
 
 
-def _plan2_pipeline(nodestate, victims, drain, aux, pbuf,
+def _plan2_pipeline(nodestate, victims, drain, aux,
                     thresh, ng, nc, cpb, alpha, *, spec, m, p, g):
     """BOTH cycles of Algorithm 1 as one traced program.
 
@@ -705,9 +727,10 @@ def _plan2_pipeline(nodestate, victims, drain, aux, pbuf,
     instead of this program.)  Returns int32[5 + `WIN_FIELDS`]: the
     normal winner (found, node, tier, gpu_mask, cg_mask) followed by the
     preemption winner."""
+    pidx, gidx, pbuf, _ = _split_aux(aux, p, g, row_width(victims.shape[2]))
     if p:
         nodestate, victims, drain = _overlay(nodestate, victims, drain,
-                                             aux[:p], pbuf)
+                                             pidx, pbuf)
     with jax.named_scope("normal_cycle"):
         norm = normal_cycle_core(nodestate, ng, nc, cpb, spec=spec)
 
@@ -715,7 +738,7 @@ def _plan2_pipeline(nodestate, victims, drain, aux, pbuf,
         return jnp.zeros(WIN_FIELDS, jnp.int32)
 
     def _preempt(_):
-        return _sorting_winner(nodestate, victims, drain, aux[p:],
+        return _sorting_winner(nodestate, victims, drain, gidx,
                                thresh, ng, nc, cpb, alpha,
                                spec=spec, m=m, g=g)
 
@@ -748,8 +771,8 @@ def resident_evaluator(spec: ServerSpec, m: int, p: int, g: int,
     argument, see `request_vector`; alpha is baked in).  The vmapped
     `batch_class_evaluator` takes the request as its vmap axis instead."""
 
-    def f(nodestate, victims, drain, aux, pbuf, req):
-        return _plan_pipeline(nodestate, victims, drain, aux, pbuf,
+    def f(nodestate, victims, drain, aux, req):
+        return _plan_pipeline(nodestate, victims, drain, aux,
                               *_req_args(req), alpha,
                               spec=spec, m=m, p=p, g=g)
 
@@ -761,18 +784,20 @@ def plan_evaluator(spec: ServerSpec, m: int, p: int, g: int, alpha: float):
     """jit of `_plan2_pipeline` (normal cycle chained into sourcing) — the
     whole ``schedule_or_preempt`` hot path is this one dispatch."""
 
-    def f(nodestate, victims, drain, aux, pbuf, req):
-        return _plan2_pipeline(nodestate, victims, drain, aux, pbuf,
+    def f(nodestate, victims, drain, aux, req):
+        return _plan2_pipeline(nodestate, victims, drain, aux,
                                *_req_args(req), alpha,
                                spec=spec, m=m, p=p, g=g)
 
     return obs.program("plan2", f)
 
 
-def _normal_pipeline(nodestate, aux, pbuf, ng, nc, cpb, *, spec, p):
-    """Nodestate-only patch overlay + the normal-cycle scorer."""
+def _normal_pipeline(nodestate, aux, ng, nc, cpb, *, spec, p):
+    """Nodestate-only patch overlay + the normal-cycle scorer (``aux``:
+    the packed ``[pidx | pbuf]`` buffer)."""
     if p:
-        nodestate = _overlay_ns(nodestate, aux[:p], pbuf)
+        pidx, _, pbuf, _ = _split_aux(aux, p, 0, aux.shape[0] // p - 1)
+        nodestate = _overlay_ns(nodestate, pidx, pbuf)
     with jax.named_scope("normal_cycle"):
         return normal_cycle_core(nodestate, ng, nc, cpb, spec=spec)
 
@@ -785,20 +810,24 @@ def normal_evaluator(spec: ServerSpec, p: int):
     The batch sessions use this as their per-plan normal cycle (one small
     [NODE_FIELDS, N] dispatch instead of the host python node loop)."""
 
-    def f(nodestate, aux, pbuf, req):
+    def f(nodestate, aux, req):
         _, ng, nc, cpb = _req_args(req)
-        return _normal_pipeline(nodestate, aux, pbuf, ng, nc, cpb,
-                                spec=spec, p=p)
+        return _normal_pipeline(nodestate, aux, ng, nc, cpb, spec=spec, p=p)
 
     return obs.program("normal_cycle", f)
 
 
-def _gathered_pipeline(nodestate, victims, drain, pidx, pbuf, gidx,
+def _gathered_pipeline(nodestate, victims, drain, aux,
                        thresh, ng, nc, cpb, alpha, *, spec, m, p):
     """Patch overlay, then DEVICE-SIDE gather of the rows named by
     ``gidx`` (wide nodes, or a batch plan's delta nodes) and the fused
     pipeline over just those rows.  ``IDX_SENTINEL`` entries gather zero
-    rows whose sentinel node id can never win."""
+    rows whose sentinel node id can never win.  ``aux`` is the packed
+    ``[pidx | gidx | pbuf]`` buffer; the gather width follows from its
+    static length."""
+    width = row_width(victims.shape[2])
+    g = aux.shape[0] - p * (1 + width)
+    pidx, gidx, pbuf, _ = _split_aux(aux, p, g, width)
     if p:
         nodestate, victims, drain = _overlay(nodestate, victims, drain,
                                              pidx, pbuf)
@@ -819,9 +848,9 @@ def _gathered_pipeline(nodestate, victims, drain, pidx, pbuf, gidx,
 def gathered_evaluator(spec: ServerSpec, m: int, p: int, alpha: float):
     """jit of `_gathered_pipeline` (``req`` as in `resident_evaluator`)."""
 
-    def f(nodestate, victims, drain, pidx, pbuf, gidx, req):
-        return _gathered_pipeline(nodestate, victims, drain, pidx, pbuf,
-                                  gidx, *_req_args(req), alpha,
+    def f(nodestate, victims, drain, aux, req):
+        return _gathered_pipeline(nodestate, victims, drain, aux,
+                                  *_req_args(req), alpha,
                                   spec=spec, m=m, p=p)
 
     return obs.program("gathered", f)
@@ -973,31 +1002,42 @@ def _shortlist_winner(nodestate, victims, drain, rep,
                                                certain.astype(jnp.int32)])])
 
 
-def _shortlist_pipeline(nodestate, victims, drain, rep, aux, pbuf,
-                        thresh, ng, nc, cpb, alpha, *, spec, k, p, f):
-    """Overlay ``p`` patch rows, force ``f`` rep-mask corrections (patched
-    rows carry stale fingerprints: the rows themselves plus the promoted
-    lowest unpatched member of each patched row's old class), then the
-    two-stage `_shortlist_winner` — one dispatch, one small readback."""
+def _shortlist_args(nodestate, victims, drain, aux, *, p, f, rep_sh):
+    """Unpack a shortlist plan's ``[pidx | fidx | pbuf | rep words]``
+    buffer: overlay the ``p`` patch rows and force the ``f`` rep-mask
+    corrections (patched rows carry stale fingerprints: the rows
+    themselves plus the promoted lowest unpatched member of each patched
+    row's old class) into the unpacked rep mask."""
+    pidx, fidx, pbuf, words = _split_aux(aux, p, f,
+                                         row_width(victims.shape[2]))
+    rep = _rep_mask(words, nodestate.shape[1], rep_sh)
     if p:
         nodestate, victims, drain = _overlay(nodestate, victims, drain,
-                                             aux[:p], pbuf)
+                                             pidx, pbuf)
     if f:
-        rep = rep.at[aux[p:p + f]].set(True, mode="drop")
+        rep = rep.at[fidx].set(True, mode="drop")
+    return nodestate, victims, drain, rep
+
+
+def _shortlist_pipeline(nodestate, victims, drain, aux,
+                        thresh, ng, nc, cpb, alpha, *, spec, k, p, f,
+                        rep_sh=None):
+    """`_shortlist_args`, then the two-stage `_shortlist_winner` — one
+    dispatch, one small readback."""
+    nodestate, victims, drain, rep = _shortlist_args(
+        nodestate, victims, drain, aux, p=p, f=f, rep_sh=rep_sh)
     return _shortlist_winner(nodestate, victims, drain, rep,
                              thresh, ng, nc, cpb, alpha, spec=spec, k=k)
 
 
-def _shortlist_plan2_pipeline(nodestate, victims, drain, rep, aux, pbuf,
-                              thresh, ng, nc, cpb, alpha, *, spec, k, p, f):
+def _shortlist_plan2_pipeline(nodestate, victims, drain, aux,
+                              thresh, ng, nc, cpb, alpha, *, spec, k, p, f,
+                              rep_sh=None):
     """`_plan2_pipeline`'s shortlisted twin: normal cycle first, the
     two-stage preemptive chain only under ``lax.cond`` when it found
     nothing.  Returns int32[5 + `WIN_FIELDS` + 2]."""
-    if p:
-        nodestate, victims, drain = _overlay(nodestate, victims, drain,
-                                             aux[:p], pbuf)
-    if f:
-        rep = rep.at[aux[p:p + f]].set(True, mode="drop")
+    nodestate, victims, drain, rep = _shortlist_args(
+        nodestate, victims, drain, aux, p=p, f=f, rep_sh=rep_sh)
     with jax.named_scope("normal_cycle"):
         norm = normal_cycle_core(nodestate, ng, nc, cpb, spec=spec)
 
@@ -1018,9 +1058,9 @@ def shortlist_evaluator(spec: ServerSpec, k: int, p: int, f: int,
                         alpha: float):
     """jit of `_shortlist_pipeline` (``req`` as in `resident_evaluator`)."""
 
-    def fn(nodestate, victims, drain, rep, aux, pbuf, req):
-        return _shortlist_pipeline(nodestate, victims, drain, rep, aux,
-                                   pbuf, *_req_args(req), alpha,
+    def fn(nodestate, victims, drain, aux, req):
+        return _shortlist_pipeline(nodestate, victims, drain, aux,
+                                   *_req_args(req), alpha,
                                    spec=spec, k=k, p=p, f=f)
 
     return obs.program("shortlist", fn)
@@ -1032,9 +1072,9 @@ def shortlist_plan_evaluator(spec: ServerSpec, k: int, p: int, f: int,
     """jit of `_shortlist_plan2_pipeline` — the shortlisted
     ``schedule_or_preempt`` hot path in one dispatch."""
 
-    def fn(nodestate, victims, drain, rep, aux, pbuf, req):
-        return _shortlist_plan2_pipeline(nodestate, victims, drain, rep,
-                                         aux, pbuf, *_req_args(req), alpha,
+    def fn(nodestate, victims, drain, aux, req):
+        return _shortlist_plan2_pipeline(nodestate, victims, drain, aux,
+                                         *_req_args(req), alpha,
                                          spec=spec, k=k, p=p, f=f)
 
     return obs.program("shortlist_plan", fn)
@@ -1216,10 +1256,17 @@ def _req_scalars(spec: ServerSpec, workload: WorkloadSpec):
 
 @lru_cache(maxsize=None)
 def _empty_patch_args(cap: int):
-    """Cached zero-size device patch arrays for the p=0 (no view deltas)
-    fast path — the common case allocates nothing per plan."""
-    _, pidx, pbuf = _pack_patches({}, cap)
-    return obs.upload(pidx), obs.upload(pbuf)
+    """Cached zero-size device ``(aux, pbuf)`` arrays of a batch session's
+    delta-free plan — the common case allocates nothing per plan."""
+    pa = _pack_patches({}, cap)
+    return obs.upload(pa.idx), obs.upload(pa.host)
+
+
+@lru_cache(maxsize=None)
+def _empty_aux():
+    """The cached zero-length argument buffer of a plan with nothing to
+    send (no patch rows, no gather rows): such a plan uploads nothing."""
+    return obs.upload(np.zeros(0, np.int32))
 
 
 def _evals(dcs: DeviceClusterState):
@@ -1229,9 +1276,9 @@ def _evals(dcs: DeviceClusterState):
     state (`cluster_parallel.ShardedDeviceClusterState`) routes to
     `cluster_parallel.sharded_evaluators`, which jits the SAME pipeline
     bodies with explicit `NamedSharding` constraints — node-axis tensors
-    arrive sharded, patch/index uploads replicate, and the winner vector
-    comes back replicated, so per-node math stays shard-local and only the
-    final argmax chain crosses shards."""
+    arrive sharded, each plan's packed argument buffer replicates, and the
+    winner vector comes back replicated, so per-node math stays
+    shard-local and only the final argmax chain crosses shards."""
     mesh = getattr(dcs, "mesh", None)
     if mesh is None:
         return sys.modules[__name__]
@@ -1261,25 +1308,80 @@ def _patch_elig(patches, thresh: int):
              for n, r in patches.items()})
 
 
-#: the device-encoded patch rows followed by the host-packed ones
-_patch_concat = obs.program("patch_concat",
-                            lambda dbuf, hbuf: jnp.concatenate([dbuf, hbuf]))
+class PatchArgs(NamedTuple):
+    """A plan's overlay rows, before they travel (`_plan_aux`).
+
+    ``p`` is the overlay bucket (0: none); ``idx`` the ``int32[p]`` row
+    indices, device-encoded rows first; ``host`` the host-packed flattened
+    rows that follow them; ``dev`` the device-encoded rows (a `ViewDelta`'s
+    dense rows, ``int32[d, width]`` on the device) or None."""
+
+    p: int
+    idx: np.ndarray
+    host: np.ndarray
+    dev: object = None
 
 
-def _patch_args(dcs: DeviceClusterState, patches):
-    """One overlay buffer covering the view's delta rows (``patches``) AND
-    the device state's unflushed ``pending`` rows (``sync(flush=False)``):
+#: the empty index vector of a plan without gather or forced rows
+_NO_IDX = np.zeros(0, np.int32)
+
+
+@lru_cache(maxsize=None)
+def _merge_dev_rows(prefix: int):
+    """The packed buffer with device-encoded patch rows spliced in after
+    its ``prefix`` index words, ahead of the host-packed rows."""
+    return obs.program("patch_concat", lambda aux, dbuf: jnp.concatenate(
+        [aux[:prefix], dbuf.reshape(-1), aux[prefix:]]))
+
+
+def _plan_aux(pa: PatchArgs, sel=_NO_IDX, words=None):
+    """ONE host→device transfer of a plan's arguments.
+
+    The host writes them into one contiguous int32 buffer in the order the
+    evaluators slice it at static offsets (`_split_aux`): the patch
+    indices, the gather or forced-rep indices ``sel``, the flattened patch
+    rows, then the shortlist's bit-packed rep mask ``words``.  Upload cost
+    is per call, not per byte, so one buffer replaces one upload per
+    array.  Device-encoded rows (``pa.dev``) are spliced in on the device.
+    A plan with nothing to send gets the cached empty buffer."""
+    parts = [pa.idx, sel, pa.host.reshape(-1)]
+    if words is not None:
+        parts.append(words)
+    host = np.concatenate(parts).astype(np.int32, copy=False)
+    if pa.dev is not None:
+        return _merge_dev_rows(len(pa.idx) + len(sel))(obs.upload(host),
+                                                       pa.dev)
+    return obs.upload(host) if len(host) else _empty_aux()
+
+
+def _memo_args(dcs: DeviceClusterState, key, patches, p: int, build):
+    """``build()`` — a plan's routing and its uploaded argument buffer —
+    kept in ``dcs.plan_cache`` under ``key`` while the state ``version``
+    and the overlay bucket ``p`` hold, so a plan against unchanged state
+    uploads nothing.  At one version the pending rows change only when a
+    flush empties them (``p`` moves to 0).  Plans with view-delta rows are
+    not kept: their rows are the view's, not the state's."""
+    if patches:
+        return build()
+    hit = dcs.plan_cache.get(key)
+    if hit is not None and hit[0] == (dcs.version, p):
+        return hit[1]
+    got = build()
+    dcs.plan_cache[key] = ((dcs.version, p), got)
+    return got
+
+
+def _patch_args(dcs: DeviceClusterState, patches) -> PatchArgs:
+    """One overlay covering the view's delta rows (``patches``) AND the
+    device state's unflushed ``pending`` rows (``sync(flush=False)``):
     both classes of stale row ride the same in-dispatch scatter, so the
-    plan hot path pays ONE host→device upload and zero standalone scatter
-    dispatches.
+    plan hot path pays zero standalone scatter dispatches.
 
     ``patches`` is a ``{node: VictimRow}`` dict (legacy / batch-session
     paths) or a `ViewDelta`: its dense rows are then rebuilt ON DEVICE by
-    the delta encoder and only fallback rows are host-packed.  Returns
-    ``(p, pidx, pbuf)`` — ``pidx`` always host int32 (it travels inside
-    the aux upload); ``pbuf`` may already live on device."""
+    the delta encoder and only fallback rows are host-packed."""
     cap = dcs.cap
-    width = NODE_FIELDS + VICTIM_FIELDS * cap + DRAIN_FIELDS
+    width = row_width(cap)
     pending = sorted(set(dcs.pending) - set(patches))
     dense = None
     bufs, ids = [], []
@@ -1298,23 +1400,19 @@ def _patch_args(dcs: DeviceClusterState, patches):
     if pending:
         bufs.append(flatten_rows(*pack_context_rows(dcs.mirror, pending)))
         ids.extend(pending)
-    hidx = hbuf = None
+    hidx, hbuf = _NO_IDX, np.zeros((0, width), np.int32)
     if ids:
         hidx = _pad_idx(ids)
         hbuf = np.concatenate(bufs) if len(bufs) > 1 else bufs[0]
         if len(hidx) > len(ids):
             hbuf = np.pad(hbuf, ((0, len(hidx) - len(ids)), (0, 0)))
     if dense is None:
-        if hbuf is None:
-            return 0, np.zeros(0, np.int32), np.zeros((0, width), np.int32)
-        return len(hidx), hidx, hbuf
-    didx, dbuf = dense
-    if hbuf is None:
-        return len(didx), didx, dbuf
+        return PatchArgs(len(hidx), hidx, hbuf)
     # disjoint node sets by construction; sentinel pads drop out of the
     # overlay scatter, so per-section pow2 buckets concatenate directly
+    didx, dbuf = dense
     idx = np.concatenate([didx, hidx])
-    return len(idx), idx, _patch_concat(dbuf, obs.upload(hbuf))
+    return PatchArgs(len(idx), idx, hbuf, dbuf)
 
 
 def _pad_idx(ids, floor: int = 4) -> np.ndarray:
@@ -1322,22 +1420,19 @@ def _pad_idx(ids, floor: int = 4) -> np.ndarray:
     return pad_idx(ids, floor)
 
 
-def _pack_patches(patches: dict[int, VictimRow], cap: int):
-    """Pack view-delta rows for the in-dispatch overlay.
-
-    Returns ``(p, pidx, pbuf)`` — one flattened int32 upload buffer (see
-    `flatten_rows`) padded to a power-of-two bucket (sentinel indices are
-    dropped by the scatter); ``p`` = 0 when there are no patches, selecting
-    the overlay-free jit variant."""
-    width = NODE_FIELDS + VICTIM_FIELDS * cap + DRAIN_FIELDS
+def _pack_patches(patches: dict[int, VictimRow], cap: int) -> PatchArgs:
+    """Pack view-delta rows for the in-dispatch overlay: host rows
+    flattened (see `flatten_rows`) and padded to a power-of-two bucket
+    (sentinel indices are dropped by the scatter); ``p`` = 0 when there
+    are no patches, selecting the overlay-free jit variant."""
     if not patches:
-        return 0, np.zeros(0, np.int32), np.zeros((0, width), np.int32)
+        return PatchArgs(0, _NO_IDX, np.zeros((0, row_width(cap)), np.int32))
     nodes = sorted(patches)
     buf = flatten_rows(*pack_rows([patches[n] for n in nodes], nodes, cap))
     idx = _pad_idx(nodes)
     if len(idx) > len(nodes):
         buf = np.pad(buf, ((0, len(idx) - len(nodes)), (0, 0)))
-    return len(idx), idx, buf
+    return PatchArgs(len(idx), idx, buf)
 
 
 class FusedSplit(NamedTuple):
@@ -1437,36 +1532,29 @@ def _append_winner(out: CandidateShortlist, res, sel_nodes, patches, ctx):
         gpu_mask=pgm & 0xFFFFFFFF, cg_mask=pcm & 0xFFFFFFFF, tier=tier)
 
 
-def _fast_plan_args(dcs: DeviceClusterState, patches: dict, thresh: int,
-                    p: int, pidx, pbuf):
-    """Routing split + device aux/patch arrays for a nodes=None dispatch.
+def _fast_plan_args(dcs: DeviceClusterState, patches, thresh: int,
+                    pa: PatchArgs):
+    """Routing split + the packed ``[pidx | gidx | pbuf]`` argument buffer
+    of a nodes=None dispatch, in one upload.  Returns ``(split, g, aux)``.
 
-    The delta-free case (``p`` == 0) caches per preemptor priority on the
-    `DeviceClusterState`, keyed by its invalidation ``version``: repeated
-    plans against unchanged state skip the host eligibility scan AND the
-    per-plan host→device upload of the gather indices — the whole host
+    Without view-delta rows it is kept per preemptor priority on the
+    `DeviceClusterState` (`_memo_args`): repeated plans against unchanged
+    state skip the host eligibility scan AND the upload — the whole host
     side of a plan is then one dict lookup."""
-    with obs.span("sched.route"):
-        cached = dcs.plan_cache.get(thresh) if p == 0 else None
-        if cached is not None and cached[0] == dcs.version:
-            return cached[1:]
+
+    def build():
         split = split_fused_nodes(dcs, patches, thresh)
-        gidx = _pad_idx(split.mid) if split.mid else np.zeros(0, np.int32)
-        g = len(gidx)
-        if p == 0 and g == 0:
-            aux_d, pbuf_d = _empty_patch_args(dcs.cap)
-        else:
-            aux_d = obs.upload(np.concatenate([pidx, gidx]))
-            pbuf_d = obs.upload(pbuf)
-        if p == 0:
-            dcs.plan_cache[thresh] = (dcs.version, split, g, aux_d, pbuf_d)
-        return split, g, aux_d, pbuf_d
+        gidx = _pad_idx(split.mid) if split.mid else _NO_IDX
+        return split, len(gidx), _plan_aux(pa, gidx)
+
+    with obs.span("sched.route"):
+        return _memo_args(dcs, thresh, patches, pa.p, build)
 
 
 def _forced_rows(dcs: DeviceClusterState, patches) -> list[int]:
     """Rep-mask corrections for view-delta patch rows.
 
-    The device rep mask is computed from the MIRROR's fingerprints, but
+    The rep mask is computed from the MIRROR's fingerprints, but
     patch rows are overlaid with different content in-dispatch, so (a)
     every patched row must be treated as its own (possibly new) class —
     forced into the rep set — and (b) a patched row may have been the
@@ -1495,37 +1583,28 @@ def _forced_rows(dcs: DeviceClusterState, patches) -> list[int]:
 
 
 def _shortlist_plan_args(dcs: DeviceClusterState, patches, thresh: int,
-                         p: int, pidx, pbuf):
+                         pa: PatchArgs):
     """`_fast_plan_args`'s shortlist twin: wide/overflow routing split +
-    forced-rep indices + the combined aux upload, cached per preemptor
-    priority while the state version holds, and the device rep mask
-    (`DeviceClusterState.rep_classes`).  After commits the host work
-    follows the rows that changed: the class index files only those,
-    the mask is re-uploaded only when a bit of it flipped, and the
-    routing scan runs only when some row stores more than `NARROW_M`
-    victims.  Returns ``(split, f, aux_d, pbuf_d, rep_dev)``."""
-    with obs.span("sched.route"):
-        rep_dev = dcs.rep_classes()[1]
-        cached = (dcs.plan_cache.get(("shortlist", thresh)) if p == 0
-                  else None)
-        if cached is not None and cached[0] == dcs.version:
-            return cached[1:] + (rep_dev,)
-        # gate=NARROW_M: the shortlist's stage 2 always runs NARROW_M wide,
-        # so only genuinely wide (elig > NARROW_M) and overflow rows route
-        # out
+    forced-rep indices + the rep mask (`DeviceClusterState.rep_classes`),
+    bit-packed, all in the plan's one ``[pidx | fidx | pbuf | rep words]``
+    upload, kept per preemptor priority while the state holds
+    (`_memo_args`).  After commits the host work follows the rows that
+    changed: the class index files only those, and the routing scan runs
+    only when some row stores more than `NARROW_M` victims.  Returns
+    ``(split, f, aux)``."""
+
+    def build():
+        # gate=NARROW_M: the shortlist's stage 2 always runs NARROW_M
+        # wide, so only genuinely wide (elig > NARROW_M) and overflow rows
+        # route out
         split = split_fused_nodes(dcs, patches, thresh, gate=NARROW_M)
         forced = _forced_rows(dcs, patches)
-        fidx = _pad_idx(forced) if forced else np.zeros(0, np.int32)
-        f = len(fidx)
-        if p == 0 and f == 0:
-            aux_d, pbuf_d = _empty_patch_args(dcs.cap)
-        else:
-            aux_d = obs.upload(np.concatenate([pidx, fidx]))
-            pbuf_d = obs.upload(pbuf)
-        if p == 0:
-            dcs.plan_cache[("shortlist", thresh)] = (dcs.version, split, f,
-                                                     aux_d, pbuf_d)
-        return split, f, aux_d, pbuf_d, rep_dev
+        fidx = _pad_idx(forced) if forced else _NO_IDX
+        return split, len(fidx), _plan_aux(pa, fidx,
+                                           pack_bits(dcs.rep_classes()))
+
+    with obs.span("sched.route"):
+        return _memo_args(dcs, ("shortlist", thresh), patches, pa.p, build)
 
 
 def source_candidates_fused(
@@ -1567,22 +1646,21 @@ def source_candidates_fused(
             delta &= set(nodes)
             patches = {d: encode_row(cluster, d, ctx.cap)
                        for d in sorted(delta)}
-        p, pidx, pbuf = _patch_args(dcs, patches)
+        pa = _patch_args(dcs, patches)
+    p = pa.p
     alpha = float(alpha)
     rq = request_vector(thresh, ng, nc, cpb)
-    pargs = None     # (pidx, pbuf) on device, built on first gathered use
     pending = []     # dispatches are async: launch all, decode once
     if nodes is None:
         sl_vals = None
         if shortlist is not None and dcs.n_rows > shortlist.k:
             # stage 1+2 shortlist dispatch; the decoded certainty flag
             # decides whether the full sweep is still required
-            split, f, aux_d, pbuf_d, rep_dev = _shortlist_plan_args(
-                dcs, patches, thresh, p, pidx, pbuf)
+            split, f, aux_d = _shortlist_plan_args(dcs, patches, thresh,
+                                                   pa)
             dcs.counts["shortlist_plans"] += 1
             res = ev.shortlist_evaluator(spec, shortlist.k, p, f, alpha)(
-                dcs.nodestate, dcs.victims, dcs.drain, rep_dev,
-                aux_d, pbuf_d, rq)
+                dcs.nodestate, dcs.victims, dcs.drain, aux_d, rq)
             vals = obs.readback(res)
             if vals[-1] or shortlist.mode != "guaranteed":
                 sl_vals = vals
@@ -1598,16 +1676,15 @@ def source_candidates_fused(
         else:
             # the whole pipeline — overlay, Filtering, m_res-wide subsets
             # over ALL rows, the gathered mid tier, and the Eq. 2 argmax —
-            # is ONE dispatch; indices travel as one aux upload (cached
+            # is ONE dispatch; its arguments travel as one upload (cached
             # with the routing split while the state version holds)
-            split, g, aux_d, pbuf_d = _fast_plan_args(dcs, patches, thresh,
-                                                      p, pidx, pbuf)
+            split, g, aux_d = _fast_plan_args(dcs, patches, thresh, pa)
             mid = split.mid
             out = CandidateShortlist(_overflow_candidates(
                 cluster, workload, split.overflow, dcs.fallbacks))
             out.n_candidates = len(out)
             res = ev.resident_evaluator(spec, split.m_res, p, g, alpha)(
-                dcs.nodestate, dcs.victims, dcs.drain, aux_d, pbuf_d, rq)
+                dcs.nodestate, dcs.victims, dcs.drain, aux_d, rq)
             n = dcs.n_rows
             sel = ({n + j: node for j, node in enumerate(mid)}
                    if mid else None)
@@ -1622,19 +1699,16 @@ def source_candidates_fused(
         excluded = set(mid) | set(split.wide) | set(split.overflow)
         narrow = [c for c in nodes if c not in excluded]
         if narrow:
-            pargs = (obs.upload(pidx), obs.upload(pbuf))
             res = ev.gathered_evaluator(spec, split.m_res, p, alpha)(
-                dcs.nodestate, dcs.victims, dcs.drain, *pargs,
-                obs.upload(_pad_idx(narrow)), rq)
+                dcs.nodestate, dcs.victims, dcs.drain,
+                _plan_aux(pa, _pad_idx(narrow)), rq)
             pending.append((res, narrow))
     for m, rows in ((NARROW_M, mid), (ctx.cap, split.wide)):
         for lo in range(0, len(rows), MAX_ROWS_WIDE):
             chunk = rows[lo:lo + MAX_ROWS_WIDE]
-            if pargs is None:
-                pargs = (obs.upload(pidx), obs.upload(pbuf))
             res = ev.gathered_evaluator(spec, m, p, alpha)(
-                dcs.nodestate, dcs.victims, dcs.drain, *pargs,
-                obs.upload(_pad_idx(chunk)), rq)
+                dcs.nodestate, dcs.victims, dcs.drain,
+                _plan_aux(pa, _pad_idx(chunk)), rq)
             pending.append((res, chunk))
     for res, sel in pending:
         _append_winner(out, res, sel, patches, ctx)
@@ -1689,15 +1763,13 @@ def plan_normal_fused(cluster, workload: WorkloadSpec):
     ev = _evals(dcs)
     with obs.span("sched.patch"):
         patches = _view_patches_of(cluster, dcs)
-        p, pidx, pbuf = _patch_args(dcs, patches)
-        if p == 0:
-            aux_d, pbuf_d = _empty_patch_args(dcs.cap)
-        else:
-            aux_d, pbuf_d = obs.upload(pidx), obs.upload(pbuf)
+        pa = _patch_args(dcs, patches)
+        aux_d = _memo_args(dcs, "normal", patches, pa.p,
+                           lambda: _plan_aux(pa))
     ng, nc, cpb = _req_scalars(spec, workload)
-    res = ev.normal_evaluator(spec, p)(dcs.nodestate, aux_d, pbuf_d,
-                                       request_vector(workload.priority, ng,
-                                                      nc, cpb))
+    res = ev.normal_evaluator(spec, pa.p)(
+        dcs.nodestate, aux_d,
+        request_vector(workload.priority, ng, nc, cpb))
     found, node, tier, gm, cm = obs.readback(res)
     if not found:
         return None
@@ -1737,7 +1809,7 @@ def _finalize_plan(vals, sel, patches, ctx, shortlist_fn, wide_chunks_fn,
 
 def _plan_fused_shortlist(cluster, workload: WorkloadSpec,
                           dcs: DeviceClusterState, ev, ctx, patches,
-                          p: int, pidx, pbuf, alpha: float,
+                          pa: PatchArgs, alpha: float,
                           shortlist: ShortlistConfig):
     """The shortlisted chained plan: one `_shortlist_plan2_pipeline`
     dispatch + decode.  Returns None when the certainty check failed in
@@ -1749,11 +1821,10 @@ def _plan_fused_shortlist(cluster, workload: WorkloadSpec,
     ng, nc, cpb = _req_scalars(spec, workload)
     alpha = float(alpha)
     rq = request_vector(thresh, ng, nc, cpb)
-    split, f, aux_d, pbuf_d, rep_dev = _shortlist_plan_args(
-        dcs, patches, thresh, p, pidx, pbuf)
+    split, f, aux_d = _shortlist_plan_args(dcs, patches, thresh, pa)
     dcs.counts["shortlist_plans"] += 1
-    res = ev.shortlist_plan_evaluator(spec, shortlist.k, p, f, alpha)(
-        dcs.nodestate, dcs.victims, dcs.drain, rep_dev, aux_d, pbuf_d, rq)
+    res = ev.shortlist_plan_evaluator(spec, shortlist.k, pa.p, f, alpha)(
+        dcs.nodestate, dcs.victims, dcs.drain, aux_d, rq)
     vals = obs.readback(res)
     if (not vals[0] and not vals[-1]
             and shortlist.mode == "guaranteed"):
@@ -1771,10 +1842,9 @@ def _plan_fused_shortlist(cluster, workload: WorkloadSpec,
     def wide_chunks():
         for lo in range(0, len(split.wide), MAX_ROWS_WIDE):
             chunk = split.wide[lo:lo + MAX_ROWS_WIDE]
-            yield ev.gathered_evaluator(spec, ctx.cap, p, alpha)(
+            yield ev.gathered_evaluator(spec, ctx.cap, pa.p, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain,
-                obs.upload(pidx), obs.upload(pbuf),
-                obs.upload(_pad_idx(chunk)), rq), chunk
+                _plan_aux(pa, _pad_idx(chunk)), rq), chunk
 
     return _finalize_plan(vals[:5 + WIN_FIELDS], sel, patches, ctx,
                           shortlist_out, wide_chunks, alpha)
@@ -1815,22 +1885,20 @@ def plan_fused(cluster, workload: WorkloadSpec, alpha: float = DEFAULT_ALPHA,
     ng, nc, cpb = _req_scalars(spec, workload)
     with obs.span("sched.patch"):
         patches = _view_patches_of(cluster, dcs)
-        p, pidx, pbuf = _patch_args(dcs, patches)
+        pa = _patch_args(dcs, patches)
     if shortlist is not None and dcs.n_rows > shortlist.k:
         got = _plan_fused_shortlist(cluster, workload, dcs, ev, ctx,
-                                    patches, p, pidx, pbuf, alpha,
-                                    shortlist)
+                                    patches, pa, alpha, shortlist)
         if got is not None:
             return got
         # guaranteed-mode certainty check failed: full sweep decides
         dcs.fallbacks["shortlist_full_sweep"] += 1
-    split, g, aux_d, pbuf_d = _fast_plan_args(dcs, patches, thresh,
-                                              p, pidx, pbuf)
+    split, g, aux_d = _fast_plan_args(dcs, patches, thresh, pa)
     mid = split.mid
     alpha = float(alpha)
     rq = request_vector(thresh, ng, nc, cpb)
-    res = ev.plan_evaluator(spec, split.m_res, p, g, alpha)(
-        dcs.nodestate, dcs.victims, dcs.drain, aux_d, pbuf_d, rq)
+    res = ev.plan_evaluator(spec, split.m_res, pa.p, g, alpha)(
+        dcs.nodestate, dcs.victims, dcs.drain, aux_d, rq)
     vals = obs.readback(res)
     n = dcs.n_rows
     sel = {n + j: node for j, node in enumerate(mid)} if mid else None
@@ -1846,10 +1914,9 @@ def plan_fused(cluster, workload: WorkloadSpec, alpha: float = DEFAULT_ALPHA,
         # to have failed — they are unreachable work otherwise
         for lo in range(0, len(split.wide), MAX_ROWS_WIDE):
             chunk = split.wide[lo:lo + MAX_ROWS_WIDE]
-            yield ev.gathered_evaluator(spec, ctx.cap, p, alpha)(
+            yield ev.gathered_evaluator(spec, ctx.cap, pa.p, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain,
-                obs.upload(pidx), obs.upload(pbuf),
-                obs.upload(_pad_idx(chunk)), rq), chunk
+                _plan_aux(pa, _pad_idx(chunk)), rq), chunk
 
     return _finalize_plan(vals, sel, patches, ctx, shortlist, wide_chunks,
                           alpha)
@@ -1983,15 +2050,15 @@ class BatchSourcingSession:
         # ONE dispatch: request i's class tensors minus its delta rows,
         # merged with a NARROW_M-wide pass over the patched dense delta
         # rows AND the untouched mid-tier rows the gate excluded
-        p, pidx, pbuf = _pack_patches({d: patches[d] for d in d_dense}, cap)
+        pa = _pack_patches({d: patches[d] for d in d_dense}, cap)
         gather = sorted(d_dense) + mid
-        didx = _pad_idx(delta) if delta else np.zeros(0, np.int32)
-        gidx = _pad_idx(gather) if gather else np.zeros(0, np.int32)
+        didx = _pad_idx(delta) if delta else _NO_IDX
+        gidx = _pad_idx(gather) if gather else _NO_IDX
         if len(didx) == 0 and len(gidx) == 0:
             aux_d, pbuf_d = _empty_patch_args(cap)
         else:
-            aux_d = obs.upload(np.concatenate([didx, pidx, gidx]))
-            pbuf_d = obs.upload(pbuf)
+            aux_d = obs.upload(np.concatenate([didx, pa.idx, gidx]))
+            pbuf_d = obs.upload(pa.host)
         res = self.ev.batch_merge_evaluator(self.spec, NARROW_M, len(didx),
                                             len(gidx), self.alpha)(
             *self.class_data, self.dcs.nodestate, self.dcs.victims,
@@ -2002,16 +2069,14 @@ class BatchSourcingSession:
         # wide rows (9..16 eligible victims): chunked 2^cap dispatches —
         # patched delta rows and untouched rows alike
         if d_wide or wide:
-            pw, pwidx, pwbuf = _pack_patches(
-                {d: patches[d] for d in d_wide}, cap)
-            pargs = (obs.upload(pwidx), obs.upload(pwbuf))
+            pw = _pack_patches({d: patches[d] for d in d_wide}, cap)
             rows = d_wide + wide
             for lo in range(0, len(rows), MAX_ROWS_WIDE):
                 chunk = rows[lo:lo + MAX_ROWS_WIDE]
-                res = self.ev.gathered_evaluator(self.spec, cap, pw,
+                res = self.ev.gathered_evaluator(self.spec, cap, pw.p,
                                                  self.alpha)(
                     self.dcs.nodestate, self.dcs.victims, self.dcs.drain,
-                    *pargs, obs.upload(_pad_idx(chunk)), rq)
+                    _plan_aux(pw, _pad_idx(chunk)), rq)
                 pending.append((res, chunk))
         for res, sel in pending:
             _append_winner(out, res, sel, patches, ctx)
@@ -2036,18 +2101,18 @@ class BatchSourcingSession:
              d_dense) = self._route(view, thresh)
             # ALL delta rows ride the overlay (wide/overflow included): the
             # normal cycle needs the view's exact free masks everywhere
-            p, pidx, pbuf = _pack_patches(patches, cap)
+            pa = _pack_patches(patches, cap)
             gather = sorted(d_dense) + mid
-            didx = _pad_idx(delta) if delta else np.zeros(0, np.int32)
-            gidx = _pad_idx(gather) if gather else np.zeros(0, np.int32)
-            if len(didx) == 0 and len(gidx) == 0 and p == 0:
+            didx = _pad_idx(delta) if delta else _NO_IDX
+            gidx = _pad_idx(gather) if gather else _NO_IDX
+            if len(didx) == 0 and len(gidx) == 0 and pa.p == 0:
                 aux_d, pbuf_d = _empty_patch_args(cap)
             else:
-                aux_d = obs.upload(np.concatenate([didx, pidx, gidx]))
-                pbuf_d = obs.upload(pbuf)
+                aux_d = obs.upload(np.concatenate([didx, pa.idx, gidx]))
+                pbuf_d = obs.upload(pa.host)
         rq = request_vector(thresh, ng, nc, cpb)
         res = self.ev.batch_plan_evaluator(self.spec, NARROW_M, len(didx),
-                                           len(gidx), p, self.alpha)(
+                                           len(gidx), pa.p, self.alpha)(
             *self.class_data, self.dcs.nodestate, self.dcs.victims,
             self.dcs.drain, obs.upload(np.asarray(i, np.int32)), aux_d,
             pbuf_d, rq)
@@ -2070,16 +2135,14 @@ class BatchSourcingSession:
             # known to have failed
             if not d_wide and not wide:
                 return
-            pw, pwidx, pwbuf = _pack_patches(
-                {d: patches[d] for d in d_wide}, cap)
-            pargs = (obs.upload(pwidx), obs.upload(pwbuf))
+            pw = _pack_patches({d: patches[d] for d in d_wide}, cap)
             rows = d_wide + wide
             for lo in range(0, len(rows), MAX_ROWS_WIDE):
                 chunk = rows[lo:lo + MAX_ROWS_WIDE]
-                yield self.ev.gathered_evaluator(self.spec, cap, pw,
+                yield self.ev.gathered_evaluator(self.spec, cap, pw.p,
                                                  self.alpha)(
                     self.dcs.nodestate, self.dcs.victims, self.dcs.drain,
-                    *pargs, obs.upload(_pad_idx(chunk)), rq), chunk
+                    _plan_aux(pw, _pad_idx(chunk)), rq), chunk
 
         return _finalize_plan(vals, sel, patches, ctx, shortlist,
                               wide_chunks, self.alpha)

@@ -15,7 +15,8 @@ Three helpers carry the device boundary, so call sites stay plain:
   ``program=<name>`` and counts ``dispatches``;
 * `readback` is the device-to-host read of a plan result
   (``sched.readback``);
-* `upload` is a host-to-device copy; it counts ``h2d_bytes``.
+* `upload` is a host-to-device copy; it counts ``h2d_calls`` (one per host
+  array, each a transfer of its own whatever its size) and ``h2d_bytes``.
 """
 from __future__ import annotations
 
@@ -91,9 +92,10 @@ def readback(res) -> list[int]:
 
 
 def upload(x, sharding=None):
-    """``x`` on the device (laid out by ``sharding`` when given); counts the
-    bytes of host arrays."""
+    """``x`` on the device (laid out by ``sharding`` when given); counts
+    host arrays and their bytes."""
     if isinstance(x, np.ndarray):
+        count("h2d_calls")
         count("h2d_bytes", x.nbytes)
     if sharding is not None:
         return jax.device_put(x, sharding)

@@ -1,6 +1,8 @@
 """In-program spans and counters (`repro.obs`): recorded under `jax.profiler`
 on the CPU at 128 nodes, with ``imp_batched`` and with ``imp_sharded`` on a
-one-device mesh, and silent while off."""
+one-device mesh, and silent while off; the host-to-device calls a plan
+makes, at 128 and 512 nodes."""
+import random
 from collections import Counter
 from pathlib import Path
 
@@ -9,6 +11,9 @@ import pytest
 
 from repro import obs
 from repro.core import TopoScheduler, table3_workloads
+from repro.core import preemption_jax as pj
+from repro.core.cluster import DeviceClusterState, ViewDelta
+from repro.core.cluster_parallel import ShardedDeviceClusterState
 from repro.core.colocation import (ColocationConfig, ColocationSim,
                                    default_policies)
 from repro.core.simulator import build_scaled_cluster
@@ -37,14 +42,43 @@ def _scheduler(engine):
                          shortlist_k=32)
 
 
+def _key(dec):
+    p = dec.placement
+    return (dec.kind, dec.node, dec.victims, dec.hit,
+            None if p is None else (p.gpu_mask, p.cg_mask, p.tier))
+
+
 def _decide(sched, rounds):
-    keys = []
-    for name in ORDER * rounds:
-        dec = sched.plan(WLS[name]).commit()
-        p = dec.placement
-        keys.append((dec.kind, dec.node, dec.victims, dec.hit,
-                     None if p is None else (p.gpu_mask, p.cg_mask, p.tier)))
-    return keys
+    return [_key(sched.plan(WLS[name]).commit()) for name in ORDER * rounds]
+
+
+def _side_uploads(monkeypatch) -> dict:
+    """Records the ``h2d_calls`` of each state-sync flush and full upload
+    and of each view-delta encode (`ViewDelta.device_rows`), by kind:
+    they carry no plan argument."""
+    side = {"flush": [], "full": [], "encoder": []}
+
+    def wrap(cls, name, kind):
+        real = cls.__dict__[name]
+
+        def counted(self, *args):
+            before = obs.counters["h2d_calls"]
+            try:
+                return real(self, *args)
+            finally:
+                side[kind].append(obs.counters["h2d_calls"] - before)
+
+        monkeypatch.setattr(cls, name, counted)
+
+    for cls in (DeviceClusterState, ShardedDeviceClusterState):
+        wrap(cls, "_scatter", "flush")
+        wrap(cls, "_upload_full", "full")
+    wrap(ViewDelta, "device_rows", "encoder")
+    return side
+
+
+def _side_total(side) -> int:
+    return sum(sum(v) for v in side.values())
 
 
 def _program_spans(log_dir):
@@ -147,24 +181,104 @@ def test_day_spans_hold_the_scheduler_spans(tmp_path, obs_on, num_nodes,
 
 
 @pytest.mark.parametrize("engine", ["imp_batched", "imp_sharded"])
-def test_route_counters_follow_the_changed_rows(engine, obs_on):
+def test_route_counters_follow_the_changed_rows(engine, obs_on,
+                                                monkeypatch):
     """On a saturated fleet planned through the shortlist, the first plan
     builds the class index; after it no plan scans the routing split or
-    rebuilds the index, and the rep mask is uploaded at most once a plan.
-    (k=64 of 128 rows: every shortlist winner is certain, so no plan takes
-    the full-sweep fallback, whose adaptive width may scan.)"""
+    rebuilds the index, and the rep mask travels at most once a plan,
+    inside the plan's one argument upload.  (k=64 of 128 rows: every
+    shortlist winner is certain, so no plan takes the full-sweep fallback,
+    whose adaptive width may scan.)"""
+    side = _side_uploads(monkeypatch)
     sched = TopoScheduler(build_scaled_cluster(128, seed=0), engine=engine,
                           shortlist_k=64)
     before = Counter(obs.counters)
     sched.plan(WLS["A"]).commit()
     first = Counter(obs.counters)
+    side0 = _side_total(side)
     assert first["rep_rebuilds"] - before["rep_rebuilds"] == 1
-    assert first["rep_uploads"] - before["rep_uploads"] == 1
     keys = _decide(sched, 2)
     assert {k[0] for k in keys} >= {"preempted"}
     got = {name: obs.counters[name] - first[name]
-           for name in ("route_scans", "rep_rebuilds", "rep_uploads")}
+           for name in ("route_scans", "rep_rebuilds", "h2d_calls")}
     assert got["route_scans"] == 0 and got["rep_rebuilds"] == 0
-    assert got["rep_uploads"] <= len(keys)
+    assert got["h2d_calls"] - (_side_total(side) - side0) <= len(keys)
     dcs = sched.cluster.device_state(sharded=engine == "imp_sharded")
     assert dcs.fallbacks["shortlist_full_sweep"] == 0
+
+
+@pytest.mark.parametrize("num_nodes", [128, 512])
+@pytest.mark.parametrize("engine", ["imp_batched", "imp_sharded"])
+def test_each_plan_uploads_its_arguments_at_most_once(engine, num_nodes,
+                                                      obs_on, monkeypatch):
+    """Seeded plans that place, preempt, force rep-mask corrections (plans
+    on a shared view) and cross pending flushes: each single-request
+    ``plan()`` makes at most one host-to-device call for its arguments,
+    and a plan against unchanged state makes none at all.  The state
+    sync's flush (one call: indices and rows packed) and the view-delta
+    encoder's uploads are counted apart, as is the first plan's full
+    upload.  At 512 rows the default K=128 shortlist runs, at 128 the
+    resident program.  Decisions equal the host reference's."""
+    side = _side_uploads(monkeypatch)
+    forced = []
+    real_forced = pj._forced_rows
+
+    def forced_seen(dcs, patches):
+        got = real_forced(dcs, patches)
+        forced.append(len(got))
+        return got
+
+    monkeypatch.setattr(pj, "_forced_rows", forced_seen)
+    sched = TopoScheduler(build_scaled_cluster(num_nodes, seed=0),
+                          engine=engine)
+    ref = TopoScheduler(build_scaled_cluster(num_nodes, seed=0), engine="imp")
+    dcs = sched.cluster.device_state(sharded=engine == "imp_sharded")
+    for name in "ABCD":          # request vectors, full upload, compiles
+        sched.plan(WLS[name])
+    rng = random.Random(num_nodes)
+    uploads, keys, want = [], [], []
+
+    def plan(wl, view=None):
+        calls, s0 = obs.counters["h2d_calls"], _side_total(side)
+        txn = sched.plan(wl, view=view)
+        got = obs.counters["h2d_calls"] - calls
+        uploads.append(got - (_side_total(side) - s0))
+        assert uploads[-1] <= 1, (wl.name, got, side)
+        return txn, got
+
+    def commit(txn):
+        if txn.decision.kind != "rejected":
+            txn.commit()
+        return _key(txn.decision)
+
+    for _ in range(40):
+        op = rng.choice(["commit", "commit", "again", "view", "release"])
+        wls = [WLS[rng.choice("ABCD")] for _ in range(3 if op == "view"
+                                                        else 1)]
+        if op == "release":      # room the normal cycle can place into
+            uid = rng.choice(sorted(u for u, i in sched.cluster.instances
+                                    .items() if i.preemptible))
+            sched.cluster.evict(uid)
+            ref.cluster.evict(uid)
+            continue
+        if op == "view":
+            view, rview = sched.cluster.view(), ref.cluster.view()
+            txns = [plan(wl, view)[0] for wl in wls]
+            keys += [commit(t) for t in txns]
+            rtxns = [ref.plan(wl, view=rview) for wl in wls]
+            want += [commit(t) for t in rtxns]
+            continue
+        if op == "again":        # unchanged state: nothing crosses
+            plan(wls[0])
+            ref.plan(wls[0])
+            assert plan(wls[0])[1] == 0
+        keys.append(commit(plan(wls[0])[0]))
+        want.append(commit(ref.plan(wls[0])))
+    assert keys == want
+    kinds = {k[0] for k in keys}
+    assert {"placed", "preempted"} <= kinds
+    assert side["flush"] and set(side["flush"]) == {1}
+    assert max(uploads) == 1
+    assert dcs.fallbacks["shortlist_full_sweep"] == 0
+    if num_nodes > 128:
+        assert dcs.counts["shortlist_plans"] > 0 and max(forced) > 0

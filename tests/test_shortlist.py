@@ -16,7 +16,8 @@ from repro import obs
 from repro.core import (Cluster, ColocationConfig, ShortlistConfig, SPECS,
                         TopoScheduler, run_day_cycle, table3_workloads)
 from repro.core import preemption_jax as pj
-from repro.core.cluster import SourcingContext, ViewDelta, encode_row
+from repro.core.cluster import (SourcingContext, ViewDelta, encode_row,
+                                row_width)
 from repro.core.cluster_parallel import ShardedDeviceClusterState
 from repro.core.placement import Placement
 from repro.core.preemption_jax import NARROW_M
@@ -237,10 +238,20 @@ def test_fingerprint_identical_rows_collide_only_when_identical():
     assert fps.count(fps[3]) == 1
 
 
+def _uploaded_rep(dcs) -> np.ndarray:
+    """The rep mask a delta-free shortlist plan uploads, sliced from its
+    packed argument buffer on the device and unpacked as the program
+    unpacks it."""
+    pa = pj._patch_args(dcs, {})
+    _, f, aux = pj._shortlist_plan_args(dcs, {}, WL3["A"].priority, pa)
+    words = aux[pa.p * (1 + row_width(dcs.cap)) + f:]
+    return np.asarray(pj._rep_mask(words, dcs.n_rows))
+
+
 def test_rep_classes_one_lowest_index_rep_per_class():
     cluster = _random_cluster(1, SPECS["rtx4090"], nodes=10)
     dcs = cluster.device_state().sync()
-    rep, rep_dev = dcs.rep_classes()
+    rep = dcs.rep_classes()
     n = cluster.num_nodes
     fp = dcs.mirror.fp[:n]
     # exactly one rep per distinct fingerprint, and it's the lowest index
@@ -248,16 +259,19 @@ def test_rep_classes_one_lowest_index_rep_per_class():
     for v in set(fp.tolist()):
         members = np.nonzero(fp == v)[0]
         assert rep[members[0]] and not rep[members[1:]].any()
+    # the plan's upload carries the same bits
+    assert np.array_equal(_uploaded_rep(dcs), rep)
     # cache: same version -> same arrays; new version -> recomputed
-    rep2, _ = dcs.rep_classes()
+    rep2 = dcs.rep_classes()
     assert rep2 is rep
     free = [(nd, cluster.free_masks(nd)) for nd in range(n)]
     node, (fg, fc) = next((nd, m) for nd, m in free if m[0] & m[1])
     bit = (fg & fc) & -(fg & fc)     # lowest jointly-free GPU/CG pair
     cluster.bind(WL3["D"], node, Placement(bit, bit, 0))
     dcs.sync()
-    rep3, _ = dcs.rep_classes()
+    rep3 = dcs.rep_classes()
     assert rep3 is not rep
+    assert np.array_equal(_uploaded_rep(dcs), rep3)
 
 
 # ---------------------------------------------------------------------------------
@@ -381,11 +395,11 @@ def _fits(cluster, inst) -> bool:
 def test_class_index_matches_from_scratch(engine, seed, monkeypatch):
     """Seeded binds, evicts, restores, commits, rollbacks, plan_batch
     sessions and plans on a shared view (view-delta patch rows): after
-    every sync the incrementally
-    kept rep mask, host and device, equals the from-scratch mask bit for
-    bit (23 nodes: the sharded axis pads to 24, and the pad row stays
-    False), and `_forced_rows` equals the scan, in every plan's own call
-    too.  A step that changes most rows rebuilds the index."""
+    every sync the incrementally kept rep mask, on the host and unpacked
+    from a plan's upload, equals the from-scratch mask bit for bit (23
+    nodes: the sharded axis pads to 24, and the pad row stays False), and
+    `_forced_rows` equals the scan, in every plan's own call too.  A step
+    that changes most rows rebuilds the index."""
     sharded = engine == "imp_sharded"
     cluster = _sat(num_nodes=23, seed=seed)
     n = cluster.num_nodes
@@ -408,10 +422,9 @@ def test_class_index_matches_from_scratch(engine, seed, monkeypatch):
 
     def check():
         dcs.sync()
-        rep, rep_dev = dcs.rep_classes()
         want = _rep_from_scratch(dcs)
-        assert np.array_equal(rep, want)
-        assert np.array_equal(np.asarray(rep_dev), want)
+        assert np.array_equal(dcs.rep_classes(), want)
+        assert np.array_equal(_uploaded_rep(dcs), want)
         patched = dict.fromkeys(rng.sample(range(n), 3))
         assert real_forced(dcs, patched) == _forced_by_scan(dcs, patched)
 

@@ -75,10 +75,10 @@ def test_topo_score_kernels_compile_for_v5e(one_chip, kernel):
 
 
 def _resident_shapes(n_rows: int, node_sh, victim_sh, repl):
-    """`DeviceClusterState` tensors plus the delta-free patch/aux arrays and
+    """`DeviceClusterState` tensors plus a delta-free plan's packed
+    argument buffer (empty; the shortlist's holds the rep-mask words) and
     the request vector, as shapes."""
     cap = MAX_DENSE_VICTIMS
-    width = NODE_FIELDS + VICTIM_FIELDS * cap + DRAIN_FIELDS
     i32 = jnp.int32
     return {
         "nodestate": jax.ShapeDtypeStruct((NODE_FIELDS, n_rows), i32,
@@ -88,7 +88,8 @@ def _resident_shapes(n_rows: int, node_sh, victim_sh, repl):
         "drain": jax.ShapeDtypeStruct((DRAIN_FIELDS, n_rows), i32,
                                       sharding=node_sh),
         "aux": jax.ShapeDtypeStruct((0,), i32, sharding=repl),
-        "pbuf": jax.ShapeDtypeStruct((0, width), i32, sharding=repl),
+        "rep_words": jax.ShapeDtypeStruct((-(-n_rows // 32),), i32,
+                                          sharding=repl),
         "req": jax.ShapeDtypeStruct((4,), i32, sharding=repl),
     }
 
@@ -102,16 +103,15 @@ def test_fused_evaluators_compile_for_v5e(one_chip, evaluator):
     s = _resident_shapes(NODES, one_chip, one_chip, one_chip)
     if evaluator == "normal":
         fn = pj.normal_evaluator(spec, 0)
-        args = (s["nodestate"], s["aux"], s["pbuf"], s["req"])
+        args = (s["nodestate"], s["aux"], s["req"])
     elif evaluator == "plan":
         fn = pj.plan_evaluator(spec, pj.MIN_M, 0, 0, ALPHA)
         args = (s["nodestate"], s["victims"], s["drain"], s["aux"],
-                s["pbuf"], s["req"])
+                s["req"])
     else:
         fn = pj.shortlist_plan_evaluator(spec, 128, 0, 0, ALPHA)
-        rep = jax.ShapeDtypeStruct((NODES,), jnp.bool_, sharding=one_chip)
-        args = (s["nodestate"], s["victims"], s["drain"], rep, s["aux"],
-                s["pbuf"], s["req"])
+        args = (s["nodestate"], s["victims"], s["drain"], s["rep_words"],
+                s["req"])
     compiled = fn.lower(*args).compile()
     assert compiled.memory_analysis() is not None
 
@@ -126,5 +126,5 @@ def test_sharded_plan_evaluator_compiles_for_four_chips(topo):
     s = _resident_shapes(NODES, ev.node_sh, ev.victim_sh, ev.repl)
     fn = ev.plan_evaluator(RTX4090_SERVER, pj.MIN_M, 0, 0, ALPHA)
     text = fn.lower(s["nodestate"], s["victims"], s["drain"], s["aux"],
-                    s["pbuf"], s["req"]).compile().as_text()
+                    s["req"]).compile().as_text()
     assert "all-reduce" in text or "all-gather" in text
