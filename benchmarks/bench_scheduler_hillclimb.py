@@ -6,10 +6,6 @@ independent preemptions.  Iterations:
 
   it0  paper-faithful python IMP, naive O(instances) cluster scans
   it1  + per-node instance index & free-mask cache (host-side data structure)
-  it2  per-node vectorized subset evaluation (imp_jax)  [hypothesis: slower —
-       per-node dispatch overhead dominates at m<=8]
-  it3  cluster-batched sweep: ONE vmapped evaluation per subset size over all
-       candidate nodes (imp_batched_legacy)
   it4  plan_batch: 8 pending preemptors planned against one snapshot through
        the batched engine (per-request amortized latency)
   it5  fused single dispatch: all subset sizes + on-device Eq. 2 argmax in
@@ -81,7 +77,7 @@ def _measure_plan_batch(engine: str, nodes: int = 100, batch: int = 8,
     Reports END-TO-END plan time per request (total_*); the sourcing_*
     fields stay zero because a batched plan interleaves filtering,
     sourcing, and selection per request — a per-phase split would not be
-    comparable with it0-it3's sourcing numbers.
+    comparable with the single-plan iterations' sourcing numbers.
     """
     wls = {w.name: w for w in table3_workloads()}
     cluster = _saturated(nodes)
@@ -106,8 +102,6 @@ def _measure_plan_batch(engine: str, nodes: int = 100, batch: int = 8,
 ITERATIONS = [
     ("it0_python_imp_naive", "imp", False),
     ("it1_python_imp_indexed", "imp", True),
-    ("it2_pernode_vectorized", "imp_jax", True),
-    ("it3_cluster_batched", "imp_batched_legacy", True),
     ("it5_fused_single_dispatch", "imp_batched", True),
 ]
 

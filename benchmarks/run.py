@@ -4,10 +4,6 @@ Prints ``name,us_per_call,derived`` CSV lines.  Set BENCH_FULL=1 for the
 paper-scale protocol (100 nodes, 100x50 preemptions).
 
   table4_*  — hit rate (paper Table 4)
-  table5_*  — candidate-sourcing latency (paper Table 5 / Fig 11)
-  scale_*   — plan-latency scale sweep 24..10k nodes, sharded vs fused
-              (in-process on a TPU, an 8-device CPU subprocess otherwise;
-              merges the BENCH_sourcing.json scale block)
   fig10_*   — per-workload sourcing overhead (paper Fig 10)
   fig9_*    — preemption timeline (paper Fig 9)
   fig8_*    — allocation snapshots (paper Fig 8)
@@ -45,15 +41,12 @@ def main(argv: list[str] | None = None) -> None:
     enable_compile_cache()
     from . import (bench_allocation_snapshot, bench_colocation,
                    bench_elastic, bench_hit_rate, bench_instance_timeline,
-                   bench_roofline, bench_scale_sourcing,
-                   bench_scheduler_hillclimb, bench_sourcing_latency,
+                   bench_roofline, bench_scheduler_hillclimb,
                    bench_workload_overhead)
 
     print("name,us_per_call,derived")
-    # bench_scale_sourcing must follow bench_sourcing_latency: the latter
-    # rewrites BENCH_sourcing.json and the former merges its scale block in
-    for mod in (bench_hit_rate, bench_sourcing_latency, bench_scale_sourcing,
-                bench_workload_overhead, bench_instance_timeline,
+    for mod in (bench_hit_rate, bench_workload_overhead,
+                bench_instance_timeline,
                 bench_allocation_snapshot, bench_colocation, bench_elastic,
                 bench_scheduler_hillclimb, bench_roofline):
         t0 = time.time()

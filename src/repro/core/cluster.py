@@ -18,9 +18,12 @@ import dataclasses
 import hashlib
 import itertools
 from collections import Counter
+from functools import lru_cache, partial
 from typing import Callable
 
+import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import obs
 from .flextopo import FlexTopo
@@ -167,18 +170,17 @@ class Cluster:
     def device_state(self, sharded: bool = False) -> "DeviceClusterState":
         """The lazily-created device-resident struct-of-arrays state.
 
-        ``sharded=True`` returns (creating or replacing as needed) a
-        `repro.core.cluster_parallel.ShardedDeviceClusterState`: the same
-        three stacked tensors, node axis padded to a multiple of the device
-        count and laid out with a `NamedSharding` over a 1-D mesh of every
-        local device.  The fused evaluators then compile to SPMD programs
-        where the per-node class math runs shard-local and only the final
-        argmax chain crosses shards (the `imp_sharded` engine)."""
+        ``sharded=True`` returns (creating or replacing as needed) a state
+        over `cluster_mesh()`: the same three stacked tensors, node axis
+        padded to a multiple of the device count and laid out with a
+        `NamedSharding` over a 1-D mesh of every local device.  The fused
+        evaluators then compile to SPMD programs where the per-node class
+        math runs shard-local and only the final argmax chain crosses
+        shards (the `imp_sharded` engine)."""
         if sharded:
-            from .cluster_parallel import ShardedDeviceClusterState
-
-            if not isinstance(self._device_state, ShardedDeviceClusterState):
-                self._device_state = ShardedDeviceClusterState(self)
+            if self._device_state is None or self._device_state.mesh is None:
+                self._device_state = DeviceClusterState(self,
+                                                        mesh=cluster_mesh())
             return self._device_state
         if self._device_state is None:
             self._device_state = DeviceClusterState(self)
@@ -886,16 +888,52 @@ def apply_packed_rows(ns, v, dr, packed):
     return apply_rows(ns, v, dr, packed[:q], packed[q:].reshape(q, -1))
 
 
-_SCATTER_JIT = None
+@lru_cache(maxsize=None)
+def _cluster_mesh(devices) -> jax.sharding.Mesh:
+    return jax.sharding.Mesh(np.asarray(devices), ("nodes",))
 
 
-def _scatter_rows(ns, v, dr, packed):
-    """One jitted scatter updating every dirty row of all three tensors
-    from a single `pack_scatter` upload."""
-    global _SCATTER_JIT
-    if _SCATTER_JIT is None:
-        _SCATTER_JIT = obs.program("apply_rows", apply_packed_rows)
-    return _SCATTER_JIT(ns, v, dr, packed)
+def cluster_mesh(devices=None) -> jax.sharding.Mesh:
+    """1-D mesh over every local device (axis ``nodes``) — the layout of
+    ``Cluster.device_state(sharded=True)``.  Degrades to a one-device mesh
+    when only one device exists, so the sharded paths stay parity-testable
+    anywhere."""
+    return _cluster_mesh(tuple(jax.devices()) if devices is None
+                         else tuple(devices))
+
+
+@lru_cache(maxsize=None)
+def _mesh_shardings(mesh):
+    """(node, victim, replicated) `NamedSharding`s of a mesh: node-axis
+    tensors split their axis 1 over EVERY mesh axis."""
+    axes = tuple(mesh.axis_names)
+    return (NamedSharding(mesh, P(None, axes)),
+            NamedSharding(mesh, P(None, axes, None)),
+            NamedSharding(mesh, P()))
+
+
+def mesh_program(name: str, fn, mesh, ins: str, out="r"):
+    """`obs.program` of ``fn`` for a device layout: ``jit_<name>`` on one
+    device (``mesh`` None), or ``jit_<name>_sharded`` over ``mesh`` with
+    explicit in/out shardings, one letter per argument in ``ins`` and a
+    letter (or a pytree of letters) for the result: ``n`` node-axis
+    tensors, ``v`` victim tensors, ``r`` replicated (`_mesh_shardings`)."""
+    if mesh is None:
+        return obs.program(name, fn)
+    sh = dict(zip("nvr", _mesh_shardings(mesh)))
+    return obs.program(f"{name}_sharded", fn,
+                       in_shardings=tuple(sh[c] for c in ins),
+                       out_shardings=jax.tree.map(sh.__getitem__, out))
+
+
+@lru_cache(maxsize=None)
+def _scatter_rows(mesh):
+    """The jitted scatter updating every dirty row of all three tensors
+    from a single `pack_scatter` upload.  Over a mesh the resident tensors
+    stay sharded on both sides; the packed rows replicate (O(dirty),
+    tiny)."""
+    return mesh_program("apply_rows", apply_packed_rows, mesh, "nvnr",
+                        ("n", "v", "n"))
 
 
 def _pad_pow2(n: int) -> int:
@@ -1010,22 +1048,17 @@ def encode_delta_core(nodestate, victims, didx, rem, fog, foc, og, oc,
         [new_ns.T, new_v.transpose(1, 0, 2).reshape(d, -1), new_dr.T], axis=1)
 
 
-_DELTA_ENCODERS: dict = {}
-
-
-def delta_encoder(cap: int, a: int):
-    """Jitted `encode_delta_core` keyed by (victim cap, add bucket); the
-    descriptor length ``D`` stays dynamic (pow2-padded by the caller), so
-    variants are bounded by the few (cap, a) combinations in play."""
-    key = (cap, a)
-    fn = _DELTA_ENCODERS.get(key)
-    if fn is None:
-        import functools
-
-        fn = obs.program("delta_encode",
-                         functools.partial(encode_delta_core, cap=cap, a=a))
-        _DELTA_ENCODERS[key] = fn
-    return fn
+@lru_cache(maxsize=None)
+def delta_encoder(mesh, cap: int, a: int):
+    """Jitted `encode_delta_core` keyed by (mesh, victim cap, add bucket);
+    the descriptor length ``D`` stays dynamic (pow2-padded by the caller),
+    so variants are bounded by the few (cap, a) combinations in play.
+    Over a mesh the descriptor columns arrive replicated and the rebuilt
+    patch rows come back replicated (O(delta), tiny), so the base-row
+    gather is the only cross-shard traffic."""
+    return mesh_program("delta_encode",
+                        partial(encode_delta_core, cap=cap, a=a), mesh,
+                        "nv" + "r" * 10)
 
 
 class ViewDelta:
@@ -1214,11 +1247,16 @@ class DeviceClusterState:
     evaluators overlay patch rows inside the dispatch (``pack_rows``).
     """
 
-    #: device mesh the stacked tensors are sharded over (None = single
-    #: device; `ShardedDeviceClusterState` overrides)
-    mesh = None
-
-    def __init__(self, cluster: Cluster, cap: int | None = None) -> None:
+    def __init__(self, cluster: Cluster, cap: int | None = None,
+                 mesh=None) -> None:
+        #: device mesh the stacked tensors are sharded over (None: one
+        #: device).  Over a mesh the node axis is padded UP to a multiple
+        #: of the device count; pad rows carry `IDX_SENTINEL` node ids and
+        #: zero masks, which every fused core already excludes, so results
+        #: are bit-identical to the unsharded layout.  The full upload, the
+        #: dirty-row scatter and the view-delta encoder pin their outputs
+        #: sharded or replicated, so the tensors never silently migrate.
+        self.mesh = mesh
         self.cluster = cluster
         self.mirror = cluster.sourcing_context()
         if cap is not None and cap != self.mirror.cap:
@@ -1294,9 +1332,13 @@ class DeviceClusterState:
 
     @property
     def n_rows(self) -> int:
-        """Length of the device node axis (== ``num_nodes`` here; the
-        sharded subclass pads to a multiple of the device count)."""
-        return self.cluster.num_nodes
+        """Length of the device node axis: ``num_nodes``, padded to a
+        multiple of the device count over a mesh (the fused paths use it as
+        the row base of their gathered sections)."""
+        if self.mesh is None:
+            return self.cluster.num_nodes
+        d = int(self.mesh.size)
+        return -(-max(self.cluster.num_nodes, 1) // d) * d
 
     def rep_classes(self) -> np.ndarray:
         """Equivalence-class representative mask over the node axis.
@@ -1324,21 +1366,35 @@ class DeviceClusterState:
         return rep
 
     def _upload_full(self, ns, v, dr):
-        """Full-rebuild upload hook (subclasses re-layout/shard here)."""
-        return obs.upload(ns), obs.upload(v), obs.upload(dr)
+        """Full-rebuild upload; over a mesh, sentinel pad rows first."""
+        if self.mesh is None:
+            return obs.upload(ns), obs.upload(v), obs.upload(dr)
+        pad = self.n_rows - ns.shape[1]
+        if pad:
+            pns = np.zeros((NODE_FIELDS, pad), np.int32)
+            pns[NS_NODE_ID] = IDX_SENTINEL
+            ns = np.concatenate([ns, pns], axis=1)
+            v = np.concatenate(
+                [v, np.zeros((VICTIM_FIELDS, pad, v.shape[2]), np.int32)],
+                axis=1)
+            dr = np.concatenate(
+                [dr, np.zeros((DRAIN_FIELDS, pad), np.int32)], axis=1)
+        node_sh, victim_sh, _ = _mesh_shardings(self.mesh)
+        return (obs.upload(np.ascontiguousarray(ns), node_sh),
+                obs.upload(np.ascontiguousarray(v), victim_sh),
+                obs.upload(np.ascontiguousarray(dr), node_sh))
 
     def _scatter(self, idx, buf):
-        """Dirty-row scatter hook (subclasses keep the output sharded)."""
-        return _scatter_rows(self.nodestate, self.victims, self.drain,
-                             obs.upload(pack_scatter(idx, buf)))
+        """The dirty-row scatter, from one packed upload."""
+        return _scatter_rows(self.mesh)(self.nodestate, self.victims,
+                                        self.drain,
+                                        obs.upload(pack_scatter(idx, buf)))
 
     def delta_encode(self, a: int, didx, *descs):
         """Run the device-side view-delta encoder against the resident
-        base tensors (`ViewDelta.device_rows` feeds the descriptors).  The
-        sharded subclass overrides to pin the descriptor inputs and the
-        tiny patch-row output replicated across the mesh."""
-        return delta_encoder(self.cap, a)(self.nodestate, self.victims,
-                                          didx, *descs)
+        base tensors (`ViewDelta.device_rows` feeds the descriptors)."""
+        return delta_encoder(self.mesh, self.cap, a)(
+            self.nodestate, self.victims, didx, *descs)
 
     @property
     def pending(self) -> set[int]:

@@ -1,14 +1,12 @@
 """Vectorized victim-subset evaluation (TPU adaptation of the paper's hot loop).
 
 The paper's candidate sourcing is a branchy per-subset CPU loop (Table 5: up
-to 417 ms P90).  Here every subset of one size is evaluated in a single dense
+to 417 ms P90).  Here every victim subset is evaluated in a single dense
 sweep: victim resources are int32 bitmasks, feasibility is
 ``popcount(freed & numa_mask)`` lane math, and the subset axis is a vector
-axis.  The same math is retiled as a Pallas TPU kernel in
-``repro.kernels.topo_score`` — this module is its jit'd reference engine and
-is also what ``cluster_parallel`` shard_maps across the device mesh.
-
-Two cluster-wide engines share the math:
+axis.  Every evaluator factory takes the device mesh of the resident state
+first (``None``: one device); over a mesh it builds the same program with
+explicit shardings (the ``imp_sharded`` engine).
 
 * ``imp_batched`` (default, *fused*): ONE jit dispatch over the cluster's
   DEVICE-RESIDENT state (`DeviceClusterState`) runs Guaranteed Filtering
@@ -31,10 +29,6 @@ masks) in front of the preemptive chain under ``lax.cond``, so the whole of
 Algorithm 1 — both cycles, Filtering, Sorting, Eq. 2 AND placement — is one
 device program and one small readback (`plan_evaluator`), with the subset
 sweep never executed when the normal cycle succeeds.
-* ``imp_batched_legacy``: the original multi-dispatch sweep (one jit call
-  per subset size, full ``[N, n_comb]`` tier/priority transfers, python
-  Candidate construction).  Kept for parity testing and as the reference
-  for the fused path's semantics.
 
 ``plan_batch`` requests ride a `BatchSourcingSession`: one *vmapped*
 dispatch evaluates ALL requests' per-node class winners against one
@@ -48,22 +42,22 @@ Tier convention matches ``placement.best_tier``:
 from __future__ import annotations
 
 import dataclasses
-import sys
 from collections import Counter
-from functools import lru_cache, partial
+from functools import lru_cache
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import obs
-from .cluster import (DRAIN_FIELDS, IDX_SENTINEL, MAX_DENSE_VICTIMS,
-                      NODE_FIELDS, NS_FREE_CG, NS_FREE_GPU, NS_NEXT_PRIO,
-                      NS_NODE_ID, NS_OVERFLOW, VF_CG, VF_GPU, VF_PRIO,
-                      VF_RANK, VF_STORED, VICTIM_FIELDS, Cluster,
-                      DeviceClusterState, VictimRow, ViewDelta, _pad_pow2,
-                      apply_rows, encode_row, flatten_rows, pack_bits,
+from .cluster import (DRAIN_FIELDS, MAX_DENSE_VICTIMS, NODE_FIELDS,
+                      NS_FREE_CG, NS_FREE_GPU, NS_NEXT_PRIO, NS_NODE_ID,
+                      NS_OVERFLOW, VF_CG, VF_GPU, VF_PRIO, VF_RANK,
+                      VF_STORED, VICTIM_FIELDS, Cluster, DeviceClusterState,
+                      VictimRow, ViewDelta, _pad_pow2, apply_rows,
+                      encode_row, flatten_rows, mesh_program, pack_bits,
                       pack_context_rows, pack_rows, pad_idx, row_width,
                       unflatten_rows, unpack_bits)
 from .engines import register_engine
@@ -73,155 +67,6 @@ from .placement_jax import (normal_cycle_core, spec_constants,
 from .scoring import DEFAULT_ALPHA, TIER_SCORES, Candidate, select_best
 from .topology import ServerSpec
 from .workload import TopoPolicy, WorkloadSpec
-
-#: compat alias — the dynamic-request tier math now lives in
-#: `placement_jax` (shared with the placement scorer); sharded and test
-#: callers keep importing it from here
-_tier_from_counts_dyn = tier_from_counts_dyn
-
-
-@lru_cache(maxsize=None)
-def combo_table(m: int, k: int) -> np.ndarray:
-    """int32[C(m,k), k] — all size-k index combinations of range(m)."""
-    import itertools
-
-    if k == 0:
-        return np.zeros((1, 0), dtype=np.int32)
-    combos = list(itertools.combinations(range(m), k))
-    return np.asarray(combos, dtype=np.int32)
-
-
-@dataclasses.dataclass(frozen=True)
-class Request:
-    """Static (trace-time) description of the preemptor's resource ask."""
-
-    need_gpus: int
-    need_cgs: int
-    bundle_locality: bool
-
-    @property
-    def cgs_per_bundle(self) -> int:
-        if not self.need_gpus:
-            return 0
-        return self.need_cgs // self.need_gpus if self.bundle_locality else 0
-
-
-def _evaluate_subsets_core(
-    free_gpu: jnp.ndarray,        # int32[] or int32[N]
-    free_cg: jnp.ndarray,
-    victim_gpu: jnp.ndarray,      # int32[M] (or [N, M])
-    victim_cg: jnp.ndarray,
-    victim_prio: jnp.ndarray,     # int32[M]
-    victim_valid: jnp.ndarray,    # bool[M]  (padding rows -> False)
-    table: jnp.ndarray,           # int32[n_comb, k]
-    numa_gpu_masks: jnp.ndarray,  # int32[U]
-    numa_cg_masks: jnp.ndarray,   # int32[U]
-    sock_onehot: jnp.ndarray,     # int32[U, S]
-    request: Request,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Evaluate every subset in `table` at once.
-
-    Returns (tier int32[n_comb], prio_sum int32[n_comb], valid bool[n_comb]).
-    Supports one leading batch axis on the dynamic state via vmap from callers.
-    """
-    k = table.shape[1]
-    combo_gpu = jnp.zeros(table.shape[0], jnp.int32)
-    combo_cg = jnp.zeros(table.shape[0], jnp.int32)
-    prio_sum = jnp.zeros(table.shape[0], jnp.int32)
-    valid = jnp.ones(table.shape[0], bool)
-    for j in range(k):  # k is small and static: unrolled fold
-        idx = table[:, j]
-        combo_gpu |= victim_gpu[idx]
-        combo_cg |= victim_cg[idx]
-        prio_sum += victim_prio[idx]
-        valid &= victim_valid[idx]
-
-    freed_gpu = free_gpu | combo_gpu        # [n_comb]
-    freed_cg = free_cg | combo_cg
-
-    # per-NUMA availability: popcount(freed & numa_mask)   -> [n_comb, U]
-    cnt_gpu = jax.lax.population_count(freed_gpu[:, None] & numa_gpu_masks[None, :])
-    cnt_cg = jax.lax.population_count(freed_cg[:, None] & numa_cg_masks[None, :])
-    tier = _tier_from_counts(cnt_gpu, cnt_cg, sock_onehot, request)
-    tier = jnp.where(valid, tier, 3).astype(jnp.int32)
-    return tier, prio_sum, valid
-
-
-def _tier_from_counts(cnt_gpu, cnt_cg, sock_onehot, request: Request):
-    """Tier of each subset from its per-NUMA availability counts.
-
-    ``cnt_gpu``/``cnt_cg`` are ``[..., U]`` (any leading batch shape; the
-    NUMA axis is last) — the single tier-semantics implementation shared by
-    the per-size evaluator and the fused single-dispatch evaluator.
-    """
-    if request.need_gpus == 0:
-        numa_ok = jnp.any(cnt_cg >= request.need_cgs, axis=-1)
-        sock_cg = cnt_cg @ sock_onehot
-        sock_ok = jnp.any(sock_cg >= request.need_cgs, axis=-1)
-        glob_ok = jnp.sum(cnt_cg, axis=-1) >= request.need_cgs
-    else:
-        if request.bundle_locality and request.cgs_per_bundle > 0:
-            units = jnp.minimum(cnt_gpu, cnt_cg // request.cgs_per_bundle)
-        else:
-            units = cnt_gpu
-        numa_ok = jnp.any(
-            (units >= request.need_gpus) & (cnt_cg >= request.need_cgs),
-            axis=-1)
-        sock_units = units @ sock_onehot    # [..., S]
-        sock_cg = cnt_cg @ sock_onehot
-        sock_ok = jnp.any(
-            (sock_units >= request.need_gpus) & (sock_cg >= request.need_cgs),
-            axis=-1)
-        glob_ok = (jnp.sum(units, axis=-1) >= request.need_gpus) & (
-            jnp.sum(cnt_cg, axis=-1) >= request.need_cgs)
-    return jnp.where(numa_ok, 0, jnp.where(sock_ok, 1, jnp.where(glob_ok, 2, 3)))
-
-
-evaluate_subsets = partial(jax.jit, static_argnames=("request",))(
-    _evaluate_subsets_core
-)
-
-
-@lru_cache(maxsize=None)
-def evaluate_subsets_batched(request: Request):
-    """jit(vmap) of the core evaluator over a leading node axis.
-
-    Dynamic state (free masks, victim arrays) is batched [N, ...]; the combo
-    table and SKU constants are shared.  Returns (tier[N, n_comb],
-    prio_sum[N, n_comb], valid[N, n_comb]).
-    """
-    fn = partial(_evaluate_subsets_core, request=request)
-    return jax.jit(
-        jax.vmap(fn, in_axes=(0, 0, 0, 0, 0, 0, None, None, None, None))
-    )
-
-
-def _bucket(m: int) -> int:
-    """Pad victim count to a small set of buckets to bound jit recompiles.
-
-    Callers must partition away nodes holding more than `MAX_DENSE_VICTIMS`
-    victims first (``split_dense_nodes``): those fall back to the per-node
-    python engine instead of tripping this guard.
-    """
-    for b in (4, 8, 16):
-        if m <= b:
-            return b
-    raise ValueError(f"too many victims on one node: {m}")
-
-
-def split_dense_nodes(
-    cluster, workload: WorkloadSpec, nodes: list[int],
-) -> tuple[list[int], list[int], dict[int, list]]:
-    """Partition nodes into (dense, overflow) by victim-row capacity.
-
-    Overflow nodes (> `MAX_DENSE_VICTIMS` potential victims) cannot be
-    encoded in the padded arrays; the batched engines source them through
-    the per-node python IMP instead of raising (old ``_bucket`` crash).
-    """
-    per_node = {n: cluster.victims_on(n, workload.priority) for n in nodes}
-    dense = [n for n in nodes if len(per_node[n]) <= MAX_DENSE_VICTIMS]
-    overflow = [n for n in nodes if len(per_node[n]) > MAX_DENSE_VICTIMS]
-    return dense, overflow, per_node
 
 
 def _overflow_candidates(cluster, workload: WorkloadSpec, nodes: list[int],
@@ -236,161 +81,6 @@ def _overflow_candidates(cluster, workload: WorkloadSpec, nodes: list[int],
     for node in nodes:
         out.extend(flextopo_imp(cluster, workload, node))
     return out
-
-
-def cluster_victim_arrays(
-    cluster: Cluster, workload: WorkloadSpec, nodes: list[int],
-    per_node: dict[int, list] | None = None,
-):
-    """Padded per-node victim arrays for the batched/sharded engines.
-
-    Returns (free_gpu[N], free_cg[N], vg[N,M], vc[N,M], vp[N,M], valid[N,M],
-    victims_per_node list-of-lists).  ``per_node`` lets callers reuse the
-    victim scan from ``split_dense_nodes``.
-    """
-    if per_node is not None:
-        per_node = [per_node[n] for n in nodes]
-    else:
-        per_node = [cluster.victims_on(n, workload.priority) for n in nodes]
-    m = _bucket(max((len(v) for v in per_node), default=1) or 1)
-    n = len(nodes)
-    free_gpu = np.zeros(n, np.int32)
-    free_cg = np.zeros(n, np.int32)
-    vg = np.zeros((n, m), np.int32)
-    vc = np.zeros((n, m), np.int32)
-    vp = np.zeros((n, m), np.int32)
-    valid = np.zeros((n, m), bool)
-    for i, node in enumerate(nodes):
-        fg, fc = cluster.free_masks(node)
-        free_gpu[i], free_cg[i] = fg, fc
-        for j, v in enumerate(per_node[i]):
-            vg[i, j] = v.gpu_mask
-            vc[i, j] = v.cg_mask
-            vp[i, j] = v.priority
-            valid[i, j] = True
-    return free_gpu, free_cg, vg, vc, vp, valid, per_node
-
-
-@register_engine("imp_batched_legacy", batched=True)
-def source_candidates_batched(
-    cluster: Cluster, workload: WorkloadSpec, nodes: list[int],
-) -> list[Candidate]:
-    """Cluster-wide IMP: one vmapped sweep per subset size k over ALL nodes.
-
-    Per-node IMP semantics are preserved: a node contributes candidates only
-    at ITS smallest feasible k (tracked with done flags); the sweep continues
-    until every node is done or k exceeds the largest victim count.
-
-    This is the legacy multi-dispatch path (one jit call + device→host
-    transfer per subset size); the fused single-dispatch rewrite is
-    registered as ``imp_batched``.
-    """
-    spec = cluster.spec
-    consts = spec_constants(spec)
-    request = Request(
-        need_gpus=workload.gpus_per_instance,
-        need_cgs=workload.coregroups_per_instance(spec.coregroup_size),
-        bundle_locality=workload.numa_policy == TopoPolicy.GUARANTEED,
-    )
-    nodes, overflow, victims_by_node = split_dense_nodes(
-        cluster, workload, nodes)
-    extra = _overflow_candidates(cluster, workload, overflow)
-    if not nodes:
-        return extra
-    free_gpu, free_cg, vg, vc, vp, valid, per_node = cluster_victim_arrays(
-        cluster, workload, nodes, per_node=victims_by_node)
-    m = vg.shape[1]
-    fn = evaluate_subsets_batched(request)
-    done = np.zeros(len(nodes), bool)
-    out: list[Candidate] = []
-    # counting lower bound (paper Fig 10 'quick failures'): sizes below the
-    # cluster-wide minimum cannot be feasible anywhere
-    from .preemption import min_feasible_k
-
-    start_k = min((min_feasible_k(cluster, workload, n, per_node[i])
-                   for i, n in enumerate(nodes)), default=0)
-    for k in range(start_k, m + 1):
-        if done.all():
-            break
-        table = combo_table(m, k)
-        tier, prio, _ = fn(
-            jnp.asarray(free_gpu), jnp.asarray(free_cg), jnp.asarray(vg),
-            jnp.asarray(vc), jnp.asarray(vp), jnp.asarray(valid),
-            jnp.asarray(table), consts["numa_gpu_masks"],
-            consts["numa_cg_masks"], consts["sock_onehot"],
-        )
-        tier = np.asarray(tier)
-        prio = np.asarray(prio)
-        for i, node in enumerate(nodes):
-            if done[i] or k > len(per_node[i]):
-                done[i] = done[i] or k > len(per_node[i])
-                continue
-            feasible = np.nonzero(tier[i] < 3)[0]
-            if feasible.size:
-                done[i] = True
-                for idx in feasible:
-                    out.append(Candidate(
-                        node=node,
-                        victims=tuple(sorted(
-                            per_node[i][j].uid for j in table[idx])),
-                        tier=int(tier[i, idx]),
-                        priority_sum=int(prio[i, idx]),
-                    ))
-    return out + extra
-
-
-def _victim_arrays(cluster: Cluster, workload: WorkloadSpec, node: int):
-    victims = cluster.victims_on(node, workload.priority)
-    m = len(victims)
-    vg = np.array([v.gpu_mask for v in victims], dtype=np.int32).reshape(m)
-    vc = np.array([v.cg_mask for v in victims], dtype=np.int32).reshape(m)
-    vp = np.array([v.priority for v in victims], dtype=np.int32).reshape(m)
-    return victims, vg, vc, vp
-
-
-@register_engine("imp_jax")
-def flextopo_imp_vectorized(cluster: Cluster, workload: WorkloadSpec, node: int
-                            ) -> list[Candidate]:
-    """IMP with the inner subset sweep vectorized (same results as python IMP)."""
-    spec = cluster.spec
-    consts = spec_constants(spec)
-    request = Request(
-        need_gpus=workload.gpus_per_instance,
-        need_cgs=workload.coregroups_per_instance(spec.coregroup_size),
-        bundle_locality=workload.numa_policy == TopoPolicy.GUARANTEED,
-    )
-    victims, vg, vc, vp = _victim_arrays(cluster, workload, node)
-    m = len(victims)
-    free_gpu, free_cg = cluster.free_masks(node)
-    valid = np.ones(max(m, 1), dtype=bool)
-    if m == 0:
-        vg = np.zeros(1, np.int32)
-        vc = np.zeros(1, np.int32)
-        vp = np.zeros(1, np.int32)
-        valid = np.zeros(1, dtype=bool)
-
-    for k in range(0, m + 1):
-        table = combo_table(max(m, 1), k)
-        tier, prio, _ = evaluate_subsets(
-            jnp.int32(free_gpu), jnp.int32(free_cg),
-            jnp.asarray(vg), jnp.asarray(vc), jnp.asarray(vp), jnp.asarray(valid),
-            jnp.asarray(table), consts["numa_gpu_masks"], consts["numa_cg_masks"],
-            consts["sock_onehot"], request,
-        )
-        tier = np.asarray(tier)
-        feasible = np.nonzero(tier < 3)[0]
-        if feasible.size:
-            prio = np.asarray(prio)
-            return [
-                Candidate(
-                    node=node,
-                    victims=tuple(sorted(victims[j].uid for j in table[i])),
-                    tier=int(tier[i]),
-                    priority_sum=int(prio[i]),
-                )
-                for i in feasible
-            ]
-    return []
 
 
 # ---------------------------------------------------------------------------------
@@ -418,8 +108,8 @@ def flextopo_imp_vectorized(cluster: Cluster, workload: WorkloadSpec, node: int
 # matches the host's f64 ordering whenever distinct candidate scores are at
 # least a few f32 ulps apart — true for realistic priority scales (the
 # per-class gap is alpha*|1/p1 - 1/p2| >= alpha/p^2 which stays above f32
-# resolution for priorities up to tens of thousands); `imp_batched_legacy`
-# keeps the exact host-side semantics for adversarial inputs.
+# resolution for priorities up to tens of thousands); the host engine
+# ``imp`` keeps the exact f64 semantics for adversarial inputs.
 #
 # The preemptor's resource ask is DYNAMIC (traced int32 scalars), so one
 # compiled program serves every workload class — jit variants are keyed only
@@ -438,8 +128,8 @@ class ClassWinners(NamedTuple):
     ``anyc[N, 3]`` marks classes holding at least one min-k feasible subset;
     ``cb``/``pp``/``um`` are the class winner's combo id, priority sum, and
     uid-rank mask; ``k_node[N]`` is each node's smallest feasible subset
-    size and ``cnt[N]`` its feasible min-k subset count (the legacy
-    engine's candidate count)."""
+    size and ``cnt[N]`` its feasible min-k subset count (the number of
+    candidates ``imp`` lists)."""
 
     anyc: jnp.ndarray
     cb: jnp.ndarray
@@ -499,8 +189,8 @@ def _fused_class_core(
     # ---- Guaranteed Filtering, fused: popcounts over the drain masks ------------
     dcnt_g = jax.lax.population_count(drain[0][:, None] & numa_g[None, :])
     dcnt_c = jax.lax.population_count(drain[1][:, None] & numa_c[None, :])
-    drain_tier = _tier_from_counts_dyn(dcnt_g, dcnt_c, sock_onehot,
-                                       need_gpus, need_cgs, cgs_per_bundle)
+    drain_tier = tier_from_counts_dyn(dcnt_g, dcnt_c, sock_onehot,
+                                      need_gpus, need_cgs, cgs_per_bundle)
     node_ok = (drain_tier < 3) & (node_ids < _INT32_MAX)
     if narrow_gate:
         elig_full = jnp.sum((stored_full & (vp_full < thresh))
@@ -540,8 +230,8 @@ def _fused_class_core(
         combo_gpu[:, :, None] & numa_g[None, None, :])
     cnt_cg = jax.lax.population_count(
         combo_cg[:, :, None] & numa_c[None, None, :])
-    tier = _tier_from_counts_dyn(cnt_gpu, cnt_cg, sock_onehot,
-                                 need_gpus, need_cgs, cgs_per_bundle)
+    tier = tier_from_counts_dyn(cnt_gpu, cnt_cg, sock_onehot,
+                                need_gpus, need_cgs, cgs_per_bundle)
     tier = jnp.where(combo_ok, tier, 3).astype(jnp.int32)
 
     # ---- per-node smallest feasible k (IMP early stop, on device) ---------------
@@ -593,8 +283,8 @@ def _fused_argmax_core(node_ids, cls: ClassWinners, alpha):
     # genuine Eq. 1 tie and skip the refinement so the victim-count break
     # applies first, as in `select_best`.  The one case left behind is a
     # cross-tier pair whose f64 scores differ by less than f32 resolution —
-    # that needs single-digit priority sums; `imp_batched_legacy` keeps
-    # exact host-side semantics for such adversarial inputs.
+    # that needs single-digit priority sums; the host engine ``imp`` keeps
+    # exact f64 semantics for such adversarial inputs.
     big_p = jnp.int32(_INT32_MAX)
     t3 = jnp.arange(3, dtype=jnp.int32)
     ppe = jnp.where(alpha > 0, pp, 0)
@@ -649,7 +339,7 @@ def _split_aux(aux, p: int, s: int, width: int):
 
 def _rep_mask(words, n_rows: int, rep_sh=None):
     """The rep mask unpacked from its `pack_bits` words, laid out by
-    ``rep_sh`` (the sharded twins' row sharding) when given."""
+    ``rep_sh`` (`_rep_sharding`) when given."""
     rep = unpack_bits(words, n_rows)
     if rep_sh is not None:
         rep = jax.lax.with_sharding_constraint(rep, rep_sh)
@@ -764,8 +454,17 @@ def request_vector(thresh: int, ng: int, nc: int, cpb: int) -> jnp.ndarray:
     return obs.upload(np.array([thresh, ng, nc, cpb], np.int32))
 
 
+#: in/out layouts (`cluster.mesh_program`) of the evaluators over the
+#: resident tensors: node-axis tensors sharded, the plan's packed argument
+#: buffer and the request vector replicated, the winner vector replicated
+_RESIDENT_IN = "nvnrr"
+#: the batch merge/plan evaluators: the six class tensors (the vmapped
+#: `batch_class_evaluator`'s result, request axis first), then as above
+_BATCH_IN = "vvvvnn" + "nvn" + "rrrr"
+
+
 @lru_cache(maxsize=None)
-def resident_evaluator(spec: ServerSpec, m: int, p: int, g: int,
+def resident_evaluator(mesh, spec: ServerSpec, m: int, p: int, g: int,
                        alpha: float):
     """jit of `_plan_pipeline` (the request is the traced ``req``
     argument, see `request_vector`; alpha is baked in).  The vmapped
@@ -776,11 +475,12 @@ def resident_evaluator(spec: ServerSpec, m: int, p: int, g: int,
                               *_req_args(req), alpha,
                               spec=spec, m=m, p=p, g=g)
 
-    return obs.program("resident_sweep", f)
+    return mesh_program("resident_sweep", f, mesh, _RESIDENT_IN)
 
 
 @lru_cache(maxsize=None)
-def plan_evaluator(spec: ServerSpec, m: int, p: int, g: int, alpha: float):
+def plan_evaluator(mesh, spec: ServerSpec, m: int, p: int, g: int,
+                   alpha: float):
     """jit of `_plan2_pipeline` (normal cycle chained into sourcing) — the
     whole ``schedule_or_preempt`` hot path is this one dispatch."""
 
@@ -789,7 +489,7 @@ def plan_evaluator(spec: ServerSpec, m: int, p: int, g: int, alpha: float):
                                *_req_args(req), alpha,
                                spec=spec, m=m, p=p, g=g)
 
-    return obs.program("plan2", f)
+    return mesh_program("plan2", f, mesh, _RESIDENT_IN)
 
 
 def _normal_pipeline(nodestate, aux, ng, nc, cpb, *, spec, p):
@@ -803,7 +503,7 @@ def _normal_pipeline(nodestate, aux, ng, nc, cpb, *, spec, p):
 
 
 @lru_cache(maxsize=None)
-def normal_evaluator(spec: ServerSpec, p: int):
+def normal_evaluator(mesh, spec: ServerSpec, p: int):
     """jit of `_normal_pipeline` (``req`` as in `resident_evaluator`; its
     priority field is unused).
 
@@ -814,7 +514,7 @@ def normal_evaluator(spec: ServerSpec, p: int):
         _, ng, nc, cpb = _req_args(req)
         return _normal_pipeline(nodestate, aux, ng, nc, cpb, spec=spec, p=p)
 
-    return obs.program("normal_cycle", f)
+    return mesh_program("normal_cycle", f, mesh, "nrr")
 
 
 def _gathered_pipeline(nodestate, victims, drain, aux,
@@ -845,7 +545,7 @@ def _gathered_pipeline(nodestate, victims, drain, aux,
 
 
 @lru_cache(maxsize=None)
-def gathered_evaluator(spec: ServerSpec, m: int, p: int, alpha: float):
+def gathered_evaluator(mesh, spec: ServerSpec, m: int, p: int, alpha: float):
     """jit of `_gathered_pipeline` (``req`` as in `resident_evaluator`)."""
 
     def f(nodestate, victims, drain, aux, req):
@@ -853,7 +553,7 @@ def gathered_evaluator(spec: ServerSpec, m: int, p: int, alpha: float):
                                   *_req_args(req), alpha,
                                   spec=spec, m=m, p=p)
 
-    return obs.program("gathered", f)
+    return mesh_program("gathered", f, mesh, _RESIDENT_IN)
 
 
 # ---------------------------------------------------------------------------------
@@ -931,10 +631,10 @@ def _prescreen_core(nodestate, victims, drain, rep,
     ec = free_cg + jnp.sum(jnp.where(elig, vc, 0), axis=1)
     cnt_g = jax.lax.population_count(eg[:, None] & numa_g[None, :])
     cnt_c = jax.lax.population_count(ec[:, None] & numa_c[None, :])
-    et = _tier_from_counts_dyn(cnt_g, cnt_c, sock_onehot, ng, nc, cpb)
+    et = tier_from_counts_dyn(cnt_g, cnt_c, sock_onehot, ng, nc, cpb)
     cnt_fg = jax.lax.population_count(free_gpu[:, None] & numa_g[None, :])
     cnt_fc = jax.lax.population_count(free_cg[:, None] & numa_c[None, :])
-    ft = _tier_from_counts_dyn(cnt_fg, cnt_fc, sock_onehot, ng, nc, cpb)
+    ft = tier_from_counts_dyn(cnt_fg, cnt_fc, sock_onehot, ng, nc, cpb)
 
     tier_vals = jnp.asarray(tuple(TIER_SCORES) + (0.0,), jnp.float32)
     min_p = jnp.min(jnp.where(elig, vp, _INT32_MAX), axis=1)
@@ -1054,34 +754,47 @@ def _shortlist_plan2_pipeline(nodestate, victims, drain, aux,
 
 
 @lru_cache(maxsize=None)
-def shortlist_evaluator(spec: ServerSpec, k: int, p: int, f: int,
+def _rep_sharding(mesh):
+    """1-D row sharding of the equivalence-class rep mask, unpacked on the
+    device so the shortlist prescreen stays shard-local up to its top-K
+    collective (None on one device)."""
+    if mesh is None:
+        return None
+    return NamedSharding(mesh, P(tuple(mesh.axis_names)))
+
+
+@lru_cache(maxsize=None)
+def shortlist_evaluator(mesh, spec: ServerSpec, k: int, p: int, f: int,
                         alpha: float):
     """jit of `_shortlist_pipeline` (``req`` as in `resident_evaluator`)."""
+    rep_sh = _rep_sharding(mesh)
 
     def fn(nodestate, victims, drain, aux, req):
         return _shortlist_pipeline(nodestate, victims, drain, aux,
                                    *_req_args(req), alpha,
-                                   spec=spec, k=k, p=p, f=f)
+                                   spec=spec, k=k, p=p, f=f, rep_sh=rep_sh)
 
-    return obs.program("shortlist", fn)
+    return mesh_program("shortlist", fn, mesh, _RESIDENT_IN)
 
 
 @lru_cache(maxsize=None)
-def shortlist_plan_evaluator(spec: ServerSpec, k: int, p: int, f: int,
+def shortlist_plan_evaluator(mesh, spec: ServerSpec, k: int, p: int, f: int,
                              alpha: float):
     """jit of `_shortlist_plan2_pipeline` — the shortlisted
     ``schedule_or_preempt`` hot path in one dispatch."""
+    rep_sh = _rep_sharding(mesh)
 
     def fn(nodestate, victims, drain, aux, req):
         return _shortlist_plan2_pipeline(nodestate, victims, drain, aux,
                                          *_req_args(req), alpha,
-                                         spec=spec, k=k, p=p, f=f)
+                                         spec=spec, k=k, p=p, f=f,
+                                         rep_sh=rep_sh)
 
-    return obs.program("shortlist_plan", fn)
+    return mesh_program("shortlist_plan", fn, mesh, _RESIDENT_IN)
 
 
 @lru_cache(maxsize=None)
-def batch_class_evaluator(spec: ServerSpec, m: int, alpha: float):
+def batch_class_evaluator(mesh, spec: ServerSpec, m: int, alpha: float):
     """jit(vmap) of the class core over a REQUEST axis: one dispatch
     computes every request's per-node class winners against one snapshot.
     The request scalars are the vmap axis (necessarily dynamic); alpha is
@@ -1092,8 +805,9 @@ def batch_class_evaluator(spec: ServerSpec, m: int, alpha: float):
                                  cpb, alpha, spec=spec, m=m,
                                  narrow_gate=True)
 
-    return obs.program("batch_class",
-                       jax.vmap(f, in_axes=(None, None, None, 0, 0, 0, 0)))
+    return mesh_program("batch_class",
+                        jax.vmap(f, in_axes=(None, None, None, 0, 0, 0, 0)),
+                        mesh, "nvnrrrr", ClassWinners(*"vvvvnn"))
 
 
 def _masked_class_winner(anyc, cb, pp, um, kn, cnt, nodestate, victims,
@@ -1157,7 +871,7 @@ def _batch_merge_pipeline(anyc, cb, pp, um, kn, cnt, nodestate, victims,
 
 
 @lru_cache(maxsize=None)
-def batch_merge_evaluator(spec: ServerSpec, m: int, dpad: int, g: int,
+def batch_merge_evaluator(mesh, spec: ServerSpec, m: int, dpad: int, g: int,
                           alpha: float):
     """Per-request device merge for the batch session, ONE dispatch.
 
@@ -1171,7 +885,7 @@ def batch_merge_evaluator(spec: ServerSpec, m: int, dpad: int, g: int,
             anyc, cb, pp, um, kn, cnt, nodestate, victims, drain, i, aux,
             pbuf, *_req_args(req), alpha, spec=spec, m=m, dpad=dpad, g=g)
 
-    return obs.program("batch_merge", f)
+    return mesh_program("batch_merge", f, mesh, _BATCH_IN)
 
 
 def _batch_plan_pipeline(anyc, cb, pp, um, kn, cnt, nodestate, victims,
@@ -1204,7 +918,7 @@ def _batch_plan_pipeline(anyc, cb, pp, um, kn, cnt, nodestate, victims,
 
 
 @lru_cache(maxsize=None)
-def batch_plan_evaluator(spec: ServerSpec, m: int, dpad: int, g: int,
+def batch_plan_evaluator(mesh, spec: ServerSpec, m: int, dpad: int, g: int,
                          p: int, alpha: float):
     """jit of `_batch_plan_pipeline` — a batched plan is one dispatch end
     to end, same as a single-request plan."""
@@ -1216,7 +930,7 @@ def batch_plan_evaluator(spec: ServerSpec, m: int, dpad: int, g: int,
             pbuf, *_req_args(req), alpha, spec=spec, m=m, dpad=dpad, g=g,
             p=p)
 
-    return obs.program("batch_plan", f)
+    return mesh_program("batch_plan", f, mesh, _BATCH_IN)
 
 
 #: node-axis chunk size for the widest (m=16) victim bucket: keeps the
@@ -1267,24 +981,6 @@ def _empty_aux():
     """The cached zero-length argument buffer of a plan with nothing to
     send (no patch rows, no gather rows): such a plan uploads nothing."""
     return obs.upload(np.zeros(0, np.int32))
-
-
-def _evals(dcs: DeviceClusterState):
-    """Evaluator-factory namespace for this device state.
-
-    Single-device states use THIS module's jit factories; a mesh-sharded
-    state (`cluster_parallel.ShardedDeviceClusterState`) routes to
-    `cluster_parallel.sharded_evaluators`, which jits the SAME pipeline
-    bodies with explicit `NamedSharding` constraints — node-axis tensors
-    arrive sharded, each plan's packed argument buffer replicates, and the
-    winner vector comes back replicated, so per-node math stays
-    shard-local and only the final argmax chain crosses shards."""
-    mesh = getattr(dcs, "mesh", None)
-    if mesh is None:
-        return sys.modules[__name__]
-    from . import cluster_parallel
-
-    return cluster_parallel.sharded_evaluators(mesh)
 
 
 def _patch_row(patches, node: int) -> VictimRow:
@@ -1622,9 +1318,8 @@ def source_candidates_fused(
     Returns the winning `Candidate` per dispatch (plus per-node python
     candidates for overflow rows) as a `CandidateShortlist` carrying the
     true evaluated-candidate count; the scheduler's ``select`` reduces the
-    shortlist with the exact host-side Eq. 2.  Winner parity with ``imp``,
-    ``imp_jax`` and ``imp_batched_legacy`` is covered by
-    tests/test_fused_sourcing.py.
+    shortlist with the exact host-side Eq. 2.  Winner parity with ``imp``
+    is covered by tests/test_fused_sourcing.py.
     """
     if nodes is not None and not nodes:
         return CandidateShortlist()
@@ -1633,7 +1328,6 @@ def source_candidates_fused(
     # flush=False: small dirty sets stay pending and ride the dispatch's
     # patch overlay instead of paying a standalone scatter dispatch
     dcs = base.device_state().sync(flush=False)
-    ev = _evals(dcs)
     ctx = dcs.mirror
     thresh = workload.priority
     ng, nc, cpb = _req_scalars(spec, workload)
@@ -1659,7 +1353,8 @@ def source_candidates_fused(
             split, f, aux_d = _shortlist_plan_args(dcs, patches, thresh,
                                                    pa)
             dcs.counts["shortlist_plans"] += 1
-            res = ev.shortlist_evaluator(spec, shortlist.k, p, f, alpha)(
+            res = shortlist_evaluator(dcs.mesh, spec, shortlist.k, p, f,
+                                      alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain, aux_d, rq)
             vals = obs.readback(res)
             if vals[-1] or shortlist.mode != "guaranteed":
@@ -1683,7 +1378,7 @@ def source_candidates_fused(
             out = CandidateShortlist(_overflow_candidates(
                 cluster, workload, split.overflow, dcs.fallbacks))
             out.n_candidates = len(out)
-            res = ev.resident_evaluator(spec, split.m_res, p, g, alpha)(
+            res = resident_evaluator(dcs.mesh, spec, split.m_res, p, g, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain, aux_d, rq)
             n = dcs.n_rows
             sel = ({n + j: node for j, node in enumerate(mid)}
@@ -1699,14 +1394,14 @@ def source_candidates_fused(
         excluded = set(mid) | set(split.wide) | set(split.overflow)
         narrow = [c for c in nodes if c not in excluded]
         if narrow:
-            res = ev.gathered_evaluator(spec, split.m_res, p, alpha)(
+            res = gathered_evaluator(dcs.mesh, spec, split.m_res, p, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain,
                 _plan_aux(pa, _pad_idx(narrow)), rq)
             pending.append((res, narrow))
     for m, rows in ((NARROW_M, mid), (ctx.cap, split.wide)):
         for lo in range(0, len(rows), MAX_ROWS_WIDE):
             chunk = rows[lo:lo + MAX_ROWS_WIDE]
-            res = ev.gathered_evaluator(spec, m, p, alpha)(
+            res = gathered_evaluator(dcs.mesh, spec, m, p, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain,
                 _plan_aux(pa, _pad_idx(chunk)), rq)
             pending.append((res, chunk))
@@ -1760,14 +1455,13 @@ def plan_normal_fused(cluster, workload: WorkloadSpec):
     spec = cluster.spec
     base = getattr(cluster, "base", cluster)
     dcs = base.device_state().sync(flush=False)
-    ev = _evals(dcs)
     with obs.span("sched.patch"):
         patches = _view_patches_of(cluster, dcs)
         pa = _patch_args(dcs, patches)
         aux_d = _memo_args(dcs, "normal", patches, pa.p,
                            lambda: _plan_aux(pa))
     ng, nc, cpb = _req_scalars(spec, workload)
-    res = ev.normal_evaluator(spec, pa.p)(
+    res = normal_evaluator(dcs.mesh, spec, pa.p)(
         dcs.nodestate, aux_d,
         request_vector(workload.priority, ng, nc, cpb))
     found, node, tier, gm, cm = obs.readback(res)
@@ -1808,7 +1502,7 @@ def _finalize_plan(vals, sel, patches, ctx, shortlist_fn, wide_chunks_fn,
 
 
 def _plan_fused_shortlist(cluster, workload: WorkloadSpec,
-                          dcs: DeviceClusterState, ev, ctx, patches,
+                          dcs: DeviceClusterState, ctx, patches,
                           pa: PatchArgs, alpha: float,
                           shortlist: ShortlistConfig):
     """The shortlisted chained plan: one `_shortlist_plan2_pipeline`
@@ -1823,7 +1517,8 @@ def _plan_fused_shortlist(cluster, workload: WorkloadSpec,
     rq = request_vector(thresh, ng, nc, cpb)
     split, f, aux_d = _shortlist_plan_args(dcs, patches, thresh, pa)
     dcs.counts["shortlist_plans"] += 1
-    res = ev.shortlist_plan_evaluator(spec, shortlist.k, pa.p, f, alpha)(
+    res = shortlist_plan_evaluator(dcs.mesh, spec, shortlist.k, pa.p, f,
+                                   alpha)(
         dcs.nodestate, dcs.victims, dcs.drain, aux_d, rq)
     vals = obs.readback(res)
     if (not vals[0] and not vals[-1]
@@ -1842,7 +1537,7 @@ def _plan_fused_shortlist(cluster, workload: WorkloadSpec,
     def wide_chunks():
         for lo in range(0, len(split.wide), MAX_ROWS_WIDE):
             chunk = split.wide[lo:lo + MAX_ROWS_WIDE]
-            yield ev.gathered_evaluator(spec, ctx.cap, pa.p, alpha)(
+            yield gathered_evaluator(dcs.mesh, spec, ctx.cap, pa.p, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain,
                 _plan_aux(pa, _pad_idx(chunk)), rq), chunk
 
@@ -1879,7 +1574,6 @@ def plan_fused(cluster, workload: WorkloadSpec, alpha: float = DEFAULT_ALPHA,
     spec = cluster.spec
     base = getattr(cluster, "base", cluster)
     dcs = base.device_state().sync(flush=False)
-    ev = _evals(dcs)
     ctx = dcs.mirror
     thresh = workload.priority
     ng, nc, cpb = _req_scalars(spec, workload)
@@ -1887,7 +1581,7 @@ def plan_fused(cluster, workload: WorkloadSpec, alpha: float = DEFAULT_ALPHA,
         patches = _view_patches_of(cluster, dcs)
         pa = _patch_args(dcs, patches)
     if shortlist is not None and dcs.n_rows > shortlist.k:
-        got = _plan_fused_shortlist(cluster, workload, dcs, ev, ctx,
+        got = _plan_fused_shortlist(cluster, workload, dcs, ctx,
                                     patches, pa, alpha, shortlist)
         if got is not None:
             return got
@@ -1897,7 +1591,7 @@ def plan_fused(cluster, workload: WorkloadSpec, alpha: float = DEFAULT_ALPHA,
     mid = split.mid
     alpha = float(alpha)
     rq = request_vector(thresh, ng, nc, cpb)
-    res = ev.plan_evaluator(spec, split.m_res, pa.p, g, alpha)(
+    res = plan_evaluator(dcs.mesh, spec, split.m_res, pa.p, g, alpha)(
         dcs.nodestate, dcs.victims, dcs.drain, aux_d, rq)
     vals = obs.readback(res)
     n = dcs.n_rows
@@ -1914,7 +1608,7 @@ def plan_fused(cluster, workload: WorkloadSpec, alpha: float = DEFAULT_ALPHA,
         # to have failed — they are unreachable work otherwise
         for lo in range(0, len(split.wide), MAX_ROWS_WIDE):
             chunk = split.wide[lo:lo + MAX_ROWS_WIDE]
-            yield ev.gathered_evaluator(spec, ctx.cap, pa.p, alpha)(
+            yield gathered_evaluator(dcs.mesh, spec, ctx.cap, pa.p, alpha)(
                 dcs.nodestate, dcs.victims, dcs.drain,
                 _plan_aux(pa, _pad_idx(chunk)), rq), chunk
 
@@ -1949,7 +1643,6 @@ class BatchSourcingSession:
         self.spec = cluster.spec
         self.alpha = float(alpha)
         self.dcs = cluster.device_state().sync()
-        self.ev = _evals(self.dcs)
         self.ctx = self.dcs.mirror
         self._row_cache: dict[int, tuple[int, VictimRow]] = {}
         self.reqs = [(wl.priority,) + _req_scalars(self.spec, wl)
@@ -1975,8 +1668,8 @@ class BatchSourcingSession:
         cpb = np.zeros(rp, np.int32)
         for j, (t, g, c, b) in enumerate(self.reqs):
             th[j], ng[j], nc[j], cpb[j] = t, g, c, b
-        self.class_data = self.ev.batch_class_evaluator(self.spec, self.gate,
-                                                        self.alpha)(
+        self.class_data = batch_class_evaluator(
+            self.dcs.mesh, self.spec, self.gate, self.alpha)(
             self.dcs.nodestate, self.dcs.victims, self.dcs.drain,
             obs.upload(th), obs.upload(ng), obs.upload(nc),
             obs.upload(cpb))
@@ -2059,8 +1752,8 @@ class BatchSourcingSession:
         else:
             aux_d = obs.upload(np.concatenate([didx, pa.idx, gidx]))
             pbuf_d = obs.upload(pa.host)
-        res = self.ev.batch_merge_evaluator(self.spec, NARROW_M, len(didx),
-                                            len(gidx), self.alpha)(
+        res = batch_merge_evaluator(self.dcs.mesh, self.spec, NARROW_M,
+                                    len(didx), len(gidx), self.alpha)(
             *self.class_data, self.dcs.nodestate, self.dcs.victims,
             self.dcs.drain, obs.upload(np.asarray(i, np.int32)), aux_d,
             pbuf_d, rq)
@@ -2073,8 +1766,8 @@ class BatchSourcingSession:
             rows = d_wide + wide
             for lo in range(0, len(rows), MAX_ROWS_WIDE):
                 chunk = rows[lo:lo + MAX_ROWS_WIDE]
-                res = self.ev.gathered_evaluator(self.spec, cap, pw.p,
-                                                 self.alpha)(
+                res = gathered_evaluator(self.dcs.mesh, self.spec, cap,
+                                         pw.p, self.alpha)(
                     self.dcs.nodestate, self.dcs.victims, self.dcs.drain,
                     _plan_aux(pw, _pad_idx(chunk)), rq)
                 pending.append((res, chunk))
@@ -2111,8 +1804,8 @@ class BatchSourcingSession:
                 aux_d = obs.upload(np.concatenate([didx, pa.idx, gidx]))
                 pbuf_d = obs.upload(pa.host)
         rq = request_vector(thresh, ng, nc, cpb)
-        res = self.ev.batch_plan_evaluator(self.spec, NARROW_M, len(didx),
-                                           len(gidx), pa.p, self.alpha)(
+        res = batch_plan_evaluator(self.dcs.mesh, self.spec, NARROW_M,
+                                   len(didx), len(gidx), pa.p, self.alpha)(
             *self.class_data, self.dcs.nodestate, self.dcs.victims,
             self.dcs.drain, obs.upload(np.asarray(i, np.int32)), aux_d,
             pbuf_d, rq)
@@ -2139,8 +1832,8 @@ class BatchSourcingSession:
             rows = d_wide + wide
             for lo in range(0, len(rows), MAX_ROWS_WIDE):
                 chunk = rows[lo:lo + MAX_ROWS_WIDE]
-                yield self.ev.gathered_evaluator(self.spec, cap, pw.p,
-                                                 self.alpha)(
+                yield gathered_evaluator(self.dcs.mesh, self.spec, cap,
+                                         pw.p, self.alpha)(
                     self.dcs.nodestate, self.dcs.victims, self.dcs.drain,
                     _plan_aux(pw, _pad_idx(chunk)), rq), chunk
 

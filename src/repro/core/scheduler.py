@@ -38,10 +38,10 @@ patch rows are rebuilt ON DEVICE by the delta encoder
 the device-resident state sharded over a 1-D device mesh
 (``Cluster.device_state(sharded=True)``: node axis padded to the mesh
 size, `NamedSharding` pinned through scatter/rebuild/delta-encode): the
-same fused entry points route to per-mesh jits of the identical traced
-pipeline bodies, per-node math stays shard-local, only the final argmax
-chain crosses shards, and decisions stay bit-identical — plans, batch
-sessions, and the day cycle work unchanged at thousands of nodes.
+same fused entry points build each program for the state's mesh, per-node
+math stays shard-local, only the final argmax chain crosses shards, and
+decisions stay bit-identical — plans, batch sessions, and the day cycle
+work unchanged at thousands of nodes.
 
 The engine list above is rendered from the live registry
 (``repro.core.engines.registered_engines``); custom engines registered with

@@ -180,15 +180,17 @@ def _write(path: str, rec: dict) -> None:
 
 
 def run_scheduler_cell(mesh_kind: str, out_dir: str, force: bool = False) -> dict:
-    """Dry-run the distributed candidate sourcing (cluster_parallel) itself.
+    """Dry-run the programs ``imp_sharded`` dispatches, over the mesh.
 
-    Lowers the per-size legacy sweep, the fused single-dispatch evaluator
-    (all subset sizes + on-device Eq. 2 argmax + winner placement), and the
-    sharded normal-cycle placement scorer over the mesh.
+    Lowers and compiles, at 65,536 resident nodes sharded over the
+    production mesh, the shortlisted chained plan (the record's top level),
+    the full-sweep chained plan (``fused``) and the normal cycle
+    (``normal_cycle``), each against a delta-free plan's arguments.
     """
-    from repro.core.cluster_parallel import (lower_distributed_fused_source,
-                                             lower_distributed_normal_cycle,
-                                             lower_distributed_source)
+    from repro.core import preemption_jax as pj
+    from repro.core.cluster import (DRAIN_FIELDS, MAX_DENSE_VICTIMS,
+                                    NODE_FIELDS, VICTIM_FIELDS,
+                                    _mesh_shardings)
     from repro.core.topology import RTX4090_SERVER
 
     cell = _cell_name("scheduler-sourcing", "cluster64k", mesh_kind)
@@ -200,27 +202,34 @@ def run_scheduler_cell(mesh_kind: str, out_dir: str, force: bool = False) -> dic
            "mesh": mesh_kind, "kind": "scheduler"}
     try:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
-        t0 = time.time()
-        lowered = lower_distributed_source(mesh, RTX4090_SERVER)
-        compiled = lowered.compile()
-        rec.update(status="ok", compile_s=round(time.time() - t0, 2),
-                   memory=_memory_dict(compiled.memory_analysis()),
-                   cost={k: float(v) for k, v in
-                         compiled.cost_analysis().items()
-                         if k in ("flops", "bytes accessed")},
-                   hlo=hlo_util.summarize(compiled.as_text()))
-        t0 = time.time()
-        fused = lower_distributed_fused_source(mesh, RTX4090_SERVER).compile()
-        rec["fused"] = {"compile_s": round(time.time() - t0, 2),
-                        "memory": _memory_dict(fused.memory_analysis()),
-                        "hlo": hlo_util.summarize(fused.as_text())}
-        t0 = time.time()
-        normal = lower_distributed_normal_cycle(mesh,
-                                                RTX4090_SERVER).compile()
-        rec["normal_cycle"] = {
-            "compile_s": round(time.time() - t0, 2),
-            "memory": _memory_dict(normal.memory_analysis()),
-            "hlo": hlo_util.summarize(normal.as_text())}
+        node_sh, victim_sh, repl = _mesh_shardings(mesh)
+        n, i32, spec, alpha = 65536, jnp.int32, RTX4090_SERVER, 0.5
+        ns = jax.ShapeDtypeStruct((NODE_FIELDS, n), i32, sharding=node_sh)
+        v = jax.ShapeDtypeStruct((VICTIM_FIELDS, n, MAX_DENSE_VICTIMS), i32,
+                                 sharding=victim_sh)
+        dr = jax.ShapeDtypeStruct((DRAIN_FIELDS, n), i32, sharding=node_sh)
+        aux = jax.ShapeDtypeStruct((0,), i32, sharding=repl)
+        words = jax.ShapeDtypeStruct((n // 32,), i32, sharding=repl)
+        req = jax.ShapeDtypeStruct((4,), i32, sharding=repl)
+        programs = (
+            (None, pj.shortlist_plan_evaluator(mesh, spec, 128, 0, 0, alpha),
+             (ns, v, dr, words, req)),
+            ("fused", pj.plan_evaluator(mesh, spec, pj.NARROW_M, 0, 0, alpha),
+             (ns, v, dr, aux, req)),
+            ("normal_cycle", pj.normal_evaluator(mesh, spec, 0),
+             (ns, aux, req)))
+        for key, fn, args in programs:
+            t0 = time.time()
+            compiled = fn.lower(*args).compile()
+            got = {"compile_s": round(time.time() - t0, 2),
+                   "memory": _memory_dict(compiled.memory_analysis()),
+                   "hlo": hlo_util.summarize(compiled.as_text())}
+            if key is None:
+                rec.update(status="ok", cost={
+                    k: float(x) for k, x in compiled.cost_analysis().items()
+                    if k in ("flops", "bytes accessed")}, **got)
+            else:
+                rec[key] = got
     except Exception as e:  # noqa: BLE001
         rec.update(status="error", error=f"{type(e).__name__}: {e}",
                    traceback=traceback.format_exc()[-4000:])

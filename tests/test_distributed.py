@@ -125,31 +125,6 @@ def test_pod_sync_int8_compression():
     """))
 
 
-def test_cluster_parallel_sourcing_executes():
-    """Sharded cluster-wide candidate sourcing runs and matches the
-    unsharded argmax."""
-    print(run_py("""
-        import jax, jax.numpy as jnp, numpy as np
-        from repro.launch.mesh import auto_mesh
-        from repro.core.cluster_parallel import (make_distributed_source,
-            distributed_source_inputs, _source_best)
-        from repro.core.preemption_jax import Request
-        from repro.core.topology import RTX4090_SERVER
-        from functools import partial
-
-        mesh = auto_mesh((4, 2), ("data", "model"))
-        req = Request(need_gpus=4, need_cgs=4, bundle_locality=True)
-        args = distributed_source_inputs(RTX4090_SERVER, 256, 8, 2, req,
-                                         rng=np.random.default_rng(3))
-        fn = make_distributed_source(mesh, RTX4090_SERVER, req, alpha=0.5)
-        score, node, combo = fn(*args)
-        ref = partial(_source_best, request=req, alpha=0.5)(*[jnp.asarray(a) for a in args])
-        assert float(score) == float(ref[0]), (score, ref[0])
-        assert int(node) == int(ref[1])
-        print("distributed sourcing ok:", float(score), int(node), int(combo))
-    """))
-
-
 def test_sharded_engine_decision_parity_randomized():
     """imp_sharded on a real 8-device mesh is bit-identical to imp_batched
     over randomized plan / commit / rollback / plan_batch sequences."""
@@ -229,12 +204,12 @@ def test_sharded_state_layout_and_scatter():
         import jax
         assert len(jax.devices()) == 8, jax.devices()
         from repro.core import Cluster, RTX4090_SERVER, table3_workloads
-        from repro.core.cluster_parallel import ShardedDeviceClusterState
+        from repro.core.cluster import cluster_mesh
         from repro.core.simulator import SimConfig, build_saturated_cluster
 
         cluster = build_saturated_cluster(SimConfig(num_nodes=13, seed=2))
         dcs = cluster.device_state(sharded=True)
-        assert isinstance(dcs, ShardedDeviceClusterState)
+        assert dcs.mesh is cluster_mesh()
         dcs.sync()
         assert dcs.n_rows == 16 and dcs.nodestate.shape[1] == 16
         for name in ("nodestate", "victims", "drain"):

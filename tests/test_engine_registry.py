@@ -50,8 +50,7 @@ def test_scheduler_rejects_unknown_engine_at_construction():
 
 def test_registry_contains_all_paper_engines():
     names = registered_engines()
-    for name in ("godel", "exhaustive", "imp", "imp_jax", "imp_batched",
-                 "imp_pallas"):
+    for name in ("godel", "exhaustive", "imp", "imp_batched", "imp_pallas"):
         assert name in names
 
 

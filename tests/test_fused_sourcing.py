@@ -16,7 +16,7 @@ from repro.core.placement import Placement
 from repro.core.workload import TopoPolicy, WorkloadSpec
 
 WL3 = {w.name: w for w in table3_workloads()}
-PARITY_ENGINES = ("imp", "imp_jax", "imp_batched_legacy", "imp_batched")
+PARITY_ENGINES = ("imp", "imp_batched", "imp_sharded")
 
 
 def random_cluster(seed: int, nodes: int = 5) -> Cluster:
@@ -47,9 +47,10 @@ def _decision_key(dec):
 
 @pytest.mark.parametrize("seed", [0, 3, 7, 11, 42, 1234])
 @pytest.mark.parametrize("wl_name", ["A", "B", "C"])
-def test_fused_matches_legacy_and_python(seed, wl_name):
-    """The fused engine's on-device Eq. 2 winner IS select_best's winner:
-    same node, same victim set, same tier as every exact engine."""
+def test_fused_matches_python(seed, wl_name):
+    """The fused engines' on-device Eq. 2 winner IS select_best's winner:
+    same node, same victim set, same tier as the host engine ``imp``, on
+    one device and over the sharded state."""
     decs = {}
     for engine in PARITY_ENGINES:
         cluster = random_cluster(seed)
@@ -77,22 +78,22 @@ def test_fused_parity_in_plan_batch():
     """Later plans in a batch see earlier planned evictions/binds through
     the copy-on-write view; the vmapped batch session masks those delta
     nodes out of its precomputed tensors and re-sources only them, and
-    must still agree with the legacy engine."""
+    must still agree with the host engine."""
     batch = [WL3["B"], WL3["B"], WL3["C"], WL3["B"]]
     keys = {}
-    for engine in ("imp_batched_legacy", "imp_batched"):
+    for engine in ("imp", "imp_batched"):
         cluster = random_cluster(21, nodes=4)
         sched = TopoScheduler(cluster, engine=engine)
         keys[engine] = [_decision_key(t.decision)
                         for t in sched.plan_batch(batch)]
-    assert keys["imp_batched_legacy"] == keys["imp_batched"]
+    assert keys["imp"] == keys["imp_batched"]
 
 
 @pytest.mark.parametrize("alpha", [0.0, 0.3, 0.5, 1.0])
 def test_vmapped_plan_batch_parity_across_engines_and_alpha(alpha):
     """Acceptance pin: the vmapped `plan_batch` produces bitwise-identical
-    decisions (node, victims, tier, hit) vs imp, imp_jax and the legacy
-    engine across the alpha sweep — 8 requests against one snapshot."""
+    decisions (node, victims, tier, hit) vs imp, on one device and over
+    the sharded state, across the alpha sweep — 8 requests against one snapshot."""
     batch = [WL3[n] for n in ("B", "B", "C", "B", "C", "C", "B", "D")]
     for seed in (3, 42):
         keys = {}
@@ -109,7 +110,7 @@ def test_vmapped_plan_batch_parity_across_commit_sequences():
     decision-identical across engines (the resident state must track every
     commit incrementally, and each round's session snapshots it)."""
     seqs = {}
-    for engine in ("imp", "imp_batched_legacy", "imp_batched"):
+    for engine in ("imp", "imp_batched"):
         cluster = random_cluster(17)
         sched = TopoScheduler(cluster, engine=engine)
         seq = []
@@ -315,7 +316,7 @@ def test_cross_tier_exact_score_tie_breaks_by_victim_count():
         return cluster
 
     decs = {}
-    for engine in ("imp", "imp_batched_legacy", "imp_batched"):
+    for engine in ("imp", "imp_batched"):
         sched = TopoScheduler(build(), engine=engine, alpha=0.5)
         decs[engine] = _decision_key(
             sched.plan(preemptor, allow_normal=False).decision)
@@ -326,15 +327,16 @@ def test_cross_tier_exact_score_tie_breaks_by_victim_count():
 
 def test_fused_num_candidates_matches_legacy():
     """The device counts every feasible min-k subset; the decision must
-    report that count, not the shortlist length."""
+    report that count, not the shortlist length: the number of candidates
+    the host engine ``imp`` lists."""
     for seed in (0, 7):
         decs = {}
-        for engine in ("imp_batched_legacy", "imp_batched"):
+        for engine in ("imp", "imp_batched"):
             cluster = random_cluster(seed)
             sched = TopoScheduler(cluster, engine=engine)
             decs[engine] = sched.plan(WL3["B"], allow_normal=False).decision
         assert (decs["imp_batched"].num_candidates
-                == decs["imp_batched_legacy"].num_candidates > 0)
+                == decs["imp"].num_candidates > 0)
 
 
 def test_truncated_row_stays_dense_when_eligible_victims_fit():
@@ -376,7 +378,7 @@ def test_truncated_row_stays_dense_when_eligible_victims_fit():
 
 
 @pytest.mark.parametrize("engine",
-                         ["imp_batched", "imp_batched_legacy", "imp_pallas"])
+                         ["imp_batched", "imp_sharded", "imp_pallas"])
 def test_overflow_node_falls_back_instead_of_crashing(engine):
     from repro.core.preemption import flextopo_imp
 

@@ -13,7 +13,6 @@ from repro import obs
 from repro.core import TopoScheduler, table3_workloads
 from repro.core import preemption_jax as pj
 from repro.core.cluster import DeviceClusterState, ViewDelta
-from repro.core.cluster_parallel import ShardedDeviceClusterState
 from repro.core.colocation import (ColocationConfig, ColocationSim,
                                    default_policies)
 from repro.core.simulator import build_scaled_cluster
@@ -70,9 +69,8 @@ def _side_uploads(monkeypatch) -> dict:
 
         monkeypatch.setattr(cls, name, counted)
 
-    for cls in (DeviceClusterState, ShardedDeviceClusterState):
-        wrap(cls, "_scatter", "flush")
-        wrap(cls, "_upload_full", "full")
+    wrap(DeviceClusterState, "_scatter", "flush")
+    wrap(DeviceClusterState, "_upload_full", "full")
     wrap(ViewDelta, "device_rows", "encoder")
     return side
 

@@ -261,13 +261,12 @@ def test_persistent_session_invalidated_by_mutation():
 
 def test_persistent_session_parity_across_repeated_plan_batch():
     """Repeated identical plan_batch bursts (pure reads, session reused)
-    must stay decision-identical to the legacy engine every round."""
+    must stay decision-identical to the host engine every round."""
     from tests.test_fused_sourcing import random_cluster
 
     batch = [WL3["B"], WL3["C"], WL3["B"]]
-    want = None
-    legacy = TopoScheduler(random_cluster(23), engine="imp_batched_legacy")
-    want = [_decision_key(t.decision) for t in legacy.plan_batch(batch)]
+    host = TopoScheduler(random_cluster(23), engine="imp")
+    want = [_decision_key(t.decision) for t in host.plan_batch(batch)]
     sched = TopoScheduler(random_cluster(23), engine="imp_batched")
     for _ in range(3):
         got = [_decision_key(t.decision) for t in sched.plan_batch(batch)]

@@ -9,9 +9,9 @@ import numpy as np
 import pytest
 
 from repro.core import preemption_jax as pj
-from repro.core.cluster import (IDX_SENTINEL, apply_packed_rows, apply_rows,
+from repro.core.cluster import (IDX_SENTINEL, DeviceClusterState,
+                                apply_packed_rows, apply_rows, cluster_mesh,
                                 pack_bits, pack_scatter, row_width)
-from repro.core.cluster_parallel import ShardedDeviceClusterState
 from repro.core.simulator import SimConfig, build_saturated_cluster
 
 CAP = 16
@@ -95,9 +95,9 @@ def test_packed_scatter_equals_the_two_array_scatter(q):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
-class _PaddedSharded(ShardedDeviceClusterState):
-    """A one-device sharded state whose node axis pads to a multiple of
-    four, as it does on a four-device mesh."""
+class _PaddedSharded(DeviceClusterState):
+    """A state over a one-device mesh whose node axis pads to a multiple
+    of four, as it does on a four-device mesh."""
 
     @property
     def n_rows(self) -> int:
@@ -112,7 +112,8 @@ def test_plan_upload_carries_the_host_rep_mask(state):
     is 36 rows long, the words cover 64)."""
     cluster = build_saturated_cluster(SimConfig(num_nodes=33, seed=4))
     if state == "sharded_padded":
-        cluster._device_state = _PaddedSharded(cluster)
+        cluster._device_state = _PaddedSharded(
+            cluster, mesh=cluster_mesh(jax.devices()[:1]))
     dcs = cluster.device_state(sharded=state != "device").sync()
     # a commit leaves pending rows, which ride ahead of the mask words
     inst = next(i for i in cluster.instances.values() if i.preemptible)
@@ -128,5 +129,5 @@ def test_plan_upload_carries_the_host_rep_mask(state):
     assert np.array_equal(np.asarray(i), pa.idx)
     assert np.array_equal(np.asarray(buf), pa.host)
     got = np.asarray(pj._rep_mask(words, dcs.n_rows,
-                                  getattr(pj._evals(dcs), "_rep_sh", None)))
+                                  pj._rep_sharding(dcs.mesh)))
     assert np.array_equal(got, rep)

@@ -9,6 +9,7 @@ clusters far below the production default of 128.
 """
 import random
 
+import jax
 import numpy as np
 import pytest
 
@@ -16,9 +17,9 @@ from repro import obs
 from repro.core import (Cluster, ColocationConfig, ShortlistConfig, SPECS,
                         TopoScheduler, run_day_cycle, table3_workloads)
 from repro.core import preemption_jax as pj
-from repro.core.cluster import (SourcingContext, ViewDelta, encode_row,
+from repro.core.cluster import (DeviceClusterState, SourcingContext,
+                                ViewDelta, cluster_mesh, encode_row,
                                 row_width)
-from repro.core.cluster_parallel import ShardedDeviceClusterState
 from repro.core.placement import Placement
 from repro.core.preemption_jax import NARROW_M
 from repro.core.simulator import SimConfig, build_saturated_cluster
@@ -352,9 +353,9 @@ def test_day_cycle_guaranteed_shortlist_matches_full_sweep():
 # Incremental class index against the from-scratch classes
 # ---------------------------------------------------------------------------------
 
-class _PaddedSharded(ShardedDeviceClusterState):
-    """A one-device sharded state whose node axis pads to a multiple of
-    four, as it does on a four-device mesh."""
+class _PaddedSharded(DeviceClusterState):
+    """A state over a one-device mesh whose node axis pads to a multiple
+    of four, as it does on a four-device mesh."""
 
     @property
     def n_rows(self) -> int:
@@ -404,7 +405,8 @@ def test_class_index_matches_from_scratch(engine, seed, monkeypatch):
     cluster = _sat(num_nodes=23, seed=seed)
     n = cluster.num_nodes
     if sharded:
-        cluster._device_state = _PaddedSharded(cluster)
+        cluster._device_state = _PaddedSharded(
+            cluster, mesh=cluster_mesh(jax.devices()[:1]))
     forced_calls = []
     real_forced = pj._forced_rows
 

@@ -102,14 +102,14 @@ def test_fused_evaluators_compile_for_v5e(one_chip, evaluator):
     spec = RTX4090_SERVER
     s = _resident_shapes(NODES, one_chip, one_chip, one_chip)
     if evaluator == "normal":
-        fn = pj.normal_evaluator(spec, 0)
+        fn = pj.normal_evaluator(None, spec, 0)
         args = (s["nodestate"], s["aux"], s["req"])
     elif evaluator == "plan":
-        fn = pj.plan_evaluator(spec, pj.MIN_M, 0, 0, ALPHA)
+        fn = pj.plan_evaluator(None, spec, pj.MIN_M, 0, 0, ALPHA)
         args = (s["nodestate"], s["victims"], s["drain"], s["aux"],
                 s["req"])
     else:
-        fn = pj.shortlist_plan_evaluator(spec, 128, 0, 0, ALPHA)
+        fn = pj.shortlist_plan_evaluator(None, spec, 128, 0, 0, ALPHA)
         args = (s["nodestate"], s["victims"], s["drain"], s["rep_words"],
                 s["req"])
     compiled = fn.lower(*args).compile()
@@ -118,13 +118,12 @@ def test_fused_evaluators_compile_for_v5e(one_chip, evaluator):
 
 def test_sharded_plan_evaluator_compiles_for_four_chips(topo):
     """`imp_sharded`'s chained plan program over a 4-chip node mesh."""
-    from repro.core import cluster_parallel as cp
     from repro.core import preemption_jax as pj
+    from repro.core.cluster import _mesh_shardings, cluster_mesh
 
-    mesh = cp.cluster_mesh(topo.devices)
-    ev = cp.sharded_evaluators(mesh)
-    s = _resident_shapes(NODES, ev.node_sh, ev.victim_sh, ev.repl)
-    fn = ev.plan_evaluator(RTX4090_SERVER, pj.MIN_M, 0, 0, ALPHA)
+    mesh = cluster_mesh(topo.devices)
+    s = _resident_shapes(NODES, *_mesh_shardings(mesh))
+    fn = pj.plan_evaluator(mesh, RTX4090_SERVER, pj.MIN_M, 0, 0, ALPHA)
     text = fn.lower(s["nodestate"], s["victims"], s["drain"], s["aux"],
                     s["req"]).compile().as_text()
     assert "all-reduce" in text or "all-gather" in text
